@@ -712,6 +712,8 @@ def main() -> None:
                     help="1 = accept any login (test default); 0 = "
                          "account/password auth via mo_user")
     args = ap.parse_args()
+    from matrixone_tpu.utils import enable_compilation_cache
+    enable_compilation_cache()
     peers = [p for p in args.peers.split(",") if p]
     cn = CNService(args.tn, data_dir=args.dir, port=args.port,
                    frag_port=args.frag_port, peers=peers,

@@ -334,7 +334,7 @@ class _Conn:
 
     def send_resultset(self, result: Result):
         self._send_column_defs(result, binary=False)
-        for row in result.rows():
+        for row in _wire_rows(result):
             out = b""
             for v in row:
                 if v is None:
@@ -351,7 +351,7 @@ class _Conn:
         self._send_column_defs(result, binary=True)
         ncols = len(result.column_names)
         nm_len = (ncols + 2 + 7) // 8
-        for row in result.rows():
+        for row in _wire_rows(result):
             nullmap = bytearray(nm_len)
             body = b""
             for i, v in enumerate(row):
@@ -473,6 +473,31 @@ class _Conn:
                 pass
 
 
+def _decimal_text(scaled: int, scale: int) -> str:
+    """A DECIMAL64 as the wire sends it: every digit of the scaled
+    integer (a float loses them past 2^53, which an SF1 sum reaches),
+    in the shape `str(float)` gave small values: trailing zeros dropped,
+    one fractional digit kept."""
+    digits = str(abs(scaled)).rjust(scale + 1, "0")
+    whole, frac = digits[:len(digits) - scale], digits[len(digits) - scale:]
+    return (("-" if scaled < 0 else "") + whole + "."
+            + (frac.rstrip("0") or "0"))
+
+
+def _wire_rows(result: Result):
+    """`Result.rows()` with DECIMAL columns rendered exactly."""
+    cols = []
+    for name in result.column_names:
+        vec = result.batch.columns[name]
+        if vec.dtype.oid == TypeOid.DECIMAL64:
+            cols.append([_decimal_text(int(v), vec.dtype.scale) if ok
+                         else None
+                         for v, ok in zip(vec.data, vec.valid_mask())])
+        else:
+            cols.append(vec.to_pylist())
+    return list(zip(*cols))
+
+
 class MOServer:
     """reference: frontend/server.go:611 NewMOServer / :99 Start.
 
@@ -504,6 +529,8 @@ class MOServer:
                        auth_manager=self.auth_mgr)
 
     def start(self):
+        from matrixone_tpu.utils import enable_compilation_cache
+        enable_compilation_cache()
         if not self.insecure:
             # accounts/users/roles live in engine tables and replicate
             # through the logtail; the seeded users land in the sys

@@ -31,16 +31,23 @@ _tried = False
 
 
 def _compile() -> bool:
+    """Build to a name of this process's own and rename it into place:
+    several processes (the test rig's workers) may build at once, each
+    rename is atomic, and every one of them loads a whole library."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    tmp = f"{_SO}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           _SRC, "-o", _SO + ".tmp"]
+           _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(_SO + ".tmp", _SO)
+        os.replace(tmp, _SO)
         return True
     except (subprocess.SubprocessError, OSError):
         # compiler missing/failed/timed out: numpy fallback paths apply
-        return False
+        # (unless another process's build already stands at _SO)
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+        return os.path.exists(_SO)
 
 
 def get_lib():
@@ -78,44 +85,38 @@ def get_lib():
         lib.mo_bitset_count.argtypes = [u8p, ctypes.c_size_t]
         lib.mo_sorted_contains.argtypes = [i64p, ctypes.c_size_t, i64p,
                                            ctypes.c_size_t, u8p]
-        try:        # an older cached .so may predate the HNSW symbols
-            f32p = ctypes.POINTER(ctypes.c_float)
-            lib.mo_hnsw_build.restype = ctypes.c_void_p
-            lib.mo_hnsw_build.argtypes = [f32p, ctypes.c_int64,
-                                          ctypes.c_int, ctypes.c_int,
-                                          ctypes.c_int, ctypes.c_int,
-                                          ctypes.c_uint64]
-            lib.mo_hnsw_search.argtypes = [ctypes.c_void_p, f32p,
-                                           ctypes.c_int64, ctypes.c_int,
-                                           ctypes.c_int, i64p, f32p]
-            lib.mo_hnsw_n.restype = ctypes.c_int64
-            lib.mo_hnsw_n.argtypes = [ctypes.c_void_p]
-            lib.mo_hnsw_free.argtypes = [ctypes.c_void_p]
-            lib.mo_has_hnsw = True
-        except AttributeError:
-            lib.mo_has_hnsw = False
-        try:        # roaring symbols (added round 4)
-            lib.mo_rbm_create.restype = ctypes.c_void_p
-            lib.mo_rbm_free.argtypes = [ctypes.c_void_p]
-            lib.mo_rbm_add.argtypes = [ctypes.c_void_p, i64p,
-                                       ctypes.c_size_t]
-            lib.mo_rbm_test.argtypes = [ctypes.c_void_p, i64p,
-                                        ctypes.c_size_t, u8p]
-            lib.mo_rbm_test_range.argtypes = [ctypes.c_void_p,
-                                              ctypes.c_int64,
-                                              ctypes.c_int64, u8p]
-            lib.mo_rbm_count.restype = ctypes.c_int64
-            lib.mo_rbm_count.argtypes = [ctypes.c_void_p]
-            lib.mo_rbm_bytes.restype = ctypes.c_int64
-            lib.mo_rbm_bytes.argtypes = [ctypes.c_void_p]
-            lib.mo_rbm_and.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
-            lib.mo_rbm_or.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
-            lib.mo_rbm_to_array.restype = ctypes.c_int64
-            lib.mo_rbm_to_array.argtypes = [ctypes.c_void_p, i64p,
-                                            ctypes.c_int64]
-            lib.mo_has_rbm = True
-        except AttributeError:
-            lib.mo_has_rbm = False
+        # the library is rebuilt whenever it is older than its source,
+        # so every symbol of native/mo_native.cpp is there
+        f32p = ctypes.POINTER(ctypes.c_float)
+        lib.mo_hnsw_build.restype = ctypes.c_void_p
+        lib.mo_hnsw_build.argtypes = [f32p, ctypes.c_int64,
+                                      ctypes.c_int, ctypes.c_int,
+                                      ctypes.c_int, ctypes.c_int,
+                                      ctypes.c_uint64]
+        lib.mo_hnsw_search.argtypes = [ctypes.c_void_p, f32p,
+                                       ctypes.c_int64, ctypes.c_int,
+                                       ctypes.c_int, i64p, f32p]
+        lib.mo_hnsw_n.restype = ctypes.c_int64
+        lib.mo_hnsw_n.argtypes = [ctypes.c_void_p]
+        lib.mo_hnsw_free.argtypes = [ctypes.c_void_p]
+        lib.mo_rbm_create.restype = ctypes.c_void_p
+        lib.mo_rbm_free.argtypes = [ctypes.c_void_p]
+        lib.mo_rbm_add.argtypes = [ctypes.c_void_p, i64p,
+                                   ctypes.c_size_t]
+        lib.mo_rbm_test.argtypes = [ctypes.c_void_p, i64p,
+                                    ctypes.c_size_t, u8p]
+        lib.mo_rbm_test_range.argtypes = [ctypes.c_void_p,
+                                          ctypes.c_int64,
+                                          ctypes.c_int64, u8p]
+        lib.mo_rbm_count.restype = ctypes.c_int64
+        lib.mo_rbm_count.argtypes = [ctypes.c_void_p]
+        lib.mo_rbm_bytes.restype = ctypes.c_int64
+        lib.mo_rbm_bytes.argtypes = [ctypes.c_void_p]
+        lib.mo_rbm_and.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        lib.mo_rbm_or.argtypes = [ctypes.c_void_p, ctypes.c_void_p]
+        lib.mo_rbm_to_array.restype = ctypes.c_int64
+        lib.mo_rbm_to_array.argtypes = [ctypes.c_void_p, i64p,
+                                        ctypes.c_int64]
         _lib = lib
         return _lib
 
@@ -292,7 +293,7 @@ class RoaringBitmap:
 
     def __init__(self, ids=None):
         lib = get_lib()
-        self._lib = lib if lib is not None and lib.mo_has_rbm else None
+        self._lib = lib
         if self._lib is not None:
             self._h = self._lib.mo_rbm_create()
         else:

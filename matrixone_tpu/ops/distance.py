@@ -53,10 +53,12 @@ def l2_distance_sq(x: jnp.ndarray, q: jnp.ndarray,
     exact-f32 path runs the hand-tiled Pallas kernel
     (ops/pallas_kernels.py) instead of the XLA default — same math,
     explicit VMEM staging."""
+    from matrixone_tpu.ops import kernels as HK
     from matrixone_tpu.ops import pallas_kernels as PK
     enabled = PK.use_pallas() if use_pallas is None else use_pallas
     if enabled and compute_dtype is None and x.shape[0] % 1024 == 0:
-        return PK.l2_distance_sq_pallas(x, q, tile_m=1024)
+        return PK.l2_distance_sq_pallas(x, q, tile_m=1024,
+                                        interpret=HK.interpret())
     xq = _matmul_xqT(x, q, compute_dtype)
     x2 = jnp.sum(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     q2 = jnp.sum(jnp.square(q.astype(jnp.float32)), axis=-1)

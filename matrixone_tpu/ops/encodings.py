@@ -30,7 +30,7 @@ bf16-vs-f32 per backend for IVF — generalized to per-column choice:
     a row across a filter is a wrong answer, not a tolerance.
 
 The policy is chosen per backend: `MO_NARROW_ENCODINGS` is `auto` by
-default (on for TPU, off for the CPU fallback, where narrow loads
+default (on for TPU, off on CPU, where narrow loads
 de-vectorize instead of saving bandwidth), `1` forces it on (the moqa
 `narrow-encodings` lockstep pair runs this against the f32/int64
 baseline), `0` kills it.
@@ -57,8 +57,8 @@ def enabled() -> bool:
         return True
     if v in ("0", "off", "false", ""):
         return False
-    import jax
-    return jax.default_backend() == "tpu"
+    from matrixone_tpu.ops.kernels import platform
+    return platform() == "tpu"
 
 
 def signature() -> tuple:

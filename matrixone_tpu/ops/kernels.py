@@ -14,9 +14,16 @@ directly; the choice is
   * `MO_HAND_KERNELS=1` — force on (tier-1 runs the Pallas kernels in
     interpret mode on cpu this way; the bit-identity drills and the
     moqa padding canary ride it);
-  * unset / `auto` — on for the TPU backend, off for the cpu fallback
+  * unset / `auto` — on where the devices are TPUs, off on cpu
     (XLA:CPU's native scatter/searchsorted beat interpreted Pallas by
     orders of magnitude).
+
+The platform is read ONCE from `jax.devices()` (`platform()`), and every
+production caller of a Pallas kernel passes `interpret=interpret()`:
+compiled on a TPU, interpreted only where a force switch
+(`MO_HAND_KERNELS=1`, `SET use_pallas = 1`, `MO_USE_PALLAS=1`) turned a
+kernel on for a platform that has no kernel compiler.  The auto route
+is on only on TPU, so it can never select interpret mode.
 
 Identity contract: `sorted_lookup` is bit-identical to the XLA path on
 EVERY backend by construction (integer count, no rounding, no order
@@ -30,7 +37,23 @@ compile key carries `signature()` (vm/fusion, vm/fusion_join).
 
 from __future__ import annotations
 
+import functools
 import os
+
+
+@functools.lru_cache(maxsize=None)
+def platform() -> str:
+    """Platform of the devices this process computes on, resolved once
+    ("tpu" | "cpu" | ...)."""
+    import jax
+    return jax.devices()[0].platform
+
+
+def interpret() -> bool:
+    """The `interpret=` every production Pallas call passes (see the
+    module docstring): False on TPU, True only under a force switch
+    elsewhere."""
+    return platform() != "tpu"
 
 
 def _flag() -> str:
@@ -46,8 +69,7 @@ def enabled() -> bool:
         return True
     if v in ("0", "off", "false"):
         return False
-    import jax
-    return jax.default_backend() == "tpu"
+    return platform() == "tpu"
 
 
 def signature() -> tuple:
@@ -64,7 +86,8 @@ def sorted_lookup(sorted_vals, queries):
     import jax.numpy as jnp
     if enabled():
         from matrixone_tpu.ops import pallas_kernels as PK
-        return PK.sorted_search_pallas(sorted_vals, queries)
+        return PK.sorted_search_pallas(sorted_vals, queries,
+                                       interpret=interpret())
     return jnp.searchsorted(sorted_vals, queries).astype(jnp.int32)
 
 
@@ -90,7 +113,7 @@ def grouped_scatter_add(values, gids, mask, max_groups: int,
             mask = jnp.pad(mask, (0, padded - n))   # pads False
         return PK.segment_sum_pallas(values, gids, mask,
                                      num_segments=max_groups,
-                                     tile_n=tile)
+                                     tile_n=tile, interpret=interpret())
     import jax
     v = jnp.where(mask, values, jnp.asarray(0, values.dtype))
     return jax.ops.segment_sum(v, gids, num_segments=max_groups)

@@ -22,11 +22,15 @@ Kernels:
     (gather-free, VPU compares + integer sum), bit-identical to
     `jnp.searchsorted(side='left')` by construction.
 
-All kernels fall back to interpret mode off TPU (tests run on the CPU
-mesh) and are opt-in: sessions enable them with `SET use_pallas = 1`
-(reference: `pkg/util/gpumode/gpu_mode.go:37 EffectiveGpuMode` — session
-value wins, else the MO_USE_PALLAS env default), because until profiled
-on real hardware the XLA default fusion is the trusted path.
+Every kernel compiles for the device unless its caller passes
+`interpret=True`; nothing here looks for a chip.  Production callers take
+the flag from the dispatch seam (`ops/kernels.py` `interpret()`), the
+CPU tests pass it themselves.  The first four are opt-in: sessions
+enable them with `SET use_pallas = 1` (reference:
+`pkg/util/gpumode/gpu_mode.go:37 EffectiveGpuMode` — session value wins,
+else the MO_USE_PALLAS env default); the sorted search is routed by the
+seam.  Every kernel compiles for a described v5e at the widths of
+chip_smoke.py (tests/test_chip_compile.py).
 """
 
 from __future__ import annotations
@@ -36,6 +40,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 
@@ -58,10 +63,17 @@ def effective_use_pallas(session_value=None) -> bool:
     return use_pallas()
 
 
-def _interpret(flag):
-    if flag is None:
-        return jax.default_backend() != "tpu"
-    return flag
+def _note_trace(kernel: str, interpret: bool) -> None:
+    """Count one trace of `kernel` (runs while the jitted wrapper is
+    traced): how chip_smoke.py learns which kernels the served path
+    really put into its programs, and in which mode."""
+    from matrixone_tpu.utils import metrics as M
+    M.pallas_traces.inc(kernel=kernel, interpret=str(bool(interpret)))
+
+
+# jax_enable_x64 is on package-wide, so a literal 0 in an index map is
+# an int64 the TPU kernel compiler refuses: block indices are int32
+_Z = np.int32(0)
 
 
 # ------------------------------------------------- pairwise L2 (fused)
@@ -78,12 +90,12 @@ def _l2_kernel(x_ref, q_ref, q2_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("tile_m", "interpret"))
 def l2_distance_sq_pallas(x: jnp.ndarray, q: jnp.ndarray,
                           tile_m: int = 1024,
-                          interpret: bool | None = None) -> jnp.ndarray:
+                          interpret: bool = False) -> jnp.ndarray:
     """Pairwise squared L2 [n, b]; n must be a multiple of tile_m."""
     n, d = x.shape
     b = q.shape[0]
     assert n % tile_m == 0, f"n={n} must be a multiple of tile_m={tile_m}"
-    interpret = _interpret(interpret)
+    _note_trace("l2_distance_sq_pallas", interpret)
     xf = x.astype(jnp.float32)
     qf = q.astype(jnp.float32)
     q2 = jnp.sum(qf * qf, axis=1)[None, :]          # [1, b]
@@ -92,11 +104,11 @@ def l2_distance_sq_pallas(x: jnp.ndarray, q: jnp.ndarray,
         _l2_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_m, d), lambda i: (i, 0)),
-            pl.BlockSpec((b, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, b), lambda i: (0, 0)),
+            pl.BlockSpec((tile_m, d), lambda i: (i, _Z)),
+            pl.BlockSpec((b, d), lambda i: (_Z, _Z)),
+            pl.BlockSpec((1, b), lambda i: (_Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
         interpret=interpret,
     )(xf, qf, q2)
@@ -121,7 +133,7 @@ def _l2_masked_kernel(x_ref, q_ref, q2_ref, m_ref, out_ref):
 def l2_distance_sq_masked_pallas(x: jnp.ndarray, q: jnp.ndarray,
                                  mask: jnp.ndarray,
                                  tile_m: int = 1024,
-                                 interpret: bool | None = None
+                                 interpret: bool = False
                                  ) -> jnp.ndarray:
     """Filtered pairwise squared L2 [n, b]: rows with mask=False score
     +inf. The mask rides into the same VMEM tile as the vectors, so the
@@ -130,7 +142,7 @@ def l2_distance_sq_masked_pallas(x: jnp.ndarray, q: jnp.ndarray,
     n, d = x.shape
     b = q.shape[0]
     assert n % tile_m == 0, f"n={n} must be a multiple of tile_m={tile_m}"
-    interpret = _interpret(interpret)
+    _note_trace("l2_distance_sq_masked_pallas", interpret)
     xf = x.astype(jnp.float32)
     qf = q.astype(jnp.float32)
     q2 = jnp.sum(qf * qf, axis=1)[None, :]
@@ -139,12 +151,12 @@ def l2_distance_sq_masked_pallas(x: jnp.ndarray, q: jnp.ndarray,
         _l2_masked_kernel,
         grid=(n // tile_m,),
         in_specs=[
-            pl.BlockSpec((tile_m, d), lambda i: (i, 0)),
-            pl.BlockSpec((b, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, b), lambda i: (0, 0)),
-            pl.BlockSpec((tile_m, 1), lambda i: (i, 0)),
+            pl.BlockSpec((tile_m, d), lambda i: (i, _Z)),
+            pl.BlockSpec((b, d), lambda i: (_Z, _Z)),
+            pl.BlockSpec((1, b), lambda i: (_Z, _Z)),
+            pl.BlockSpec((tile_m, 1), lambda i: (i, _Z)),
         ],
-        out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, 0)),
+        out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
         interpret=interpret,
     )(xf, qf, q2, m2)
@@ -177,7 +189,7 @@ def _segsum_kernel(v_ref, g_ref, out_ref):
 def segment_sum_pallas(values: jnp.ndarray, gids: jnp.ndarray,
                        mask: jnp.ndarray, num_segments: int,
                        tile_n: int = 2048,
-                       interpret: bool | None = None) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """Masked float32 segment sum over [n] values into [num_segments].
 
     TPU formulation of `colexec/group` partial aggregation: instead of a
@@ -192,7 +204,7 @@ def segment_sum_pallas(values: jnp.ndarray, gids: jnp.ndarray,
     """
     n = values.shape[0]
     assert n % tile_n == 0, f"n={n} not a multiple of tile_n={tile_n}"
-    interpret = _interpret(interpret)
+    _note_trace("segment_sum_pallas", interpret)
     v = jnp.where(mask, values.astype(jnp.float32), 0.0)[None, :]  # [1, n]
     # masked rows also get an out-of-range id so a gid collision with a
     # real group cannot resurrect them (id G sums into nothing: the iota
@@ -202,10 +214,10 @@ def segment_sum_pallas(values: jnp.ndarray, gids: jnp.ndarray,
         _segsum_kernel,
         grid=(n // tile_n,),
         in_specs=[
-            pl.BlockSpec((1, tile_n), lambda i: (0, i)),
-            pl.BlockSpec((1, tile_n), lambda i: (0, i)),
+            pl.BlockSpec((1, tile_n), lambda i: (_Z, i)),
+            pl.BlockSpec((1, tile_n), lambda i: (_Z, i)),
         ],
-        out_specs=pl.BlockSpec((1, num_segments), lambda i: (0, 0)),
+        out_specs=pl.BlockSpec((1, num_segments), lambda i: (_Z, _Z)),
         out_shape=jax.ShapeDtypeStruct((1, num_segments), jnp.float32),
         interpret=interpret,
     )(v, g)
@@ -249,7 +261,7 @@ def _sign_flip_halves(x64: jnp.ndarray):
                    static_argnames=("tile_q", "tile_n", "interpret"))
 def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
                          tile_q: int = 1024, tile_n: int = 1024,
-                         interpret: bool | None = None) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """`jnp.searchsorted(sorted_vals, queries, side='left')` for uint64
     hashes, as a Pallas kernel: insertion-point-left(q) == #{s : s < q},
     so each (query-tile, sorted-tile) step is a dense VPU compare plus
@@ -262,7 +274,7 @@ def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
     sliced off the result.
     """
     (n,), (m,) = sorted_vals.shape, queries.shape
-    interpret = _interpret(interpret)
+    _note_trace("sorted_search_pallas", interpret)
     s64 = sorted_vals.astype(jnp.uint64)
     q64 = queries.astype(jnp.uint64)
     pad_n = (-n) % tile_n
@@ -278,12 +290,12 @@ def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
         _sorted_search_kernel,
         grid=(q64.shape[0] // tile_q, s64.shape[0] // tile_n),
         in_specs=[
-            pl.BlockSpec((1, tile_n), lambda qi, ni: (0, ni)),
-            pl.BlockSpec((1, tile_n), lambda qi, ni: (0, ni)),
-            pl.BlockSpec((1, tile_q), lambda qi, ni: (0, qi)),
-            pl.BlockSpec((1, tile_q), lambda qi, ni: (0, qi)),
+            pl.BlockSpec((1, tile_n), lambda qi, ni: (_Z, ni)),
+            pl.BlockSpec((1, tile_n), lambda qi, ni: (_Z, ni)),
+            pl.BlockSpec((1, tile_q), lambda qi, ni: (_Z, qi)),
+            pl.BlockSpec((1, tile_q), lambda qi, ni: (_Z, qi)),
         ],
-        out_specs=pl.BlockSpec((1, tile_q), lambda qi, ni: (0, qi)),
+        out_specs=pl.BlockSpec((1, tile_q), lambda qi, ni: (_Z, qi)),
         out_shape=jax.ShapeDtypeStruct((1, q64.shape[0]), jnp.int32),
         interpret=interpret,
     )(shi[None, :], slo[None, :], qhi[None, :], qlo[None, :])
@@ -292,27 +304,31 @@ def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
 
 # ------------------------------------------------- IVF-PQ ADC scoring
 def _adc_kernel(codes_ref, lut_ref, out_ref):
-    codes = codes_ref[:][0]                         # [TC, M] int32
-    lut = lut_ref[:][0]                             # [M, 256] f32
+    codes = codes_ref[0]                            # [TC, M] int32
+    lut = lut_ref[0]                                # [M, 256] f32
     tc, m = codes.shape
-    # scores[c] = sum_m lut[m, codes[c, m]] — expressed as a one-hot
-    # [TC, M*256] @ [M*256, 1] matmul so the gather runs on the MXU
+    # scores[c] = sum_m lut[m, codes[c, m]] — one one-hot [TC, 256]
+    # per subspace contracted with that subspace's LUT row on the MXU
     # (the reference's cuVS ADC kernel does warp-local LUT gathers;
     # TPUs have no per-lane gather, but the one-hot contraction is
-    # exactly what the systolic array is good at)
+    # exactly what the systolic array is good at).  The contraction is
+    # [1, 256] x [TC, 256]^T so the scores come out lane-major, the
+    # layout of the output block, with no in-kernel reshape.
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, 256), 1)  # [1, 256]
-    onehot = (codes[:, :, None] == iota[None, :, :]).astype(jnp.float32)
-    onehot = onehot.reshape(tc, m * 256)
-    lut_flat = lut.reshape(m * 256, 1)
-    out_ref[:] = jax.lax.dot_general(
-        onehot, lut_flat, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(1, tc)
+    acc = jnp.zeros((1, tc), jnp.float32)
+    for j in range(m):
+        onehot = (codes[:, j:j + 1] == iota).astype(jnp.float32)
+        acc = acc + jax.lax.dot_general(
+            lut[j:j + 1, :], onehot,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    out_ref[0] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("tile_c", "interpret"))
 def adc_score_pallas(codes: jnp.ndarray, lut: jnp.ndarray,
                      tile_c: int = 256,
-                     interpret: bool | None = None) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """Batched ADC scoring: codes [G, P, M] uint8/int32 (G query-probe
     groups, P candidates each), lut [G, M, 256] f32 -> scores [G, P]
     with scores[g, p] = sum_m lut[g, m, codes[g, p, m]].
@@ -323,17 +339,21 @@ def adc_score_pallas(codes: jnp.ndarray, lut: jnp.ndarray,
     g, p, m = codes.shape
     assert p % tile_c == 0, f"P={p} not a multiple of tile_c={tile_c}"
     assert lut.shape == (g, m, 256), lut.shape
-    interpret = _interpret(interpret)
+    _note_trace("adc_score_pallas", interpret)
     c32 = codes.astype(jnp.int32)
     out = pl.pallas_call(
         _adc_kernel,
         grid=(g, p // tile_c),
         in_specs=[
-            pl.BlockSpec((1, tile_c, m), lambda gi, ci: (gi, ci, 0)),
-            pl.BlockSpec((1, m, 256), lambda gi, ci: (gi, 0, 0)),
+            pl.BlockSpec((1, tile_c, m), lambda gi, ci: (gi, ci, _Z)),
+            pl.BlockSpec((1, m, 256), lambda gi, ci: (gi, _Z, _Z)),
         ],
-        out_specs=pl.BlockSpec((1, tile_c), lambda gi, ci: (gi, ci)),
-        out_shape=jax.ShapeDtypeStruct((g, p), jnp.float32),
+        # [g, 1, p]: a (1, tile_c) block of a [g, p] array breaks the
+        # chip's (8, 128) block rule; with the unit axis second-to-last
+        # the block equals the array there
+        out_specs=pl.BlockSpec((1, 1, tile_c),
+                               lambda gi, ci: (gi, _Z, ci)),
+        out_shape=jax.ShapeDtypeStruct((g, 1, p), jnp.float32),
         interpret=interpret,
     )(c32, lut.astype(jnp.float32))
-    return out
+    return out[:, 0, :]

@@ -30,9 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# mesh.py installs the jax.shard_map compat shim for jax 0.4.37 (where
-# it lives in jax.experimental and the replication check is spelled
-# check_rep, not check_vma) — import it before any shard_map call site.
 from matrixone_tpu.parallel.mesh import make_mesh
 
 from matrixone_tpu.ops import agg as A, distance as D, hash as H
@@ -685,6 +682,8 @@ def try_shard(node, catalog, ctx, n_shards: int,
             else:
                 leaf = _exec_join(split, xp, catalog, ctx, n_shards)
     except Exception as e:      # noqa: BLE001 — degrade, never fail
+        from matrixone_tpu.utils import metrics as M
+        M.exchange_degrade.inc()
         print(f"[shard] degrading to single-device execution: "
               f"{type(e).__name__}: {e}", file=sys.stderr)
         return None

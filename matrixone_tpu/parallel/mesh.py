@@ -20,21 +20,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax.shard_map only exists as a top-level name on newer jax; this image
-# ships 0.4.37 where it lives in jax.experimental and the replication
-# check is spelled check_rep, not check_vma.  Every mesh consumer imports
-# this module, so the shim installs before any shard_map call site runs.
-if not hasattr(jax, "shard_map"):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map(*args, **kwargs)
-
-    jax.shard_map = _compat_shard_map
-
-
 def make_mesh(n_devices: Optional[int] = None,
               axis_name: str = "shard") -> Mesh:
     devs = jax.devices()

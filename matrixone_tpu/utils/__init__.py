@@ -21,20 +21,21 @@ def __getattr__(name):
 
 
 def enable_compilation_cache(min_compile_seconds: float = 0.05) -> bool:
-    """Point jax at the persistent XLA compilation cache shared by the
-    test rig and bench (the cuVS worker the design chases caches its
-    compiled kernels the same way). Honors JAX_COMPILATION_CACHE_DIR,
-    defaults to ~/.cache/mo_tpu_jax; MO_JAX_CACHE=0 disables. Returns
+    """Turn on jax's persistent XLA compilation cache for this process.
+    Where JAX_COMPILATION_CACHE_DIR is set jax already uses it and no
+    other directory is set here; otherwise the cache lives at the fixed
+    path `<checkout>/.jax_cache` (the path is part of the cache key, so
+    it must not move between runs). MO_JAX_CACHE=0 disables. Returns
     whether the cache was enabled. Call before the first compile."""
     import os
 
     import jax
     if os.environ.get("MO_JAX_CACHE", "1") == "0":
         return False
-    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                          os.path.expanduser("~/.cache/mo_tpu_jax"))
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ["JAX_COMPILATION_CACHE_DIR"])
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)))), ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       min_compile_seconds)
     return True

@@ -382,6 +382,12 @@ fusion_step_seconds = REGISTRY.counter(
     "fused step wall seconds by kind (device vs host bookkeeping; "
     "filled under MO_FUSION_PROFILE=1 diagnostic runs, bench.py)")
 
+pallas_traces = REGISTRY.counter(
+    "mo_pallas_trace_total",
+    "Pallas kernels traced into a program, by kernel and by whether "
+    "the trace was for interpret mode (ops/pallas_kernels.py; counted "
+    "at trace time, so once per compiled program, not per dispatch)")
+
 # ---- Python/JAX UDF subsystem (udf/, reference: pkg/udf/pythonservice)
 udf_calls = REGISTRY.counter(
     "mo_udf_calls_total",
@@ -507,6 +513,10 @@ exchange_partial_merge = REGISTRY.counter(
     "mo_exchange_partial_merge_total",
     "cross-shard partial-result merges by kind "
     "(dense/general/scalar/topk/join)")
+exchange_degrade = REGISTRY.counter(
+    "mo_exchange_degrade_total",
+    "sharded fragments that failed on the shards and re-ran on one "
+    "device (the `[shard] degrading` line on stderr)")
 
 # ---- restart recovery (Engine.open) + crash sweep (utils/crash.py,
 # ---- tools/mocrash)

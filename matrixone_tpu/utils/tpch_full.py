@@ -20,7 +20,7 @@ from __future__ import annotations
 import datetime
 import re
 import sqlite3
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -83,10 +83,15 @@ def _comments(rng, n, extra_rate=0.0, extra=""):
     return np.array(out, dtype=object)
 
 
-def gen_tpch(sf: float = 0.01, seed: int = 0) -> Dict[str, Dict[str, np.ndarray]]:
+def gen_tpch(sf: float = 0.01, seed: int = 0,
+             lineitem_rows: Optional[int] = None
+             ) -> Dict[str, Dict[str, np.ndarray]]:
     """All 8 tables as column arrays. Money columns are in CENTS (int64,
     decimal64 scale-2 storage); dates are days-since-epoch int32; strings
-    are object arrays."""
+    are object arrays.  Lines per order are drawn 1..7, so lineitem has
+    about 4 rows per order; `lineitem_rows` pins its cardinality exactly
+    (the spec's 6,001,215 at SF1) by moving single lines onto or off the
+    first orders that have room, still 1..7 each."""
     rng = np.random.default_rng(seed)
     n_supp = max(10, int(10_000 * sf))
     n_part = max(40, int(200_000 * sf))
@@ -185,6 +190,17 @@ def gen_tpch(sf: float = 0.01, seed: int = 0) -> Dict[str, Dict[str, np.ndarray]
     o_date = rng.integers(_days(1992, 1, 1), _days(1998, 8, 3),
                           n_ord).astype(np.int32)
     n_lines_per = rng.integers(1, 8, n_ord)
+    if lineitem_rows is not None:
+        if not n_ord <= lineitem_rows <= 7 * n_ord:
+            raise ValueError(f"lineitem_rows={lineitem_rows} does not fit "
+                             f"{n_ord} orders of 1..7 lines")
+        diff = lineitem_rows - int(n_lines_per.sum())
+        step = 1 if diff > 0 else -1
+        while diff:
+            room = np.flatnonzero(n_lines_per < 7 if step > 0
+                                  else n_lines_per > 1)[:abs(diff)]
+            n_lines_per[room] += step
+            diff -= step * len(room)
     orders = {
         "o_orderkey": np.arange(1, n_ord + 1, dtype=np.int64),
         "o_custkey": o_cust.astype(np.int64),
@@ -305,29 +321,35 @@ _PKS = {"region": ["r_regionkey"], "nation": ["n_nationkey"],
 
 
 def _encode_strings(values: np.ndarray) -> Tuple[np.ndarray, list]:
-    cats, lut, codes = [], {}, np.empty(len(values), np.int32)
-    for i, s in enumerate(values):
-        c = lut.get(s)
-        if c is None:
-            c = lut[s] = len(cats)
-            cats.append(s)
-        codes[i] = c
-    return codes, cats
+    """(codes, categories) in first-seen order."""
+    import pandas as pd
+    codes, cats = pd.factorize(values)
+    return codes.astype(np.int32), list(cats)
 
 
-def load_tpch(catalog: Catalog, sf: float = 0.01, seed: int = 0
-              ) -> Dict[str, Dict[str, np.ndarray]]:
-    tables = gen_tpch(sf, seed)
+def load_tpch(catalog: Catalog, sf: float = 0.01, seed: int = 0,
+              tables: Optional[Dict[str, Dict[str, np.ndarray]]] = None,
+              commits: int = 1) -> Dict[str, Dict[str, np.ndarray]]:
+    """Generate (or take `tables`) and bulk-insert every table.  A table
+    that already exists keeps its DDL (a `PARTITION BY HASH ... SHARDS n`
+    lineitem created beforehand stays partitioned).  `commits` > 1 splits
+    each table of at least that many rows into that many insert commits
+    (several segments, as a deployment's load would leave)."""
+    if tables is None:
+        tables = gen_tpch(sf, seed)
     for name, arrays in tables.items():
         schema = _SCHEMAS[name]
         catalog.create_table(TableMeta(name, schema, _PKS[name]),
                              if_not_exists=True)
         t = catalog.get_table(name)
-        strings = {}
-        for col, dtype in schema:
-            if dtype.is_varlen:
-                strings[col] = _encode_strings(arrays[col])
-        t.insert_numpy(arrays, strings=strings)
+        n = len(arrays[schema[0][0]])
+        bounds = np.linspace(0, n, (commits if n >= commits else 1) + 1
+                             ).astype(np.int64)
+        for lo, hi in zip(bounds[:-1], bounds[1:]):
+            part = {c: a[lo:hi] for c, a in arrays.items()}
+            strings = {col: _encode_strings(part[col])
+                       for col, dtype in schema if dtype.is_varlen}
+            t.insert_numpy(part, strings=strings)
     return tables
 
 

@@ -100,7 +100,7 @@ def build(dataset: np.ndarray, M: int = 16, ef_construction: int = 64,
     if native and len(dataset):
         from matrixone_tpu import native as N
         lib = N.get_lib()
-        if lib is not None and getattr(lib, "mo_has_hnsw", False):
+        if lib is not None:
             import ctypes
             data = np.ascontiguousarray(dataset, np.float32)
             n, d = data.shape

@@ -163,16 +163,10 @@ def build(dataset: jnp.ndarray, nlist: int, metric: str = METRIC_L2,
     jax.block_until_ready(counts)
     M.vector_build_seconds.inc(time.perf_counter() - t0, stage="assign")
     t0 = time.perf_counter()
-    order = jnp.argsort(labels).astype(jnp.int32)
     offsets = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                jnp.cumsum(counts).astype(jnp.int32)])
-    sorted_vecs = data[order].astype(jnp.float32)
-    sorted_centroids = centroids[labels[order]]
-    residuals = sorted_vecs - sorted_centroids          # small magnitude
-    r_norm2 = jnp.sum(jnp.square(residuals), axis=-1)
-    r_dot_c = jnp.sum(residuals * sorted_centroids, axis=-1)
-    if storage_dtype is not None:
-        residuals = residuals.astype(storage_dtype)
+    order, residuals, r_norm2, r_dot_c = _pack(data, centroids, labels,
+                                               storage_dtype)
     max_cs = int(jnp.max(counts))
     max_cs = ((max_cs + 127) // 128) * 128  # lane-align the gather budget
     index = IvfFlatIndex(centroids=centroids, vectors=residuals,
@@ -182,6 +176,24 @@ def build(dataset: jnp.ndarray, nlist: int, metric: str = METRIC_L2,
     jax.block_until_ready(index.vectors)
     M.vector_build_seconds.inc(time.perf_counter() - t0, stage="pack")
     return index
+
+
+@partial(jax.jit, static_argnames=("storage_dtype",))
+def _pack(data, centroids, labels, storage_dtype):
+    """Cluster-major residual encoding as ONE program.  Op by op, the
+    sorted copy, the gathered centroids, the residuals and the square
+    each hold an [n, d] f32 array beside the data: five of them at
+    1M x 768 peaked at 15.6 of the v5e's 16 GB (chip_smoke, PR 25); the
+    fused program needs the data, one temporary and the result."""
+    order = jnp.argsort(labels).astype(jnp.int32)
+    sorted_vecs = data[order].astype(jnp.float32)
+    sorted_centroids = centroids[labels[order]]
+    residuals = sorted_vecs - sorted_centroids          # small magnitude
+    r_norm2 = jnp.sum(jnp.square(residuals), axis=-1)
+    r_dot_c = jnp.sum(residuals * sorted_centroids, axis=-1)
+    if storage_dtype is not None:
+        residuals = residuals.astype(storage_dtype)
+    return order, residuals, r_norm2, r_dot_c
 
 
 def _bucket_batch(b: int, query_chunk: int) -> Tuple[int, int]:

@@ -177,12 +177,14 @@ def _search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
         cand_codes = index.codes[cand]               # [qc, nprobe, pad, M]
         # dist = sum_m LUT[..., m, code_m]
         if use_pallas and pad % 128 == 0:
+            from matrixone_tpu.ops import kernels as HK
             from matrixone_tpu.ops import pallas_kernels as PK
             g = query_chunk * nprobe
             dist = PK.adc_score_pallas(
                 cand_codes.reshape(g, pad, M),
-                lut.reshape(g, M, 256),
-                tile_c=128).reshape(query_chunk, nprobe, pad)
+                lut.reshape(g, M, 256), tile_c=128,
+                interpret=HK.interpret()
+            ).reshape(query_chunk, nprobe, pad)
         else:
             gathered = jnp.take_along_axis(
                 lut[:, :, None, :, :],                   # [qc,np,1,M,256]

@@ -156,7 +156,6 @@ def assign_topc_sharded(data: jnp.ndarray, centroids: jnp.ndarray,
     centroids replicated, each device runs the chunked scan over its
     block — the build-side analogue of the sharded search path."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     n = data.shape[0]
@@ -172,9 +171,9 @@ def assign_topc_sharded(data: jnp.ndarray, centroids: jnp.ndarray,
     data = jax.device_put(data, NamedSharding(mesh, P("shard", None)))
     centroids = jax.device_put(centroids, NamedSharding(mesh, P()))
 
-    @partial(shard_map, mesh=mesh, in_specs=(P("shard", None), P()),
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P("shard", None), P()),
              out_specs=(P("shard", None), P("shard", None)),
-             check_rep=False)
+             check_vma=False)
     def local(block, cents):
         return assign_topc(block, cents, topc, chunk_size=chunk_size,
                            compute_dtype=compute_dtype)

@@ -34,7 +34,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from matrixone_tpu.ops import distance as D
@@ -131,15 +130,18 @@ def shard_ivf(index: IvfFlatIndex, mesh) -> ShardedIvfIndex:
     M.vector_shard_imbalance.set(float(loads.max()) / mean_rows)
     row = NamedSharding(mesh, P("shard"))
     rep = NamedSharding(mesh, P())
+    # the packed arrays go from the HOST straight to their shards: a
+    # jnp.asarray first would land the whole index on the default device
+    # (device 0 then holds S times its share until the copy is freed)
     return ShardedIvfIndex(
-        centroids=jax.device_put(index.centroids, rep),
-        owner=jax.device_put(jnp.asarray(owner), rep),
-        local_slot=jax.device_put(jnp.asarray(local_slot), rep),
-        vectors=jax.device_put(jnp.asarray(vecs), row),
-        r_norm2=jax.device_put(jnp.asarray(rns), row),
-        r_dot_c=jax.device_put(jnp.asarray(rcs), row),
-        ids=jax.device_put(jnp.asarray(gids), row),
-        local_offsets=jax.device_put(jnp.asarray(lofs), row),
+        centroids=jax.device_put(np.asarray(index.centroids), rep),
+        owner=jax.device_put(owner, rep),
+        local_slot=jax.device_put(local_slot, rep),
+        vectors=jax.device_put(vecs, row),
+        r_norm2=jax.device_put(rns, row),
+        r_dot_c=jax.device_put(rcs, row),
+        ids=jax.device_put(gids, row),
+        local_offsets=jax.device_put(lofs, row),
         metric=index.metric, max_cluster_size=index.max_cluster_size,
         n=index.n, n_shards=S, mesh=mesh)
 
@@ -205,11 +207,11 @@ def _search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
         top_s, top_pos = jax.lax.top_k(-alld, min(k, alld.shape[1]))
         return -top_s, jnp.take_along_axis(alli, top_pos, axis=1)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P("shard"), P("shard"), P("shard"),
                   P("shard"), P("shard")),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     return fn(queries, sidx.centroids, sidx.owner, sidx.local_slot,
               sidx.vectors, sidx.r_norm2, sidx.r_dot_c, sidx.ids,
               sidx.local_offsets)

@@ -1370,30 +1370,35 @@ class FusedFragmentOp(O.Operator):
                 compiled = entry["compiled"].get(slot)
                 if compiled is None:
                     t0 = time.perf_counter()
-                    try:
-                        from matrixone_tpu.utils import motrace
-                        _fragment_step = fn
-                        # donate the carry (arg 6) on accelerator
-                        # backends: the step returns a new carry each
-                        # dispatch and the old one is dead, so XLA can
-                        # reuse its HBM in place instead of holding two
-                        # copies of the agg/topk state per slot (cpu
-                        # donation is unimplemented in XLA and only
-                        # produces warning spam, so gate it)
-                        donate = ((6,) if jax.default_backend() != "cpu"
-                                  else ())
-                        with motrace.span("fusion.compile", slot=slot):
-                            compiled = jax.jit(
+                    from matrixone_tpu.utils import motrace
+                    _fragment_step = fn
+                    # donate the carry (arg 6) on accelerator
+                    # backends: the step returns a new carry each
+                    # dispatch and the old one is dead, so XLA can
+                    # reuse its HBM in place instead of holding two
+                    # copies of the agg/topk state per slot (cpu
+                    # donation is unimplemented in XLA and only
+                    # produces warning spam, so gate it)
+                    donate = (6,) if HK.platform() != "cpu" else ()
+                    with motrace.span("fusion.compile", slot=slot):
+                        try:
+                            lowered = jax.jit(
                                 _fragment_step,
-                                donate_argnums=donate).lower(
-                                *args).compile()
-                    except Exception:   # noqa: BLE001 — whatever the
-                        # tracer rejected, the eager path below computes
-                        # the identical result (and surfaces identical
-                        # user errors); mark so we stop re-trying
-                        self._note_trace_fail(entry)
-                    else:
-                        self._note_compiled(entry, slot, compiled, t0)
+                                donate_argnums=donate).lower(*args)
+                        except Exception:   # noqa: BLE001 — whatever
+                            # the tracer rejected, the eager path below
+                            # computes the identical result (and
+                            # surfaces identical user errors); mark so
+                            # we stop re-trying
+                            lowered = None
+                            self._note_trace_fail(entry)
+                        if lowered is not None:
+                            # a refusal by the device's compiler (fast
+                            # memory, alignment, out of device memory)
+                            # raises: it is never degraded to eager
+                            compiled = lowered.compile()
+                            self._note_compiled(entry, slot, compiled,
+                                                t0)
                 if not entry["failed"]:
                     if profile:
                         M.fusion_step_seconds.inc(

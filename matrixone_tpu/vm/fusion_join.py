@@ -412,15 +412,18 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
             compiled = entry["compiled"].get("build")
             if compiled is None:
                 t0 = time.perf_counter()
-                try:
-                    with motrace.span("fusion.compile", slot="build"):
-                        compiled = jax.jit(fn).lower(*args).compile()
-                except Exception:   # noqa: BLE001 — whatever the tracer
-                    # rejected, the eager call below computes the
-                    # identical result (same function)
-                    self._note_trace_fail(entry)
-                else:
-                    self._note_compiled(entry, "build", compiled, t0)
+                with motrace.span("fusion.compile", slot="build"):
+                    try:
+                        lowered = jax.jit(fn).lower(*args)
+                    except Exception:   # noqa: BLE001 — whatever the
+                        # tracer rejected, the eager call below computes
+                        # the identical result (same function)
+                        lowered = None
+                        self._note_trace_fail(entry)
+                    if lowered is not None:
+                        # the device compiler's refusal raises
+                        compiled = lowered.compile()
+                        self._note_compiled(entry, "build", compiled, t0)
             if not entry["failed"]:
                 out = self._dispatch_entry(
                     entry, "build", args,
@@ -603,18 +606,19 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                     compiled = entry["compiled"].get(slot)
                     if compiled is None:
                         t0 = time.perf_counter()
-                        try:
-                            with motrace.span("fusion.compile",
-                                              slot=slot):
-                                compiled = jax.jit(fn).lower(
-                                    *args).compile()
-                        except Exception:   # noqa: BLE001 — eager
-                            # evaluation of the SAME function below
-                            # computes the identical result
-                            self._note_trace_fail(entry)
-                        else:
-                            self._note_compiled(entry, slot, compiled,
-                                                t0)
+                        with motrace.span("fusion.compile", slot=slot):
+                            try:
+                                lowered = jax.jit(fn).lower(*args)
+                            except Exception:   # noqa: BLE001 — eager
+                                # evaluation of the SAME function below
+                                # computes the identical result
+                                lowered = None
+                                self._note_trace_fail(entry)
+                            if lowered is not None:
+                                # the device compiler's refusal raises
+                                compiled = lowered.compile()
+                                self._note_compiled(entry, slot,
+                                                    compiled, t0)
                     if not entry["failed"]:
                         if profile:
                             M.fusion_step_seconds.inc(

@@ -14,7 +14,8 @@ from matrixone_tpu.worker.server import TpuWorkerServer
 
 
 def main() -> None:
-    from matrixone_tpu.utils import motrace
+    from matrixone_tpu.utils import enable_compilation_cache, motrace
+    enable_compilation_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--port", type=int, default=0)
     args = ap.parse_args()

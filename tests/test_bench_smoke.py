@@ -17,12 +17,11 @@ def test_bench_ivf_smoke_under_60s():
     env = dict(os.environ)
     env.update({
         "MO_BENCH_SMOKE": "1",
-        "MO_BENCH_CPU_FALLBACK": "1",    # pin the CPU backend pre-import
         "MO_BENCH_NO_Q1": "1",           # IVF path only, <60s budget
         "MO_BENCH_N": "8000",            # tier-1 rides every PR: keep the
         "MO_BENCH_D": "32",              # smoke shapes tiny but end-to-end
         "MO_BENCH_Q": "128",
-        "JAX_PLATFORMS": "cpu",
+        "JAX_PLATFORMS": "cpu",          # as tests/conftest.py asks
     })
     t0 = time.time()
     r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],

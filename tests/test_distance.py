@@ -73,10 +73,10 @@ def test_pallas_l2_matches_xla(rng):
     x = rng.standard_normal((2048, 128)).astype(np.float32)
     q = rng.standard_normal((16, 128)).astype(np.float32)
     got = np.asarray(PK.l2_distance_sq_pallas(jnp.asarray(x), jnp.asarray(q),
-                                              tile_m=512))
+                                              tile_m=512, interpret=True))
     ref = np.asarray(D.l2_distance_sq(jnp.asarray(x), jnp.asarray(q)))
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
     # clamped non-negative even for self-pairs
     got2 = np.asarray(PK.l2_distance_sq_pallas(jnp.asarray(x), jnp.asarray(x[:16]),
-                                               tile_m=512))
+                                               tile_m=512, interpret=True))
     assert (got2 >= 0).all()

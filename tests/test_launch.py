@@ -187,3 +187,58 @@ def test_dashboard_snapshot(cluster):
     assert all("frags_run" in c for c in snap["cn_fragments"])
     kinds = {s["kind"] for s in snap["services"]}
     assert {"tn", "cn"} <= kinds
+
+
+# ---- the chip belongs to one process: what each role's child is given
+
+def _launcher_for(tmp_path, platform):
+    cfg = tmp_path / "cluster.toml"
+    plat = f'platform = "{platform}"\n' if platform else ""
+    cfg.write_text(f'[cluster]\ndata_dir = "{tmp_path}/data"\n{plat}')
+    return Launcher(str(cfg))
+
+
+@pytest.mark.parametrize("role", ["log0", "log2", "tn", "tn-respawn"])
+def test_chip_deployment_keeps_non_cn_roles_on_cpu(tmp_path, role,
+                                                   monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")   # whatever the parent has
+    env = _launcher_for(tmp_path, "tpu")._role_env(role)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in env
+
+
+@pytest.mark.parametrize("i", [0, 3])
+def test_chip_deployment_gives_each_cn_one_chip(tmp_path, i):
+    env = _launcher_for(tmp_path, "tpu")._role_env(f"cn{i}")
+    assert env["JAX_PLATFORMS"] == "tpu"
+    assert env["TPU_VISIBLE_CHIPS"] == str(i)
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+
+
+@pytest.mark.parametrize("role", ["log0", "tn", "cn0", "cn1"])
+def test_default_deployment_is_cpu_for_every_role(tmp_path, role):
+    env = _launcher_for(tmp_path, None)._role_env(role)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "TPU_VISIBLE_CHIPS" not in env
+
+
+def test_launcher_process_initialises_no_jax_backend(tmp_path):
+    """The launcher hosts the keepers and the proxy in-process; neither
+    may take a device (a parent that holds the chip starves its CNs)."""
+    import subprocess
+    import sys
+    code = (
+        "import jax._src.xla_bridge as xb\n"
+        "from matrixone_tpu.launch import Launcher\n"
+        "from matrixone_tpu.hakeeper import HAKeeper\n"
+        "from matrixone_tpu.frontend.proxy import MOProxy\n"
+        "k = HAKeeper().start(); p = MOProxy([('127.0.0.1', 1)]).start()\n"
+        "p.stop(); k.stop()\n"
+        "assert not xb._backends, list(xb._backends)\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=120,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert r.returncode == 0, r.stderr[-2000:]

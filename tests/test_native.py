@@ -11,6 +11,31 @@ def test_native_lib_compiles():
     assert native.get_lib() is not None, "g++ toolchain present; must build"
 
 
+def test_concurrent_builds_each_load_a_whole_library(tmp_path):
+    """Six xdist workers used to build to ONE temp name and race on the
+    rename; now every process builds to its own name and all load."""
+    import os
+    import subprocess
+    import sys
+    code = (
+        "import os, sys\n"
+        "from matrixone_tpu import native as N\n"
+        f"N._BUILD_DIR = {str(tmp_path)!r}\n"
+        "N._SO = os.path.join(N._BUILD_DIR, 'libmo_native.so')\n"
+        "assert N._compile()\n"
+        "lib = N.get_lib()\n"
+        "assert lib is not None and lib.mo_bitset_count\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    procs = [subprocess.Popen([sys.executable, "-c", code], env=env,
+                              cwd=root, stderr=subprocess.PIPE, text=True)
+             for _ in range(4)]
+    for p in procs:
+        _, err = p.communicate(timeout=300)
+        assert p.returncode == 0, err[-2000:]
+    assert os.listdir(tmp_path) == ["libmo_native.so"]   # no stray temps
+
+
 def test_hash64_matches_device_and_fallback(rng):
     vals = rng.integers(-2**62, 2**62, 1000)
     h_native = native.hash64(vals)

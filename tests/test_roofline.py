@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from matrixone_tpu.utils import roofline
 
@@ -17,9 +18,34 @@ def test_cost_of_matmul():
     assert c["bytes"] >= 0
 
 
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_peaks_keyed_by_device_kind():
+    v5e = roofline.peaks(_Dev("tpu", "TPU v5 lite"))
+    assert v5e["flops"] == 197e12 and v5e["bytes_per_s"] == 819e9
+    assert roofline.peaks(_Dev("cpu", "cpu")) is None
+    assert roofline.peaks() is None            # the test rig is CPU
+
+
+def test_unknown_device_kind_is_an_error_not_a_default():
+    with pytest.raises(KeyError, match="TPU v9"):
+        roofline.peaks(_Dev("tpu", "TPU v9"))
+
+
+def test_mfu_has_no_share_on_cpu():
+    out = roofline.mfu(flops_per_call=1e12, bytes_per_call=1e9,
+                       calls=10, seconds=1.0)
+    assert out["achieved_tflops"] == 10.0
+    assert out["mfu"] is None and out["hbm_util"] is None
+    assert "bound" not in out
+
+
 def test_mfu_fields(monkeypatch):
-    monkeypatch.setenv("MO_PEAK_TFLOPS", "100")
-    monkeypatch.setenv("MO_PEAK_GBPS", "800")
+    monkeypatch.setattr(roofline, "peaks", lambda: {
+        "flops": 100e12, "bytes_per_s": 800e9})
     out = roofline.mfu(flops_per_call=1e12, bytes_per_call=1e9,
                        calls=10, seconds=1.0)
     assert out["achieved_tflops"] == 10.0

@@ -36,6 +36,32 @@ def test_ddl_dml_roundtrip(server):
     c.close()
 
 
+@pytest.mark.parametrize("x, y", [
+    ("266581.321", "266581.321"),      # product's scaled int > 2^53
+    ("-300000.001", "300239.977"),
+    ("0.001", "0.001"), ("2.500", "5.000"), ("1.000", "7.000")])
+def test_decimal_is_exact_on_the_wire(server, x, y):
+    """A DECIMAL goes out digit for digit from its scaled integer: a
+    detour through float loses digits past 2^53 (an SF1 TPC-H sum), and
+    small values keep the shape they always had (trailing zeros dropped,
+    one fractional digit kept)."""
+    from decimal import Decimal
+    want = Decimal(x) * Decimal(y)
+    text = format(want, "f").rstrip("0")
+    text += "0" if text.endswith(".") else ""
+    c = client.connect(port=server.port)
+    c.execute("create table if not exists bigdec (id bigint primary key"
+              " auto_increment, x decimal(18,3), y decimal(18,3))")
+    c.execute(f"insert into bigdec (x, y) values ({x}, {y})")
+    _, rows = c.query("select x * y from bigdec order by id desc limit 1")
+    assert rows == [(text,)] and Decimal(rows[0][0]) == want
+    stmt = c.prepare("select x * y from bigdec where id > ? order by id"
+                     " desc limit 1")
+    _, rows, _ = stmt.execute(0)
+    assert rows == [(text,)]
+    c.close()
+
+
 def test_error_packet(server):
     c = client.connect(port=server.port)
     with pytest.raises(client.MySQLError, match="no such table"):

@@ -156,14 +156,12 @@ def check(bench_dir: str, tolerance: float = 0.2):
                        for be, v in per_be.items()}
         except (OSError, ValueError, TypeError) as e:
             report.append(f"WARN unreadable {floors_path}: {e}")
-    if len(rounds) < 2 and not floors:
+    if not rounds or (len(rounds) < 2 and not floors):
+        # no round record (none has been taken on a chip yet) or a
+        # single one without floors: nothing to hold anything to
         report.append(f"bench_guard: only {len(rounds)} readable round(s)"
                       f" in {bench_dir}; nothing to compare")
         return True, report
-    if not rounds:
-        report.append(f"bench_guard: no readable BENCH_*.json in "
-                      f"{bench_dir}")
-        return False, report
     latest_n, latest_name, latest = rounds[-1]
     best: dict = {}
     for n, name, ms in rounds[:-1]:
