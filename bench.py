@@ -142,24 +142,6 @@ def bench_q1(n: int = None) -> dict:
         best_res = max(best_res, n / (time.time() - t0))
     cache_res = blockcache.CACHE.stats()
     dev_tier = cache_res["device_tier"]
-    # ---- per-stage device vs host split: one diagnostic re-execution
-    # with the fragment's profile hooks armed (block_until_ready around
-    # the compiled step, host bookkeeping timed separately)
-    dev0 = M.fusion_step_seconds.get(kind="device")
-    host0 = M.fusion_step_seconds.get(kind="host")
-    profile_was = os.environ.get("MO_FUSION_PROFILE")
-    os.environ["MO_FUSION_PROFILE"] = "1"
-    try:
-        s.execute(tpch.Q1_SQL)
-    finally:
-        if profile_was is None:
-            os.environ.pop("MO_FUSION_PROFILE", None)
-        else:
-            os.environ["MO_FUSION_PROFILE"] = profile_was
-    stage_device_s = round(
-        M.fusion_step_seconds.get(kind="device") - dev0, 4)
-    stage_host_s = round(
-        M.fusion_step_seconds.get(kind="host") - host0, 4)
     # ---- the pre-fusion per-operator path, kept as its own
     # non-comparable metric family (same convention as the r04->r05
     # object-backed methodology split): trends continue for both
@@ -302,8 +284,6 @@ def bench_q1(n: int = None) -> dict:
         "exact_vs_oracle": exact,
         "fused_dispatches": int(fused_dispatches),
         "trace_seconds": round(trace_seconds, 4),
-        "stage_device_seconds": stage_device_s,
-        "stage_host_seconds": stage_host_s,
         "fused_over_unfused": (round(best / best_unfused, 2)
                                if best_unfused else None),
         "load_seconds": round(t_load, 2),

@@ -100,7 +100,7 @@ def l2_distance_sq_pallas(x: jnp.ndarray, q: jnp.ndarray,
     qf = q.astype(jnp.float32)
     q2 = jnp.sum(qf * qf, axis=1)[None, :]          # [1, b]
     grid = (n // tile_m,)
-    return pl.pallas_call(
+    kernel = pl.pallas_call(
         _l2_kernel,
         grid=grid,
         in_specs=[
@@ -111,7 +111,9 @@ def l2_distance_sq_pallas(x: jnp.ndarray, q: jnp.ndarray,
         out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
         interpret=interpret,
-    )(xf, qf, q2)
+    )
+    with jax.named_scope("l2_distance_sq_pallas"):
+        return kernel(xf, qf, q2)
 
 
 # -------------------------------------- pairwise L2 with fused prefilter
@@ -147,7 +149,7 @@ def l2_distance_sq_masked_pallas(x: jnp.ndarray, q: jnp.ndarray,
     qf = q.astype(jnp.float32)
     q2 = jnp.sum(qf * qf, axis=1)[None, :]
     m2 = mask.astype(jnp.int32)[:, None]            # [n, 1]
-    return pl.pallas_call(
+    kernel = pl.pallas_call(
         _l2_masked_kernel,
         grid=(n // tile_m,),
         in_specs=[
@@ -159,7 +161,9 @@ def l2_distance_sq_masked_pallas(x: jnp.ndarray, q: jnp.ndarray,
         out_specs=pl.BlockSpec((tile_m, b), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((n, b), jnp.float32),
         interpret=interpret,
-    )(xf, qf, q2, m2)
+    )
+    with jax.named_scope("l2_distance_sq_masked_pallas"):
+        return kernel(xf, qf, q2, m2)
 
 
 # ------------------------------------------------ GROUP BY segment sum
@@ -210,7 +214,7 @@ def segment_sum_pallas(values: jnp.ndarray, gids: jnp.ndarray,
     # real group cannot resurrect them (id G sums into nothing: the iota
     # comparison never matches because iota < G)
     g = jnp.where(mask, gids.astype(jnp.int32), num_segments)[None, :]
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         _segsum_kernel,
         grid=(n // tile_n,),
         in_specs=[
@@ -220,8 +224,9 @@ def segment_sum_pallas(values: jnp.ndarray, gids: jnp.ndarray,
         out_specs=pl.BlockSpec((1, num_segments), lambda i: (_Z, _Z)),
         out_shape=jax.ShapeDtypeStruct((1, num_segments), jnp.float32),
         interpret=interpret,
-    )(v, g)
-    return out[0]
+    )
+    with jax.named_scope("segment_sum_pallas"):
+        return kernel(v, g)[0]
 
 
 # --------------------------------------- hash-join probe sorted search
@@ -286,7 +291,7 @@ def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
         q64 = jnp.pad(q64, (0, pad_m))
     shi, slo = _sign_flip_halves(s64)
     qhi, qlo = _sign_flip_halves(q64)
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         _sorted_search_kernel,
         grid=(q64.shape[0] // tile_q, s64.shape[0] // tile_n),
         in_specs=[
@@ -298,7 +303,10 @@ def sorted_search_pallas(sorted_vals: jnp.ndarray, queries: jnp.ndarray,
         out_specs=pl.BlockSpec((1, tile_q), lambda qi, ni: (_Z, qi)),
         out_shape=jax.ShapeDtypeStruct((1, q64.shape[0]), jnp.int32),
         interpret=interpret,
-    )(shi[None, :], slo[None, :], qhi[None, :], qlo[None, :])
+    )
+    with jax.named_scope("sorted_search_pallas"):
+        out = kernel(shi[None, :], slo[None, :], qhi[None, :],
+                     qlo[None, :])
     return out[0][:m]
 
 
@@ -341,7 +349,7 @@ def adc_score_pallas(codes: jnp.ndarray, lut: jnp.ndarray,
     assert lut.shape == (g, m, 256), lut.shape
     _note_trace("adc_score_pallas", interpret)
     c32 = codes.astype(jnp.int32)
-    out = pl.pallas_call(
+    kernel = pl.pallas_call(
         _adc_kernel,
         grid=(g, p // tile_c),
         in_specs=[
@@ -355,5 +363,7 @@ def adc_score_pallas(codes: jnp.ndarray, lut: jnp.ndarray,
                                lambda gi, ci: (gi, _Z, ci)),
         out_shape=jax.ShapeDtypeStruct((g, 1, p), jnp.float32),
         interpret=interpret,
-    )(c32, lut.astype(jnp.float32))
+    )
+    with jax.named_scope("adc_score_pallas"):
+        out = kernel(c32, lut.astype(jnp.float32))
     return out[:, 0, :]

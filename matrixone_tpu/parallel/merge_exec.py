@@ -87,7 +87,7 @@ def _general_merge(states, aggs, psig):
     key = ("general", n_sh, mgs, mg_out, kdts, fl, fdts, "shard", psig)
 
     def build():
-        def run(keys_ss, kvalid_ss, present_s, fields_ss):
+        def merge_general(keys_ss, kvalid_ss, present_s, fields_ss):
             kd = [jnp.concatenate(ks) for ks in keys_ss]
             kv = [jnp.concatenate(vs) for vs in kvalid_ss]
             mask = jnp.concatenate(present_s)
@@ -102,7 +102,7 @@ def _general_merge(states, aggs, psig):
                     for f, arrs in zip(fs, per_field)))
             return (tuple(rep_k), tuple(rep_v), present, tuple(outs),
                     gi.num_groups)
-        return jax.jit(run)
+        return jax.jit(merge_general)
 
     def deps():
         return {"mesh_shape": (n_sh,), "shard_axis": "shard",
@@ -151,11 +151,11 @@ def _dense_merge(helper, denses, psig):
     def build():
         mesh = make_mesh(n_sh)
 
-        def body(*cols):
+        def merge_dense(*cols):
             return tuple(jax.lax.psum(c[0], "shard") for c in cols)
 
         return jax.shard_map(
-            body, mesh=mesh,
+            merge_dense, mesh=mesh,
             in_specs=tuple([P("shard")] * len(dts)),
             out_specs=tuple([P()] * len(dts)))
 

@@ -186,12 +186,19 @@ class BlockCache:
         """host array -> device array, admitted to the device tier
         under its budget (skipped when the budget is 0 — the array is
         still returned, it just isn't pinned)."""
+        from matrixone_tpu.utils import motrace
+        with motrace.span("blockcache.upload"):
+            return self._upload(key, host_value)
+
+    def _upload(self, key: tuple, host_value):
         import jax.numpy as jnp
+        from matrixone_tpu.utils import motrace
         dev = jnp.asarray(host_value)
         nb = int(dev.nbytes)
         budget = _device_budget_bytes()
         with self._lock:
             san.mutating(self)
+            evicted0 = self.dev_evictions
             self.uploaded_bytes += nb
             if budget > 0 and key not in self._dev:
                 while self._dev and self.dev_used_bytes + nb > budget:
@@ -204,6 +211,8 @@ class BlockCache:
                 self.dev_peak_bytes = max(self.dev_peak_bytes,
                                           self.dev_used_bytes)
             self._note_peak_locked()
+            evicted = self.dev_evictions - evicted0
+        motrace.annotate(bytes=nb, evicted=evicted)
         _metrics_upload(nb)
         return dev
 
@@ -372,41 +381,47 @@ class _ObjectSource:
                             count=False)   # recheck: not a second miss
             if got is not None:
                 return got
-            from matrixone_tpu.storage import objectio
-            from matrixone_tpu.utils import metrics as M
-            t0 = time.perf_counter()
-            raw = self._header()
-            if raw.get("v", 1) < 2:
-                # legacy whole-IPC object: one decode populates EVERY
-                # column (a per-column loop would re-download the full
-                # object per column)
-                _m, a_all, v_all = objectio.read_object(self.fs,
-                                                        self.path)
-                if col not in a_all:
-                    raise KeyError(
-                        f"column {col!r} not in object {self.path}")
-                out = None
-                for c in a_all:
-                    d = CACHE.put((self._tok, self.path, c, "data"),
-                                  a_all[c])
-                    v = CACHE.put((self._tok, self.path, c, "validity"),
-                                  v_all[c])
-                    if c == col:
-                        out = d if kind == "data" else v
-                    self._account(d, v)
-                self._account_time(t0, M)
-                return out
-            if col not in raw["cols"]:
+            from matrixone_tpu.utils import motrace
+            with motrace.span("blockcache.load", col=col):
+                return self._load(col, kind)
+
+    def _load(self, col: str, kind: str) -> np.ndarray:
+        """The miss path (under `_load_lock`): read, decode, admit to
+        both tiers."""
+        from matrixone_tpu.storage import objectio
+        from matrixone_tpu.utils import metrics as M
+        t0 = time.perf_counter()
+        raw = self._header()
+        if raw.get("v", 1) < 2:
+            # legacy whole-IPC object: one decode populates EVERY
+            # column (a per-column loop would re-download the full
+            # object per column)
+            _m, a_all, v_all = objectio.read_object(self.fs, self.path)
+            if col not in a_all:
                 raise KeyError(
                     f"column {col!r} not in object {self.path}")
-            data, valid = objectio.read_column_block(self.fs, self.path,
-                                                     raw, col)
-            data = CACHE.put((self._tok, self.path, col, "data"), data)
-            valid = CACHE.put((self._tok, self.path, col, "validity"),
-                              valid)
-            self._account(data, valid)
+            out = None
+            for c in a_all:
+                d = CACHE.put((self._tok, self.path, c, "data"),
+                              a_all[c])
+                v = CACHE.put((self._tok, self.path, c, "validity"),
+                              v_all[c])
+                if c == col:
+                    out = d if kind == "data" else v
+                self._account(d, v)
             self._account_time(t0, M)
-            return data if kind == "data" else valid
+            return out
+        if col not in raw["cols"]:
+            raise KeyError(
+                f"column {col!r} not in object {self.path}")
+        data, valid = objectio.read_column_block(self.fs, self.path,
+                                                 raw, col)
+        data = CACHE.put((self._tok, self.path, col, "data"), data)
+        valid = CACHE.put((self._tok, self.path, col, "validity"),
+                          valid)
+        self._account(data, valid)
+        self._account_time(t0, M)
+        return data if kind == "data" else valid
 
     def _account(self, data, valid) -> None:
         nb = int(data.nbytes) + int(valid.nbytes)

@@ -464,12 +464,13 @@ class MVCCTable:
                     ) -> Iterator[tuple]:
         """Yield (arrays, validity, dicts, n) merging committed segments
         visible at snapshot_ts with txn-local segments/deletes."""
+        from matrixone_tpu.utils import metrics as M, motrace
         want_rowid = ROWID in columns
         data_cols = [c for c in columns if c != ROWID]
         src_segs, src_tombs = self._view_at(snapshot_ts)
         dead = self._dead_gids(snapshot_ts, extra_deletes, src_tombs)
-        have_dead = len(dead) > 0
-        if have_dead:
+        dead_filter = None
+        if len(dead) > 0:
             # tombstones as a compressed bitmap built ONCE per scan: a
             # chunk's gids are a contiguous range, so the per-chunk
             # membership test is one container walk instead of an
@@ -501,36 +502,62 @@ class MVCCTable:
             if filters and seg.zonemaps is not None and \
                     _seg_zonemap_excludes(filters, seg.zonemaps,
                                           seg.n_rows, qmap):
+                M.scan_chunks.inc(-(-seg.n_rows // batch_rows),
+                                  outcome="pruned_segment")
                 continue
             for start in range(0, seg.n_rows, batch_rows):
                 end = min(start + batch_rows, seg.n_rows)
-                gids = np.arange(seg.base_gid + start, seg.base_gid + end,
-                                 dtype=np.int64)
-                keep = None
-                if have_dead:
-                    keep = ~dead_filter.test_range(seg.base_gid + start,
-                                                   seg.base_gid + end)
-                    if not keep.any():
-                        continue
-                arrays, validity = {}, {}
-                for c in data_cols:
-                    a = seg.arrays[c][start:end]
-                    v = seg.validity[c][start:end]
-                    if keep is not None and not keep.all():
-                        a, v = a[keep], v[keep]
-                    arrays[c] = a
-                    validity[c] = v
-                if want_rowid:
-                    g = gids if keep is None or keep.all() else gids[keep]
-                    arrays[ROWID] = g
-                    validity[ROWID] = np.ones(len(g), np.bool_)
-                n = len(next(iter(arrays.values()))) if arrays else 0
-                if n == 0:
-                    continue
-                if filters and _zonemap_excludes(filters, arrays, validity,
-                                                 qmap, dict(self.meta.schema)):
-                    continue
-                yield arrays, validity, self.dicts, n
+                # the span ends before the yield (motrace's rule for
+                # generators): the consumer's work is not the scan's
+                with motrace.span("scan.chunk", table=self.meta.name):
+                    chunk = self._read_chunk(
+                        seg, start, end, data_cols, want_rowid,
+                        dead_filter, filters, qmap)
+                if chunk is not None:
+                    yield chunk
+
+    def _read_chunk(self, seg, start: int, end: int, data_cols,
+                    want_rowid: bool, dead_filter, filters, qmap):
+        """One chunk of one segment: column lookups (which fetch, decode
+        and upload what the block cache misses), slices, the tombstone
+        mask and the chunk's own zonemap check.  -> (arrays, validity,
+        dicts, n), or None when the chunk has nothing to scan."""
+        from matrixone_tpu.utils import metrics as M, motrace
+        gids = np.arange(seg.base_gid + start, seg.base_gid + end,
+                         dtype=np.int64)
+        keep = None
+        if dead_filter is not None:
+            keep = ~dead_filter.test_range(seg.base_gid + start,
+                                           seg.base_gid + end)
+            if not keep.any():
+                M.scan_chunks.inc(outcome="all_dead")
+                return None
+        arrays, validity = {}, {}
+        for c in data_cols:
+            a = seg.arrays[c][start:end]
+            v = seg.validity[c][start:end]
+            if keep is not None and not keep.all():
+                a, v = a[keep], v[keep]
+            arrays[c] = a
+            validity[c] = v
+        if want_rowid:
+            g = gids if keep is None or keep.all() else gids[keep]
+            arrays[ROWID] = g
+            validity[ROWID] = np.ones(len(g), np.bool_)
+        n = len(next(iter(arrays.values()))) if arrays else 0
+        if n == 0:
+            return None
+        if filters:
+            with motrace.span("scan.zonemap"):
+                pruned = _zonemap_excludes(filters, arrays, validity,
+                                           qmap, dict(self.meta.schema))
+                motrace.annotate(pruned=pruned)
+            if pruned:
+                M.scan_chunks.inc(outcome="pruned_chunk")
+                return None
+        M.scan_chunks.inc(outcome="scanned")
+        motrace.annotate(rows=n)
+        return arrays, validity, self.dicts, n
 
     def scan_is_cold(self, columns: List[str]) -> bool:
         """True when a scan of `columns` would miss the decoded-column
@@ -718,11 +745,20 @@ def _zm_range_excludes(op, lo, hi, lv) -> bool:
 
 
 def _zonemap_excludes(filters, arrays, validity, qmap, schema) -> bool:
+    """Chunk-level prune on the chunk's own values.  Columns of an
+    object-backed segment are device arrays (the block cache's device
+    tier): each `.all()` and each min/max comparison is then a small
+    eager program and a wait for its answer, counted as such."""
+    from matrixone_tpu.utils import metrics as M
     for raw, op, col, lit in _zm_predicates(filters, qmap):
         if raw not in arrays:
             continue
         v = validity[raw]
-        vals = arrays[raw] if v.all() else arrays[raw][v]
+        on_device = not isinstance(v, np.ndarray)
+        all_valid = bool(v.all())
+        if on_device:
+            M.device_wait.inc(site="zonemap")
+        vals = arrays[raw] if all_valid else arrays[raw][v]
         if len(vals) == 0:
             return True
         if vals.ndim != 1:
@@ -730,7 +766,10 @@ def _zonemap_excludes(filters, arrays, validity, qmap, schema) -> bool:
         lv = _zm_normalize_lit(col, lit)
         if lv is None:
             continue
-        if _zm_range_excludes(op, vals.min(), vals.max(), lv):
+        excluded = _zm_range_excludes(op, vals.min(), vals.max(), lv)
+        if on_device:
+            M.device_wait.inc(site="zonemap")
+        if excluded:
             return True
     return False
 

@@ -230,26 +230,35 @@ def read_object(fs: FileService, path: str
                 ) -> Tuple[ObjectMeta, Dict[str, np.ndarray],
                            Dict[str, np.ndarray]]:
     """Full object read (v1 whole-IPC objects and v2 per-column)."""
+    from matrixone_tpu.utils import metrics as M, motrace
     from matrixone_tpu.utils.fault import INJECTOR
     if INJECTOR.trigger("object.read") == "fail":
         raise IOError(f"fault injected: object.read {path}")
-    blob = fs.read(path)
-    meta, raw, body = _parse_header(blob)
-    if raw.get("v", 1) < 2:
-        if raw.get("codec") == "zlib":
-            body = zlib.decompress(body)
-        arrays, validity = arrowio.ipc_to_arrays(body)
+    with motrace.span("object.read"):
+        blob = fs.read(path)
+        motrace.annotate(bytes=len(blob))
+    M.object_read_bytes.inc(len(blob))
+    with motrace.span("object.decode"):
+        meta, raw, body = _parse_header(blob)
+        if raw.get("v", 1) < 2:
+            if raw.get("codec") == "zlib":
+                body = zlib.decompress(body)
+            motrace.annotate(bytes_out=len(body))
+            arrays, validity = arrowio.ipc_to_arrays(body)
+            return meta, arrays, validity
+        arrays: Dict[str, np.ndarray] = {}
+        validity: Dict[str, np.ndarray] = {}
+        bytes_out = 0
+        for c, ent in raw["cols"].items():
+            off, ln, codec = ent[0], ent[1], ent[2]
+            raw_len = ent[3] if len(ent) > 3 else None
+            ipc = _decompress(body[off:off + ln], codec, raw_len)
+            bytes_out += len(ipc)
+            a, v = arrowio.ipc_to_arrays(ipc)
+            arrays[c] = a[c]
+            validity[c] = v[c]
+        motrace.annotate(bytes_out=bytes_out)
         return meta, arrays, validity
-    arrays: Dict[str, np.ndarray] = {}
-    validity: Dict[str, np.ndarray] = {}
-    for c, ent in raw["cols"].items():
-        off, ln, codec = ent[0], ent[1], ent[2]
-        raw_len = ent[3] if len(ent) > 3 else None
-        ipc = _decompress(body[off:off + ln], codec, raw_len)
-        a, v = arrowio.ipc_to_arrays(ipc)
-        arrays[c] = a[c]
-        validity[c] = v[c]
-    return meta, arrays, validity
 
 
 #: header prefetch size for ranged reads: covers the JSON meta of any
@@ -261,12 +270,16 @@ def read_header_ranged(fs: FileService, path: str) -> Tuple[ObjectMeta,
                                                             dict]:
     """Header-only read via ranged fetch: the zonemap-prune fast path
     that never downloads column bytes (reference: objectio meta reads)."""
-    head = fs.read_range(path, 0, _HDR_PREFETCH)
-    assert head[:4] == _MAGIC, "bad object magic"
-    (mlen,) = struct.unpack("<I", head[4:8])
-    if len(head) < 8 + mlen:
-        head = head + fs.read_range(path, len(head),
-                                    8 + mlen - len(head))
+    from matrixone_tpu.utils import metrics as M, motrace
+    with motrace.span("object.read"):
+        head = fs.read_range(path, 0, _HDR_PREFETCH)
+        assert head[:4] == _MAGIC, "bad object magic"
+        (mlen,) = struct.unpack("<I", head[4:8])
+        if len(head) < 8 + mlen:
+            head = head + fs.read_range(path, len(head),
+                                        8 + mlen - len(head))
+        motrace.annotate(bytes=len(head))
+    M.object_read_bytes.inc(len(head))
     raw = json.loads(head[8:8 + mlen].decode())
     raw["_body_off"] = 8 + mlen
     return _meta_from_raw(raw), raw
@@ -277,15 +290,20 @@ def read_column_block(fs: FileService, path: str, raw: dict, col: str
     """Fetch one column of a v2 object given its PARSED header `raw`
     (from read_header_ranged — callers cache it so N column fetches
     cost N ranged reads, not 2N). Returns (data, validity)."""
+    from matrixone_tpu.utils import metrics as M, motrace
     from matrixone_tpu.utils.fault import INJECTOR
     if INJECTOR.trigger("object.read") == "fail":
         raise IOError(f"fault injected: object.read {path}")
     ent = raw["cols"][col]
     off, ln, codec = ent[0], ent[1], ent[2]
     raw_len = ent[3] if len(ent) > 3 else None
-    ipc = _decompress(fs.read_range(path, raw["_body_off"] + off, ln),
-                      codec, raw_len)
-    a, v = arrowio.ipc_to_arrays(ipc)
+    with motrace.span("object.read", bytes=ln):
+        stored = fs.read_range(path, raw["_body_off"] + off, ln)
+    M.object_read_bytes.inc(len(stored))
+    with motrace.span("object.decode"):
+        ipc = _decompress(stored, codec, raw_len)
+        motrace.annotate(bytes_out=len(ipc))
+        a, v = arrowio.ipc_to_arrays(ipc)
     return a[col], v[col]
 
 

@@ -272,12 +272,31 @@ blockcache_upload_bytes = REGISTRY.counter(
     "mo_blockcache_upload_bytes_total",
     "host->device bytes staged for cached columns (warm loops drive "
     "this to ~0)")
+object_read_bytes = REGISTRY.counter(
+    "mo_object_read_bytes_total",
+    "stored (compressed) bytes read from the file service by object "
+    "reads; over mo_blockcache_fetch_bytes_total it is what "
+    "compression saves, per row it is the read amplification")
 decode_seconds = REGISTRY.counter(
     "mo_object_decode_seconds_total",
     "seconds spent fetching+decoding object column blocks (miss path)")
 object_write_seconds = REGISTRY.counter(
     "mo_object_write_seconds_total",
     "seconds spent serializing+writing objectio objects")
+scan_chunks = REGISTRY.counter(
+    "mo_scan_chunks_total",
+    "chunks of table scans by outcome: scanned (handed to the "
+    "consumer), pruned_segment (every chunk of a segment excluded by "
+    "its stored zonemap, before any read), pruned_chunk (excluded by "
+    "the chunk's own min/max, after the read), all_dead (every row "
+    "deleted)")
+device_wait = REGISTRY.counter(
+    "mo_device_wait_total",
+    "host reads of a device value that block the statement's path, by "
+    "site: zonemap (chunk min/max/all-valid), flags (fused all-valid "
+    "flags), limit (fused LIMIT rows seen), finalize (the fused carry "
+    "handed to the result path, whose fetch is the statement's last "
+    "wait)")
 scan_prefetch = REGISTRY.counter(
     "mo_scan_prefetch_total",
     "scan read-ahead outcomes: chunks served ready vs waited-on")
@@ -377,10 +396,6 @@ fusion_trace_seconds = REGISTRY.counter(
 fusion_exec = REGISTRY.counter(
     "mo_fusion_exec_total",
     "fragment executions by mode (fused/eager/fallback/degraded)")
-fusion_step_seconds = REGISTRY.counter(
-    "mo_fusion_step_seconds_total",
-    "fused step wall seconds by kind (device vs host bookkeeping; "
-    "filled under MO_FUSION_PROFILE=1 diagnostic runs, bench.py)")
 
 pallas_traces = REGISTRY.counter(
     "mo_pallas_trace_total",

@@ -8,14 +8,44 @@ RPC wire.  Here the span tree covers the whole statement lifecycle:
       parse                      sql/parser via Session.execute
       run                        per-statement execution envelope
         admission.queue          serving/admission.py slot wait
-        fusion.compile           vm/fusion.py fragment trace+compile
-        fusion.dispatch          vm/fusion.py compiled step dispatch
+        plan                     bind + optimize on a plan-cache miss
+        scan.wait                vm/operators.py: the statement's thread
+                                 blocked on the scan prefetcher
+        scan.chunk               storage/engine.py iter_chunks, one per
+                                 chunk; on thread `mo-scan-prefetch`
+                                 when the scan is cold (see `bind`)
+          blockcache.load        storage/blockcache.py: one column of
+                                 one object brought in (miss path)
+            object.read          storage/objectio.py: bytes from the
+                                 file service
+            object.decode        decompress + Arrow IPC -> numpy
+            blockcache.upload    host column -> device tier
+          scan.zonemap           chunk-level min/max check and the
+                                 waits for the device's answers
+        scan.batch               vm/operators.py: chunk -> ExecBatch
+        fusion.compile           vm/fusion*.py fragment trace+compile
+        fusion.flags             all-valid flags program + its wait
+        fusion.dispatch          compiled step dispatch
+        fusion.finalize          carry -> result batch, limit check
+        vector.search            vm/vector_scan.py index search
+        vector.fetch             candidate rows by id (blockcache.*
+                                 below it after a re-open)
         rpc.call                 cluster/rpc.py (CN->TN commit, DDL, ...)
           tn.<op>                cluster/tn.py server-side handling
         worker.run               worker/client.py gRPC offload
           worker.<op>            worker/server.py server-side handling
         txn.commit               txn/client.py commit pipeline
         mview.apply              mview/maintain.py delta maintenance
+
+Rule for generators (kept by tests/test_motrace.py and the molint rule
+`span-hygiene`): a span in a generator wraps the work between two
+yields and NEVER a `yield`.  A span held open across a yield becomes
+the ambient parent of whatever the consumer opens next, and its
+duration counts the consumer's work.
+
+The one thread hop of the scan path (the prefetch thread) carries the
+trace context through `bind(fn)`; a new thread inherits no contextvars,
+and a span opened without a context is the no-op.
 
 Cross-process propagation rides the SAME wire header that already
 carries `deadline_ms`: `inject()` adds a compact `trace` entry
@@ -36,7 +66,8 @@ statement pays almost nothing either.
 
 Knobs: `MO_TRACE` (arm), `MO_TRACE_SAMPLE` (head-sampling fraction),
 `MO_TRACE_SLOW_MS` (auto-persist slow statements' full span tree into
-system_statement_info), `MO_TRACE_RING` (ring capacity, spans).
+system_statement_info), `MO_TRACE_RING` (ring capacity in spans,
+65,536 by default: about 0.6 KB a span, 40 MB when full).
 Ops surface: `SHOW TRACE`, `mo_ctl('trace', 'status|on|off|clear|'
 'sample:<f>|slow:<ms>|dump:<path>')`.
 """
@@ -47,6 +78,7 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -99,8 +131,12 @@ class Tracer:
         self.sample = _env_float("MO_TRACE_SAMPLE", 1.0)
         self.slow_ms = _env_float("MO_TRACE_SLOW_MS", 0.0)
         self.proc = "cn"
-        cap = int(_env_float("MO_TRACE_RING", 4096))
-        self._ring: deque = deque(maxlen=max(16, cap))
+        #: the ring: completed spans in arrival order, at most _cap
+        self._cap = max(16, int(_env_float("MO_TRACE_RING", 65536)))
+        self._ring: deque = deque()
+        #: the same records by trace id (each trace's spans in arrival
+        #: order), so a read costs one trace and not the ring
+        self._by_tid: Dict[str, deque] = {}
         self._lock = san.lock("motrace.Tracer._lock", internal=True)
 
     # ------------------------------------------------------------ control
@@ -118,6 +154,7 @@ class Tracer:
     def clear(self) -> None:
         with self._lock:
             self._ring.clear()
+            self._by_tid.clear()
 
     # ------------------------------------------------------------- record
     def record(self, rec: dict, sink: Optional[list] = None) -> None:
@@ -132,40 +169,44 @@ class Tracer:
             return
         M.trace_spans.inc(proc=rec["proc"])
         with self._lock:
-            if len(self._ring) == self._ring.maxlen:
+            if len(self._ring) >= self._cap:
+                # the ring's oldest span is its trace's oldest too
+                old = self._ring.popleft()
+                own = self._by_tid[old["tid"]]
+                own.popleft()
+                if not own:
+                    del self._by_tid[old["tid"]]
                 M.trace_ring_dropped.inc()
             self._ring.append(rec)
+            own = self._by_tid.get(rec["tid"])
+            if own is None:
+                own = self._by_tid[rec["tid"]] = deque()
+            own.append(rec)
 
     # -------------------------------------------------------------- reads
     def spans_of(self, trace_id: str) -> List[dict]:
         with self._lock:
-            return [r for r in self._ring if r["tid"] == trace_id]
+            return list(self._by_tid.get(trace_id, ()))
+
+    def span_count(self, trace_id: str) -> int:
+        with self._lock:
+            return len(self._by_tid.get(trace_id, ()))
 
     def trace_ids(self) -> List[str]:
-        """Distinct trace ids, oldest first."""
+        """Distinct trace ids, by the arrival of each trace's first
+        span: oldest first."""
         with self._lock:
-            seen, out = set(), []
-            for r in self._ring:
-                if r["tid"] not in seen:
-                    seen.add(r["tid"])
-                    out.append(r["tid"])
-            return out
+            return list(self._by_tid)
 
     def traces(self) -> List[dict]:
         """Per-trace summaries (SHOW TRACE), oldest first."""
         with self._lock:
-            rows: Dict[str, dict] = {}
-            for r in self._ring:
-                t = rows.setdefault(
-                    r["tid"], {"trace_id": r["tid"], "root": "",
-                               "spans": 0, "procs": set(),
-                               "ts_us": r["ts_us"], "dur_ms": 0.0})
-                t["spans"] += 1
-                t["procs"].add(r["proc"])
-                t["ts_us"] = min(t["ts_us"], r["ts_us"])
+            by_tid = {tid: list(own) for tid, own in self._by_tid.items()}
         out = []
-        for t in rows.values():
-            spans = self.spans_of(t["trace_id"])
+        for tid, spans in by_tid.items():
+            t = {"trace_id": tid, "root": "", "spans": len(spans),
+                 "procs": {s["proc"] for s in spans},
+                 "ts_us": min(s["ts_us"] for s in spans), "dur_ms": 0.0}
             ids = {s["sid"] for s in spans}
             roots = [s for s in spans if s["psid"] not in ids]
             if roots:
@@ -180,10 +221,10 @@ class Tracer:
     def status(self) -> dict:
         with self._lock:
             n = len(self._ring)
-            tids = len({r["tid"] for r in self._ring})
+            tids = len(self._by_tid)
         return {"armed": self.armed, "sample": self.sample,
                 "slow_ms": self.slow_ms, "proc": self.proc,
-                "ring_capacity": self._ring.maxlen,
+                "ring_capacity": self._cap,
                 "spans": n, "traces": tids}
 
 
@@ -205,14 +246,29 @@ class _NoopSpan:
 
 _NOOP = _NoopSpan()
 
+_ANNOTATION = None
+
+
+def _annotation():
+    """`jax.profiler.TraceAnnotation`, once jax is loaded (a process
+    that never imported jax has no profiler to write to)."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
 
 class _Span:
     """One recording span.  ONLY ever opened via `with` (molint rule
     span-hygiene) — enter/exit balance is what keeps the ambient
-    context stack and the ring consistent."""
+    context stack and the ring consistent.  While it is open it also
+    holds a `jax.profiler.TraceAnnotation` of its name, so a profile
+    of the process shows the program's spans on the profiler's own
+    clock beside the device's operations."""
 
     __slots__ = ("name", "attrs", "_tid", "_psid", "_sid", "_proc",
-                 "_sink", "_events", "_t0", "_token")
+                 "_sink", "_events", "_t0", "_token", "_twin")
 
     def __init__(self, name: str, trace_id: str, parent_sid: str,
                  proc: str, sink: Optional[list], attrs: dict):
@@ -226,9 +282,14 @@ class _Span:
         self._events: list = []
         self._t0 = 0
         self._token = None
+        self._twin = None
 
     def __enter__(self):
         self._t0 = time.time_ns()
+        annotation = _annotation()
+        if annotation is not None:
+            self._twin = annotation(self.name)
+            self._twin.__enter__()
         self._token = _CTX.set(_Ctx(self._tid, self._sid, self._proc,
                                     self._sink, self.attrs,
                                     self._events))
@@ -236,6 +297,8 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         _CTX.reset(self._token)
+        if self._twin is not None:
+            self._twin.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
         dur = time.time_ns() - self._t0
@@ -323,6 +386,30 @@ def annotate(**attrs) -> None:
 
 def current_ctx() -> Optional[_Ctx]:
     return _CTX.get()
+
+
+def bind(fn):
+    """`fn`, to be run on another thread under the caller's current
+    trace context: spans it opens there join the caller's trace as
+    children of the span open now.  A new thread inherits no
+    contextvars, and only motrace's context is carried (not
+    `contextvars.copy_context()`: nothing else the caller has set may
+    change what the other thread does).  Disarmed, or with no trace
+    active, it is `fn` itself."""
+    if not TRACER.armed:
+        return fn
+    ctx = _CTX.get()
+    if ctx is None:
+        return fn
+
+    def bound(*args, **kwargs):
+        token = _CTX.set(ctx)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _CTX.reset(token)
+
+    return bound
 
 
 # --------------------------------------------------- wire propagation
@@ -421,7 +508,7 @@ def trace_mark() -> int:
     ctx = _CTX.get()
     if ctx is None:
         return 0
-    return len(TRACER.spans_of(ctx.trace_id))
+    return TRACER.span_count(ctx.trace_id)
 
 
 def statement_record(dur_ms: float, since: int = 0):

@@ -770,6 +770,13 @@ class _ReplaySource(O.Operator):
             yield ex
 
 
+def _named(fn, name: str):
+    """`fn` under the name its compiled program carries: jit calls the
+    program `jit_<name>`, and that is the line a device trace prints."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 # =====================================================================
 # the fused fragment operator
 # =====================================================================
@@ -785,6 +792,8 @@ class FusedFragmentOp(O.Operator):
     #: prelude subclasses (join probe, window) build the chain's input
     #: batch in-trace — the child scan stays its own operator there
     _allow_scan_defer = True
+    #: first part of the step programs' names (`_step_name`)
+    _step_prefix = "frag"
 
     def __init__(self, source, stages: List[_Stage], agg_op, ctx,
                  fragment_id: int, sort_op=None):
@@ -988,26 +997,25 @@ class FusedFragmentOp(O.Operator):
         if self.last_stats["cache"] == "-":
             self.last_stats["cache"] = "miss"
 
-    def _dispatch_entry(self, entry, slot, args, profile=False):
+    def _dispatch_entry(self, entry, slot, args):
         """One compiled-program dispatch under the shared span/metric
-        discipline; profile mode syncs and attributes TRUE device time
-        to the span instead of async-dispatch time."""
+        discipline (the span is the host's time to enqueue the step;
+        the device's time is the program's event in a device trace)."""
         from matrixone_tpu.utils import metrics as M
         from matrixone_tpu.utils import motrace
         if self.last_stats["cache"] == "-":
             self.last_stats["cache"] = "hit"
-        t_dev0 = time.perf_counter()
-        with motrace.span("fusion.dispatch", slot=slot,
-                          profiled=profile):
+        with motrace.span("fusion.dispatch", slot=slot):
             out = entry["compiled"][slot](*args)
             M.fusion_dispatch.inc(kind="step")
             self.last_stats["dispatches"] += 1
-            if profile:
-                san.check_blocking("device.sync")
-                jax.block_until_ready(out)
-                M.fusion_step_seconds.inc(
-                    time.perf_counter() - t_dev0, kind="device")
         return out
+
+    def _step_name(self, slot: str) -> str:
+        """What a device trace calls the program of `slot` (after
+        `jit_`): Q1's grouped step and Q6's scalar steps get lines of
+        their own."""
+        return f"{self._step_prefix}_{self._terminal}_{slot}"
 
     def _initial_validity_colmap(self) -> dict:
         """name -> (source column set, flaggable) seed for the flag
@@ -1058,6 +1066,7 @@ class FusedFragmentOp(O.Operator):
         extra device program + host sync, identical in role to the
         unfused dense path's fused flag check."""
         from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import motrace
         node = self._agg_op.node
         flaggable = (self._keys_flaggable
                      or any(p and a.arg is not None
@@ -1071,7 +1080,9 @@ class FusedFragmentOp(O.Operator):
         if any(c not in cols for c in self._flag_cols):
             return False, tuple(a.arg is None for a in node.aggs)
         valids = tuple(cols[c].validity for c in self._flag_cols)
-        got = np.asarray(jax.device_get(_allvalid_flags(valids)))
+        with motrace.span("fusion.flags"):
+            got = np.asarray(jax.device_get(_allvalid_flags(valids)))
+        M.device_wait.inc(site="flags")
         M.fusion_dispatch.inc(kind="step")
         self.last_stats["dispatches"] += 1
         ok = dict(zip(self._flag_cols, (bool(x) for x in got)))
@@ -1303,7 +1314,7 @@ class FusedFragmentOp(O.Operator):
     def _execute_fused(self, first, src_iter, filters, rt_filters,
                        rt_info):
         from matrixone_tpu.utils import metrics as M
-        profile = os.environ.get("MO_FUSION_PROFILE") == "1"
+        from matrixone_tpu.utils import motrace
         self.last_stats["mode"] = "fused"
         M.fusion_exec.inc(mode="fused")
         node = self._agg_op.node if self._agg_op is not None else None
@@ -1322,7 +1333,6 @@ class FusedFragmentOp(O.Operator):
         trace_sizes: object = ()          # () = not yet pinned
         batches = itertools.chain([first], src_iter)
         for ex in batches:
-            t_host0 = time.perf_counter() if profile else 0.0
             envs = self._dict_envs(ex.dicts)
             sizes = None
             flags = None
@@ -1362,15 +1372,15 @@ class FusedFragmentOp(O.Operator):
             if fn is None:
                 trig = tuple((nm, c.dtype)
                              for nm, c in ex.batch.columns.items())
-                fn = self._make_step(trig, sizes, flags, envs,
-                                     scan_filters, rt_lift)
+                fn = _named(self._make_step(trig, sizes, flags, envs,
+                                            scan_filters, rt_lift),
+                            self._step_name(slot))
                 entry["fn"][slot] = fn
             out = None
             if not entry["failed"]:
                 compiled = entry["compiled"].get(slot)
                 if compiled is None:
                     t0 = time.perf_counter()
-                    from matrixone_tpu.utils import motrace
                     _fragment_step = fn
                     # donate the carry (arg 6) on accelerator
                     # backends: the step returns a new carry each
@@ -1400,11 +1410,7 @@ class FusedFragmentOp(O.Operator):
                             self._note_compiled(entry, slot, compiled,
                                                 t0)
                 if not entry["failed"]:
-                    if profile:
-                        M.fusion_step_seconds.inc(
-                            time.perf_counter() - t_host0, kind="host")
-                    out = self._dispatch_entry(entry, slot, args,
-                                               profile)
+                    out = self._dispatch_entry(entry, slot, args)
             if out is None:
                 # eager evaluation of the SAME step function — identical
                 # math, per-op dispatch (the pre-fusion cost model)
@@ -1421,15 +1427,29 @@ class FusedFragmentOp(O.Operator):
                 break
         if self._terminal == "stream":
             return
-        if self._terminal == "topk":
-            yield self._finalize_topk(carry)
-            return
-        yield self._finalize_agg(carry, trace_sizes, key_dicts)
+        yield self._finalize(carry, trace_sizes, key_dicts)
+
+    def _finalize(self, carry, sizes, key_dicts) -> ExecBatch:
+        """The carry of an aggregate or top-k terminal -> the result
+        batch (the caller yields it: no span is held across a yield)."""
+        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import motrace
+        with motrace.span("fusion.finalize"):
+            M.device_wait.inc(site="finalize")
+            if self._terminal == "topk":
+                return self._finalize_topk(carry)
+            return self._finalize_agg(carry, sizes, key_dicts)
 
     def _limits_satisfied(self, seens) -> bool:
+        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import motrace
         for st, s in zip(self._limit_stages, seens):
-            if st.n is not None and \
-                    int(jax.device_get(s)) >= st.offset + st.n:
+            if st.n is None:
+                continue
+            with motrace.span("fusion.finalize"):
+                seen = int(jax.device_get(s))
+            M.device_wait.inc(site="limit")
+            if seen >= st.offset + st.n:
                 return True
         return False
 
@@ -1475,9 +1495,10 @@ class FusedFragmentOp(O.Operator):
                 ex = ExecBatch(batch=DeviceBatch(columns=cols,
                                                  n_rows=n_rows),
                                dicts=env0, mask=mask)
-                for f in scan_filters:
-                    ex.mask = ex.mask & F.predicate_mask(
-                        eval_expr(f, ex), ex.batch)
+                with jax.named_scope("scan_filter"):
+                    for f in scan_filters:
+                        ex.mask = ex.mask & F.predicate_mask(
+                            eval_expr(f, ex), ex.batch)
                 return chain(ex, seens, carry)
 
         return _fragment_step
@@ -1505,19 +1526,21 @@ class FusedFragmentOp(O.Operator):
             keys_allvalid = with_null = None
             agg_flags = pos = None
 
-        def chain(ex, seens, carry):
+        def run_stages(ex, seens):
             out_seens: list = []
             li = 0
             env_i = 0
             for st in stages:
                 if st.kind == "filter":
-                    ex.mask = ex.mask & F.predicate_mask(
-                        eval_expr(st.pred, ex), ex.batch)
+                    with jax.named_scope("filter"):
+                        ex.mask = ex.mask & F.predicate_mask(
+                            eval_expr(st.pred, ex), ex.batch)
                 elif st.kind == "project":
                     env_i += 1
                     pcols = {}
-                    for (nm, _d), e in zip(st.schema, st.exprs):
-                        pcols[nm] = eval_expr(e, ex)
+                    with jax.named_scope("project"):
+                        for (nm, _d), e in zip(st.schema, st.exprs):
+                            pcols[nm] = eval_expr(e, ex)
                     ex = ExecBatch(
                         batch=DeviceBatch(columns=pcols,
                                           n_rows=ex.batch.n_rows),
@@ -1535,12 +1558,9 @@ class FusedFragmentOp(O.Operator):
                         seen + jnp.sum(ex.mask.astype(jnp.int64)))
                     ex = ExecBatch(ex.batch, ex.dicts, keep)
                     li += 1
-            if terminal == "stream":
-                ocols = list(ex.batch.columns.values())
-                payload = (tuple(c.data for c in ocols),
-                           tuple(c.validity for c in ocols),
-                           ex.mask)
-                return payload, tuple(out_seens)
+            return ex, out_seens
+
+        def fold(ex, carry, out_seens):
             if terminal == "agg_scalar":
                 sts = (carry if carry is not None
                        else [None] * len(node.aggs))
@@ -1668,6 +1688,18 @@ class FusedFragmentOp(O.Operator):
                     f_arr.at[pos].add(add.astype(f_arr.dtype)))
             new_rows = crows.at[pos].add(rows)
             return (tuple(new_fields), new_rows), tuple(out_seens)
+
+        def chain(ex, seens, carry):
+            ex, out_seens = run_stages(ex, seens)
+            if terminal == "stream":
+                ocols = list(ex.batch.columns.values())
+                payload = (tuple(c.data for c in ocols),
+                           tuple(c.validity for c in ocols),
+                           ex.mask)
+                return payload, tuple(out_seens)
+            with jax.named_scope("topk" if terminal == "topk"
+                                 else "aggregate"):
+                return fold(ex, carry, out_seens)
 
         return chain
 

@@ -27,7 +27,6 @@ traced output of the probe program — one host sync, no extra dispatch).
 from __future__ import annotations
 
 import itertools
-import os
 import time
 from typing import Dict, Iterator, List, Optional
 
@@ -90,6 +89,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
     traverse both unchanged."""
 
     _allow_scan_defer = False
+    _step_prefix = "frag_join"
 
     def __init__(self, join_op, stages, agg_op, probe_src, build_src,
                  ctx, fragment_id: int, sort_op=None):
@@ -400,7 +400,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
 
         fn = entry["fn"].get("build")
         if fn is None:
-            fn = _join_build_step
+            fn = FF._named(_join_build_step, "frag_join_build")
             entry["fn"]["build"] = fn
         args = (tuple(c.data for c in build.batch.columns.values()),
                 tuple(c.validity for c in build.batch.columns.values()),
@@ -425,9 +425,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                         compiled = lowered.compile()
                         self._note_compiled(entry, "build", compiled, t0)
             if not entry["failed"]:
-                out = self._dispatch_entry(
-                    entry, "build", args,
-                    os.environ.get("MO_FUSION_PROFILE") == "1")
+                out = self._dispatch_entry(entry, "build", args)
                 self.last_stats["build_dispatches"] += 1
         if out is None:
             out = fn(*args)
@@ -514,7 +512,6 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         from matrixone_tpu.utils import motrace
         self.last_stats["mode"] = "fused"
         M.fusion_exec.inc(mode="fused")
-        profile = os.environ.get("MO_FUSION_PROFILE") == "1"
         sorted_hash, border, bvalid, bkeys, build_key = bstate
         node = self._agg_op.node if self._agg_op is not None else None
         grouped = self._terminal == "agg_grouped"
@@ -530,7 +527,6 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         trace_sizes: object = ()
         batches = itertools.chain([first], probe_iter)
         for ex in batches:
-            t_host0 = time.perf_counter() if profile else 0.0
             envs = self._dict_envs(ex.dicts)
             sizes = None
             flags = None
@@ -583,8 +579,10 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                     slot = "step0" if carry is None else "stepN"
                 fn = entry["fn"].get(slot)
                 if fn is None:
-                    fn = self._make_probe_step(trig, bschema, sizes,
-                                               flags, envs, mm)
+                    fn = FF._named(
+                        self._make_probe_step(trig, bschema, sizes,
+                                              flags, envs, mm),
+                        self._step_name(slot))
                     entry["fn"][slot] = fn
                 args = (tuple(c.data
                               for c in ex.batch.columns.values()),
@@ -620,12 +618,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                 self._note_compiled(entry, slot,
                                                     compiled, t0)
                     if not entry["failed"]:
-                        if profile:
-                            M.fusion_step_seconds.inc(
-                                time.perf_counter() - t_host0,
-                                kind="host")
-                        out = self._dispatch_entry(entry, slot, args,
-                                                   profile)
+                        out = self._dispatch_entry(entry, slot, args)
                 if out is None:
                     out = fn(*args)
                     M.fusion_dispatch.inc(kind="eager")
@@ -646,10 +639,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                 break
         if self._terminal == "stream":
             return
-        if self._terminal == "topk":
-            yield self._finalize_topk(carry)
-            return
-        yield self._finalize_agg(carry, trace_sizes, key_dicts)
+        yield self._finalize(carry, trace_sizes, key_dicts)
 
     def _degrade_join_grouped(self, carry, sizes, key_dicts, build, ex,
                               rest):

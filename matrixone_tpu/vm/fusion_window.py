@@ -78,6 +78,7 @@ class FusedWindowOp(FF.FusedFragmentOp):
     single compiled program: window prelude + stages + terminal."""
 
     _allow_scan_defer = False
+    _step_prefix = "frag_window"
 
     def __init__(self, window_op, stages, agg_op, child_src, ctx,
                  fragment_id: int, sort_op=None):

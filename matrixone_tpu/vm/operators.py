@@ -78,16 +78,19 @@ class _ChunkPrefetcher:
     triggers the column fetch + decode through the blockcache — while
     the consumer's filter/agg compute runs over chunk N, so cold-read IO
     overlaps device compute. Exceptions propagate to the consumer;
-    closing stops the worker and closes the source generator."""
+    closing stops the worker and closes the source generator.  The
+    worker runs under the creator's trace context (`motrace.bind`), so
+    what it reads, decodes and uploads lands in the statement's trace."""
 
     _DONE, _ITEM, _ERR = 0, 1, 2
 
     def __init__(self, gen, depth: int):
         import queue
+        from matrixone_tpu.utils import motrace
         self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
         self._stop = threading.Event()
         self._thread = threading.Thread(
-            target=self._run, args=(gen,), daemon=True,
+            target=motrace.bind(self._run), args=(gen,), daemon=True,
             name="mo-scan-prefetch")
         self._thread.start()
 
@@ -118,11 +121,15 @@ class _ChunkPrefetcher:
                     continue
 
     def __iter__(self):
-        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import metrics as M, motrace
         while True:
             ready = not self._q.empty()
-            t0 = 0.0 if ready else time.perf_counter()
-            kind, payload = self._q.get()
+            if ready:
+                kind, payload = self._q.get()
+            else:
+                t0 = time.perf_counter()
+                with motrace.span("scan.wait"):
+                    kind, payload = self._q.get()
             if kind == self._DONE:
                 return
             if kind == self._ERR:
@@ -171,7 +178,7 @@ class ScanOp(Operator):
         still handed to iter_chunks (zonemap pruning) but NOT evaluated
         as an early row mask — a fused fragment (vm/fusion.py) folds
         them into its single traced program instead."""
-        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import metrics as M, motrace
         from matrixone_tpu.utils.fault import INJECTOR
         INJECTOR.trigger("scan.before")
         qnames = [n for n, _ in self.node.schema]
@@ -239,17 +246,18 @@ class ScanOp(Operator):
                     if moved:
                         M.exchange_shuffle_rows.inc(moved)
                 M.rows_scanned.inc(n, table=self.node.table)
-                ex = chunk_to_execbatch(arrays, validity, dicts, n,
-                                        self.node.columns,
-                                        self.node.schema)
-                # evaluate pushed filters as an early mask (zonemap
-                # pruning already dropped fully-excluded chunks
-                # host-side)
-                if apply_mask:
-                    for f in filters:
-                        pred = eval_expr(f, ex)
-                        ex.mask = ex.mask & F.predicate_mask(pred,
-                                                             ex.batch)
+                with motrace.span("scan.batch", rows=n):
+                    ex = chunk_to_execbatch(arrays, validity, dicts, n,
+                                            self.node.columns,
+                                            self.node.schema)
+                    # evaluate pushed filters as an early mask (zonemap
+                    # pruning already dropped fully-excluded chunks
+                    # host-side)
+                    if apply_mask:
+                        for f in filters:
+                            pred = eval_expr(f, ex)
+                            ex.mask = ex.mask & F.predicate_mask(
+                                pred, ex.batch)
                 yield ex
         finally:
             if prefetcher is not None:

@@ -62,8 +62,8 @@ class VectorTopKOp(Operator):
         return sidx
 
     def execute(self) -> Iterator[ExecBatch]:
-        from matrixone_tpu.vectorindex import ivf_flat, ivf_pq
         from matrixone_tpu import indexing
+        from matrixone_tpu.utils import motrace
         catalog = self.ctx.catalog
         ix = catalog.indexes[self.node.index_name]
         cache = getattr(catalog, "index_cache", None)
@@ -97,6 +97,25 @@ class VectorTopKOp(Operator):
                                      self.node.columns, self.node.schema)
             return
 
+        with motrace.span("vector.search"):
+            gids = self._search(ix, index, row_gids, delta_vecs,
+                                delta_gids)
+            motrace.annotate(rows=len(gids))
+        with motrace.span("vector.fetch", rows=len(gids)):
+            read_args = self.ctx.table_read_args(self.node.table)
+            gids = table.visible_gids(
+                gids, snapshot_ts=self.ctx.snapshot_ts,
+                extra_deletes=read_args.get("extra_deletes"))
+            arrays, validity = table.fetch_rows(gids, self.node.columns)
+            out = chunk_to_execbatch(arrays, validity, table.dicts,
+                                     len(gids), self.node.columns,
+                                     self.node.schema)
+        yield out
+
+    def _search(self, ix, index, row_gids, delta_vecs, delta_gids):
+        """The index search and the exact scan of the delta segment.
+        -> gids of the candidates, nearest first."""
+        from matrixone_tpu.vectorindex import ivf_flat, ivf_pq
         q = np.asarray([self.node.query_vector], dtype=np.float32)
         if ix.algo == "hnsw":
             from matrixone_tpu.vectorindex import hnsw
@@ -156,10 +175,4 @@ class VectorTopKOp(Operator):
             all_g = np.concatenate([gids, delta_gids])
             order = np.argsort(all_d)[:self.node.k]
             gids = all_g[order]
-        read_args = self.ctx.table_read_args(self.node.table)
-        gids = table.visible_gids(
-            gids, snapshot_ts=self.ctx.snapshot_ts,
-            extra_deletes=read_args.get("extra_deletes"))
-        arrays, validity = table.fetch_rows(gids, self.node.columns)
-        yield chunk_to_execbatch(arrays, validity, table.dicts, len(gids),
-                                 self.node.columns, self.node.schema)
+        return gids

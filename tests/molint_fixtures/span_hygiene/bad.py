@@ -1,5 +1,6 @@
-"""span-hygiene fixture: spans opened outside `with`, out-of-fabric
-injection, hand-built trace wire keys.  AST-only."""
+"""span-hygiene fixture: spans opened outside `with`, a span held across
+a `yield`, out-of-fabric injection, hand-built trace wire keys.
+AST-only."""
 
 from matrixone_tpu.utils import motrace
 
@@ -11,6 +12,12 @@ def leaky(work):
         return work()
     finally:
         sp.__exit__(None, None, None)
+
+
+def chunks(source):
+    for raw in source:
+        with motrace.span("chunk"):      # held open across the yield
+            yield raw.decode()
 
 
 def forked_propagation(client, header):

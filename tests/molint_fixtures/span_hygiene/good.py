@@ -15,6 +15,14 @@ def nested(work):
             return work()
 
 
+def chunks(source):
+    # the span wraps the work between two yields, never the yield
+    for raw in source:
+        with motrace.span("chunk"):
+            text = raw.decode()
+        yield text
+
+
 def server_side(header, dispatch):
     # remote_session is exempt from the with-only factory rule: the
     # session object carries attach()/harvest() by design

@@ -17,7 +17,7 @@ from matrixone_tpu.vm.fusion import FusedFragmentOp
 @pytest.fixture()
 def env():
     """Snapshot/restore the fusion env knobs around every test."""
-    keys = ("MO_PLAN_FUSION", "MO_FUSION_MIN_ROWS", "MO_FUSION_PROFILE")
+    keys = ("MO_PLAN_FUSION", "MO_FUSION_MIN_ROWS")
     saved = {k: os.environ.get(k) for k in keys}
     yield os.environ
     for k, v in saved.items():

@@ -708,6 +708,7 @@ def test_span_hygiene_fixtures():
     assert "outside a `with`" in msgs          # unbalanced enter/exit
     assert "outside the RPC fabric" in msgs    # forked injection
     assert "hand-built" in msgs                # clobbered wire key
+    assert "`yield` inside" in msgs            # span held across a yield
 
 
 def test_span_hygiene_good_fixture_uses_a_suppression():
@@ -740,6 +741,31 @@ def test_span_hygiene_planted_violation(tmp_path):
                  "    motrace.inject(wire)\n")
     findings2, _ = _run([str(q)], rules=["span-hygiene"])
     assert not findings2
+
+
+def test_span_hygiene_yield_inside_span(tmp_path):
+    """motrace's rule for generators: a `yield` (or `yield from`) in the
+    body of `with motrace.span(...)` fires; a nested generator's own
+    yield and a span that ends before the yield do not."""
+    p = tmp_path / "gen.py"
+    p.write_text("from matrixone_tpu.utils import motrace\n"
+                 "def held(src):\n"
+                 "    for x in src:\n"
+                 "        with motrace.span('held'):\n"
+                 "            yield x\n"
+                 "def delegated(src):\n"
+                 "    with motrace.span('delegated'), open('f'):\n"
+                 "        yield from src\n"
+                 "def clean(src):\n"
+                 "    for x in src:\n"
+                 "        with motrace.span('clean'):\n"
+                 "            y = [i for i in (lambda: (yield))()]\n"
+                 "            def inner():\n"
+                 "                yield x\n"
+                 "        yield y, inner\n")
+    findings, _ = _run([str(p)], rules=["span-hygiene"])
+    assert sorted(f.lineno for f in findings) == [5, 8]
+    assert all("`yield` inside" in f.message for f in findings)
 
 
 # --------------------------------------------------- framework perf (PR 14)

@@ -14,6 +14,11 @@ Covers the PR-12 acceptance surface:
     old-schema auto-recreate, flush-on-close;
   * Prometheus text exposition that a strict parser accepts, plus the
     Registry.snapshot()/Histogram.quantile public read API.
+
+And of the split of the `run` span (PR 27): the scan path's spans on
+the prefetch thread through `motrace.bind`, the generator rule, the
+ring's per-trace index under overflow, and the counters at the same
+boundaries.
 """
 
 import json
@@ -449,6 +454,234 @@ def test_mo_ctl_metrics_dump(sess):
     snap = json.loads(sess.execute(
         "select mo_ctl('metrics','snapshot')").rows()[0][0])
     assert snap["mo_query_duration_seconds"]["count"] > 0
+
+
+# ------------------------------------------ the split of `run` (PR 27)
+SCAN_ROWS = 2000          # rows a commit, two commits: two segments
+
+
+@pytest.fixture
+def cold(tmp_path, monkeypatch):
+    """A table of two object-backed segments (a = 0..1999, 2000..3999)
+    in an engine re-opened from its files, nothing of it in the block
+    cache, every batch on the fused path.  -> (session, clear): clear()
+    makes the next scan cold again."""
+    from matrixone_tpu.storage import blockcache
+    from matrixone_tpu.storage.fileservice import LocalFS
+    monkeypatch.setenv("MO_FUSION_MIN_ROWS", "0")
+    eng = Engine(LocalFS(str(tmp_path)))
+    s = Session(catalog=eng)
+    s.execute("create table sc (a bigint, b bigint)")
+    for c in range(2):
+        s.execute("insert into sc values " + ",".join(
+            f"({i},{i % 7})"
+            for i in range(c * SCAN_ROWS, (c + 1) * SCAN_ROWS)))
+    eng.checkpoint()
+    s.close()
+    eng.close()
+    blockcache.CACHE.clear()
+    s = Session(catalog=Engine.open(LocalFS(str(tmp_path))))
+    yield s, blockcache.CACHE.clear
+    s.close()
+    blockcache.CACHE.clear()
+
+
+def _last_trace(tracer):
+    spans = tracer.spans_of(tracer.trace_ids()[-1])
+    return spans, {sp["sid"]: sp for sp in spans}
+
+
+def test_cold_scan_spans_ride_the_prefetch_thread(tracer, cold):
+    """What the prefetch thread reads, decodes and uploads lands in the
+    statement's trace: `bind` carried the context across the hop."""
+    s, _clear = cold
+    tracer.clear()                # the fixture's own statements
+    assert s.execute("select sum(b) from sc where a >= 0").rows() \
+        == [(sum(i % 7 for i in range(2 * SCAN_ROWS)),)]
+    spans, by_sid = _last_trace(tracer)
+    assert len(tracer.trace_ids()) == 1
+    on_prefetch = {sp["name"] for sp in spans
+                   if sp["thread"] == "mo-scan-prefetch"}
+    assert {"scan.chunk", "blockcache.load", "object.read",
+            "object.decode", "blockcache.upload",
+            "scan.zonemap"} <= on_prefetch
+    names = {sp["name"] for sp in spans}
+    assert {"scan.wait", "scan.batch", "fusion.dispatch",
+            "fusion.finalize"} <= names
+    # the tree: run > scan.chunk > blockcache.load > read/decode/upload,
+    # scan.wait beside scan.chunk on the statement's own thread
+    parent = {sp["name"]: by_sid[sp["psid"]]["name"]
+              for sp in spans if sp["psid"] in by_sid}
+    assert parent["scan.chunk"] == parent["scan.wait"] == "run"
+    assert parent["blockcache.load"] == parent["scan.zonemap"] \
+        == "scan.chunk"
+    assert parent["object.read"] == parent["object.decode"] \
+        == parent["blockcache.upload"] == "blockcache.load"
+    waits = [sp for sp in spans if sp["name"] == "scan.wait"]
+    assert all(sp["thread"] != "mo-scan-prefetch" for sp in waits)
+    chunks = [sp for sp in spans if sp["name"] == "scan.chunk"]
+    assert [sp["attrs"]["rows"] for sp in chunks] == [SCAN_ROWS] * 2
+    assert all(sp["attrs"]["table"] == "sc" for sp in chunks)
+    # one load a column a segment, one upload for its data and one for
+    # its validity; the attributes are the bytes that moved
+    loads = [sp for sp in spans if sp["name"] == "blockcache.load"]
+    assert sorted(sp["attrs"]["col"] for sp in loads) == ["a", "a",
+                                                          "b", "b"]
+    uploads = [sp["attrs"]["bytes"] for sp in spans
+               if sp["name"] == "blockcache.upload"]
+    assert sorted(uploads) == [SCAN_ROWS] * 4 + [8 * SCAN_ROWS] * 4
+
+
+def test_generator_rule_no_span_is_held_across_a_yield(tracer, cold):
+    """A span in a generator wraps the work between two yields: what the
+    consumer opens (`fusion.*`, `scan.batch`) never hangs under a scan
+    span, and no scan span lasts into the consumer's work."""
+    s, clear = cold
+    for where in ("a >= 0", "a >= 1000"):
+        clear()
+        s.execute(f"select sum(b), count(*) from sc where {where}")
+        spans, by_sid = _last_trace(tracer)
+        fused = [sp for sp in spans if sp["name"].startswith("fusion.")]
+        assert any(sp["name"] == "fusion.dispatch" for sp in fused)
+        for sp in fused + [x for x in spans if x["name"] == "scan.batch"]:
+            assert by_sid[sp["psid"]]["name"] == "run", sp
+        # on one thread a child lies inside its parent: a span held
+        # across a yield would end after spans that are not its children
+        for sp in spans:
+            p = by_sid.get(sp["psid"])
+            if p is None or p["thread"] != sp["thread"]:
+                continue
+            assert p["ts_us"] <= sp["ts_us"]
+            assert sp["ts_us"] + sp["dur_us"] <= \
+                p["ts_us"] + p["dur_us"] + 1      # microsecond rounding
+
+
+def test_disarmed_scan_records_nothing_and_bind_is_identity(cold):
+    tr = motrace.TRACER
+    assert not tr.armed
+    tr.clear()
+    s, _clear = cold
+
+    def work():
+        return motrace.current_ctx()
+
+    assert motrace.bind(work) is work
+    s.execute("select sum(b) from sc where a >= 0")
+    assert tr.trace_ids() == [] and tr.status()["spans"] == 0
+
+
+def test_bind_carries_only_the_trace_context(tracer):
+    import contextvars
+    import threading
+    other = contextvars.ContextVar("other", default="unset")
+
+    def work():
+        with motrace.span("hop"):
+            pass
+        return other.get()
+
+    assert motrace.bind(work) is work           # armed, no trace active
+    got = []
+    with motrace.root_span("root") as root:
+        other.set("the caller's")
+        bound = motrace.bind(work)
+        assert bound is not work
+        t = threading.Thread(target=lambda: got.append(bound()),
+                             name="hop-thread")
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+    assert got == ["unset"]       # nothing but motrace's context crossed
+    spans, _ = _last_trace(tracer)
+    hop = [sp for sp in spans if sp["name"] == "hop"][0]
+    assert hop["thread"] == "hop-thread" and hop["psid"] == root._sid
+    assert motrace.current_ctx() is None
+
+
+def test_span_holds_a_profiler_annotation_while_open(tracer):
+    """Armed, a span enters a `jax.profiler.TraceAnnotation` of its name
+    (jax is loaded here), so a profile of the process holds the spans on
+    the profiler's own clock."""
+    import jax.profiler
+    with motrace.root_span("annotated") as sp:
+        assert isinstance(sp._twin, jax.profiler.TraceAnnotation)
+
+
+def test_ring_overflow_keeps_the_index_right(tracer, monkeypatch):
+    """Filled past its capacity the ring drops its oldest spans, and
+    `spans_of` / `trace_mark` / `statement_record` read what is left of
+    one trace through the index, not the ring."""
+    monkeypatch.setattr(tracer, "_cap", 32)
+    dropped0 = M.trace_ring_dropped.get()
+    with motrace.root_span("old"):
+        for _ in range(20):
+            with motrace.span("o"):
+                pass
+    old_tid = tracer.trace_ids()[0]
+    assert tracer.span_count(old_tid) == 21
+    with motrace.root_span("statement"):
+        tid = motrace.current_ctx().trace_id
+        for i in range(40):
+            with motrace.span("s", i=i):
+                pass
+        assert motrace.trace_mark() == 32       # the ring is all ours
+        tr_id, n, summary, _tree = motrace.statement_record(1.0, since=30)
+        assert (tr_id, n) == (tid, 2) and set(json.loads(summary)) == {"s"}
+    assert tracer.spans_of(old_tid) == []
+    assert tracer.trace_ids() == [tid]
+    spans = tracer.spans_of(tid)
+    assert [sp["attrs"].get("i") for sp in spans] \
+        == list(range(9, 40)) + [None]          # 31 children + the root
+    assert spans == list(tracer._ring)
+    st = tracer.status()
+    assert (st["spans"], st["traces"], st["ring_capacity"]) == (32, 1, 32)
+    assert M.trace_ring_dropped.get() - dropped0 == 21 + 41 - 32
+    tracer.clear()
+    assert tracer.spans_of(tid) == [] and tracer.trace_ids() == []
+
+
+@pytest.mark.parametrize("setup, where, chunks, segments_read", [
+    # batch_rows 1000: two chunks a segment, four in all
+    ("", "a >= 0", {"scanned": 4}, (0, 1)),
+    ("", "a >= 2000", {"scanned": 2, "pruned_segment": 2}, (1,)),
+    ("", "a >= 1500", {"scanned": 3, "pruned_chunk": 1}, (0, 1)),
+    ("", "a >= 100000", {"pruned_segment": 4}, ()),
+    ("delete from sc where a < 1000", "a >= 0",
+     {"scanned": 3, "all_dead": 1}, (0, 1)),
+])
+def test_scan_counters_on_pruned_and_unpruned_scans(
+        cold, setup, where, chunks, segments_read):
+    """`mo_scan_chunks_total` counts every chunk of the scan once, by
+    what became of it, and `mo_object_read_bytes_total` moves by the
+    stored bytes of exactly the column blocks the scan had to read: a
+    segment pruned on its stored zonemap costs none."""
+    from matrixone_tpu.storage import objectio
+    s, clear = cold
+    s.execute("set batch_rows = 1000")
+    if setup:
+        s.execute(setup)
+    s.execute("select sum(b) from sc where a >= 0")  # headers now read
+    clear()
+    table = s.catalog.get_table("sc")
+    stored = []
+    for seg in table.segments[:2]:
+        _meta, raw = objectio.read_header_ranged(s.catalog.fs,
+                                                 seg.obj_path)
+        stored.append(sum(ent[1] for ent in raw["cols"].values()))
+    outcomes = ("scanned", "pruned_segment", "pruned_chunk", "all_dead")
+    before = {o: M.scan_chunks.get(outcome=o) for o in outcomes}
+    read0 = M.object_read_bytes.get()
+    waits0 = M.device_wait.get(site="zonemap")
+    s.execute(f"select sum(b) from sc where {where}")
+    moved = {o: M.scan_chunks.get(outcome=o) - before[o]
+             for o in outcomes}
+    assert {o: n for o, n in moved.items() if n} == chunks
+    assert M.object_read_bytes.get() - read0 \
+        == sum(stored[i] for i in segments_read)
+    # the chunk's own check waits on the device twice a predicate, for
+    # every chunk that was read
+    read_chunks = chunks.get("scanned", 0) + chunks.get("pruned_chunk", 0)
+    assert M.device_wait.get(site="zonemap") - waits0 == 2 * read_chunks
 
 
 # --------------------------------------------------------------- smoke

@@ -14,6 +14,10 @@ the fabric.  Conventions encoded:
     corrupts the ambient context stack for every later span on the
     thread (`remote_session` is exempt: its object carries
     `attach()`/`harvest()` by design and is still entered via `with`);
+  * a span in a generator wraps the work between two yields and never
+    a `yield`: a span held open across a yield becomes the ambient
+    parent of whatever the consumer opens next, and its duration counts
+    the consumer's work;
   * trace injection is single-definition, exactly like the deadline
     checker's contract for `deadline_ms`: `RpcClient.call` /
     `WorkerClient.run` inject the ambient context themselves, so every
@@ -50,6 +54,20 @@ def _span_call_names(mod, factories) -> Set[str]:
             if target == f"{_MOTRACE_MOD}.{f}":
                 out.add(alias)
     return out
+
+
+def _yields_under(body) -> Iterable[ast.AST]:
+    """`yield` / `yield from` nodes in `body` that belong to the same
+    function as `body` (a nested def or lambda is another generator)."""
+    stack = list(body)
+    while stack:
+        node = stack.pop()
+        if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                             ast.Lambda)):
+            continue
+        if isinstance(node, (ast.Yield, ast.YieldFrom)):
+            yield node
+        stack.extend(ast.iter_child_nodes(node))
 
 
 def _injector_names(mod) -> Set[str]:
@@ -96,6 +114,20 @@ class SpanHygieneChecker(Checker):
                 if isinstance(node, (ast.With, ast.AsyncWith)):
                     for item in node.items:
                         with_exprs.add(id(item.context_expr))
+                    opened = [dotted(item.context_expr.func) or ""
+                              for item in node.items
+                              if isinstance(item.context_expr, ast.Call)]
+                    opened = [d for d in opened if d in span_names]
+                    if not opened:
+                        continue
+                    for y in _yields_under(node.body):
+                        yield Finding(
+                            self.rule, mod.path, y.lineno,
+                            f"`yield` inside `with {opened[0]}(...)` — "
+                            f"the span stays open while the consumer "
+                            f"runs: it becomes the parent of the "
+                            f"consumer's spans and its duration counts "
+                            f"their work; end the span before the yield")
             for node in ast.walk(mod.tree):
                 if not isinstance(node, ast.Call):
                     continue
