@@ -390,17 +390,9 @@ class Session:
         if isinstance(stmt, ast.Insert):
             return self._insert(stmt)
         if isinstance(stmt, ast.Explain):
-            from matrixone_tpu.sql.optimize import apply_indices
-            binder = Binder(self.catalog)
             if not isinstance(stmt.stmt, (ast.Select, ast.Union)):
                 raise BindError("EXPLAIN supports SELECT only for now")
-            self._prepare_select(stmt.stmt)
-            node = binder.bind_statement(stmt.stmt)
-            node = self._cbo(node)
-            node = apply_indices(
-                node, self.catalog,
-                nprobe=int(self.variables.get("ivf_nprobe", 8)),
-                skip_tables=self._index_skip_tables())
+            node = self._plan_select(stmt.stmt)
             if stmt.analyze:
                 return Result(text=self._explain_analyze(node))
             anns = [a for a in (self._fragment_annotator(node),
@@ -1300,8 +1292,21 @@ class Session:
         return optimize_plan(node, self.catalog)
 
     # ------------------------------------------------------------- select
+    def _plan_select(self, sel) -> P.PlanNode:
+        """SELECT/UNION AST -> the plan that runs (and that EXPLAIN shows):
+        bind, join order, index rewrites, then projection pruning last, so
+        every Scan carries only the columns the finished plan reads."""
+        from matrixone_tpu.sql.optimize import apply_indices, prune_columns
+        self._prepare_select(sel)
+        node = Binder(self.catalog).bind_statement(sel)
+        node = self._cbo(node)
+        node = apply_indices(
+            node, self.catalog,
+            nprobe=int(self.variables.get("ivf_nprobe", 8)),
+            skip_tables=self._index_skip_tables())
+        return prune_columns(node)
+
     def _select(self, sel: ast.Select, serving=None) -> Result:
-        from matrixone_tpu.sql.optimize import apply_indices
         ctl = self._try_mo_ctl(sel)
         if ctl is not None:
             return ctl
@@ -1352,13 +1357,7 @@ class Session:
                 # above never pays the AST deepcopy at all
                 sel = sv.instantiate(raise_errors=True)
             with motrace.span("plan"):
-                self._prepare_select(sel)
-                node = Binder(self.catalog).bind_statement(sel)
-                node = self._cbo(node)
-                node = apply_indices(
-                    node, self.catalog,
-                    nprobe=int(self.variables.get("ivf_nprobe", 8)),
-                    skip_tables=self._index_skip_tables())
+                node = self._plan_select(sel)
             if sv is not None and sv.template_mode \
                     and sv.plan_enabled() and plan_missed:
                 # store under the gens captured at LOOKUP time: a DDL

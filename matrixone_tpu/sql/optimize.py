@@ -12,6 +12,13 @@ the IVF index (vectorindex/ivf_flat) and yields only ~k candidate rows
 then recomputes the exact distance over k rows (free exact re-rank) and
 the TopK re-orders them — so the rewrite can only change WHICH k rows are
 returned (index recall), never their values or order semantics.
+
+`prune_columns` is the last pass of planning (reference:
+plan/query_builder.go remapAllColRefs + the column-pruning half of
+`plan/opt_misc.go`): every Scan is narrowed to the columns the plan above
+it references, so the reader, the block cache, the upload and the fused
+steps carry only those.  What is pruned depends on the plan alone; there
+is no switch.
 """
 
 from __future__ import annotations
@@ -19,7 +26,8 @@ from __future__ import annotations
 from typing import Optional
 
 from matrixone_tpu.sql import plan as P
-from matrixone_tpu.sql.expr import BoundCol, BoundFunc, BoundLiteral
+from matrixone_tpu.sql.expr import (BoundCol, BoundFunc, BoundLiteral,
+                                    columns_used)
 
 _DIST_METRIC = {"l2_distance": "l2", "l2_distance_sq": "l2",
                 "cosine_distance": "cosine", "inner_product": "ip"}
@@ -130,3 +138,113 @@ def _try_fulltext(node: P.TopK, catalog, skip_tables) -> "P.PlanNode | None":
             k=node.k, offset=node.offset, columns=scan.columns,
             out_exprs=out_exprs, schema=proj.schema)
     return None
+
+
+# ------------------------------------------------------ projection pruning
+
+def prune_columns(node: P.PlanNode) -> P.PlanNode:
+    """Narrow every Scan (and VectorTopK source) to the columns the plan
+    references, in place, and rebuild the schemas derived from them.
+
+    Top-down: each node is told which of its output names its parent
+    reads (`None` = all of them, which is what the root, a node type this
+    pass does not know, DISTINCT, FILL and UNION arms ask for).  A node
+    that computes its output (Project, Aggregate, UdfAggregate) asks its
+    child for exactly what its own expressions reference; a node that
+    passes rows through (Filter, Sort, TopK, Limit, Sample, Window, Join)
+    adds its own references to its parent's.  Expressions are never
+    copied: the plan cache patches tagged parameter literals in place."""
+    _prune(node, None)
+    return node
+
+
+def _cols(*exprs) -> set:
+    out: set = set()
+    for e in exprs:
+        if e is not None:
+            out.update(columns_used(e))
+    return out
+
+
+def _own_refs(node: P.PlanNode) -> set:
+    """Names a pass-through node's own expressions read from its input."""
+    if isinstance(node, P.Filter):
+        return _cols(node.pred)
+    if isinstance(node, (P.Sort, P.TopK)):
+        return _cols(*node.keys)
+    if isinstance(node, P.Window):
+        return _cols(*[e for _fn, arg, part, okeys, *_ in node.entries
+                       for e in (arg, *part, *okeys)])
+    if isinstance(node, P.Join):
+        return _cols(*node.left_keys, *node.right_keys, node.residual)
+    return set()                                   # Limit, Sample
+
+
+def _prune(node: P.PlanNode, needed: Optional[set]) -> None:
+    if isinstance(node, (P.Scan, P.VectorTopK)):
+        _narrow_source(node, needed)
+    elif isinstance(node, P.Project):
+        _prune(node.child, _cols(*node.exprs))
+    elif isinstance(node, P.Aggregate):
+        _prune(node.child, _cols(*node.group_keys,
+                                 *[a.arg for a in node.aggs]))
+    elif isinstance(node, P.UdfAggregate):
+        _prune(node.child, _cols(*node.calls))
+    elif isinstance(node, (P.Filter, P.Sort, P.TopK, P.Limit, P.Sample,
+                           P.Window, P.Join)):
+        own = _own_refs(node)
+        below = None if needed is None else needed | own
+        if isinstance(node, P.Join):
+            semi = node.kind in ("semi", "anti")
+            _prune(node.left, below)
+            # a semi/anti build side is only matched against, never output
+            _prune(node.right, own if semi else below)
+            inputs = [node.left] if semi else [node.left, node.right]
+        else:
+            _prune(node.child, below)
+            inputs = [node.child]
+        # the derived schema keeps its order, less what the inputs no longer
+        # produce; a Window's own hidden columns stay
+        kept = {n for c in inputs for n, _ in c.schema}
+        if isinstance(node, P.Window):
+            kept |= {e[5] for e in node.entries}
+        node.schema = [(n, d) for n, d in node.schema if n in kept]
+    else:
+        # Distinct, Fill, Union, and anything this pass does not know:
+        # every column of every child is needed
+        for attr in ("child", "left", "right"):
+            c = getattr(node, attr, None)
+            if c is not None:
+                _prune(c, None)
+        for c in getattr(node, "children", None) or []:
+            _prune(c, None)
+
+
+def _narrow_source(node, needed: Optional[set]) -> None:
+    """`columns` and `schema` of a Scan / VectorTopK narrow together
+    (every consumer zips the two) to what is needed above, what the
+    pushed filters read, and the row id where the scan had it."""
+    from matrixone_tpu.storage.engine import ROWID
+    if needed is None:
+        return
+    keep = needed | _cols(*getattr(node, "filters", ()))
+    pairs = [(c, s) for c, s in zip(node.columns, node.schema)
+             if s[0] in keep or c == ROWID]
+    if not pairs:
+        # nothing is read from the rows (count(*)): the scan still has to
+        # count them, through the column that costs the fewest bytes a row
+        pairs = [min(zip(node.columns, node.schema), key=_row_cost)]
+    node.columns = [c for c, _ in pairs]
+    node.schema = [s for _, s in pairs]
+
+
+def _row_cost(column_and_schema_entry) -> tuple:
+    """Order of the one column a scan keeps when none is needed: fixed
+    width before varlen and vector columns, then bytes a row; `min` keeps
+    the first of equals, so ties go by schema order and the plan cache and
+    the peers of a distributed scan agree."""
+    d = column_and_schema_entry[1][1]
+    if d.is_varlen:
+        return (1, 4)                      # dictionary codes
+    width = d.np_dtype.itemsize * max(d.dim, 1)
+    return (1 if d.is_vector else 0, width)

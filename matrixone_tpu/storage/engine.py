@@ -509,7 +509,8 @@ class MVCCTable:
                 end = min(start + batch_rows, seg.n_rows)
                 # the span ends before the yield (motrace's rule for
                 # generators): the consumer's work is not the scan's
-                with motrace.span("scan.chunk", table=self.meta.name):
+                with motrace.span("scan.chunk", table=self.meta.name,
+                                  cols=len(data_cols)):
                     chunk = self._read_chunk(
                         seg, start, end, data_cols, want_rowid,
                         dead_filter, filters, qmap)

@@ -290,6 +290,12 @@ scan_chunks = REGISTRY.counter(
     "its stored zonemap, before any read), pruned_chunk (excluded by "
     "the chunk's own min/max, after the read), all_dead (every row "
     "deleted)")
+scan_columns = REGISTRY.counter(
+    "mo_scan_columns_total",
+    "columns of executed table scans by outcome, once per scan: read "
+    "(the columns the planned Scan carries), pruned (the table's other "
+    "columns, which sql/optimize.prune_columns dropped because the plan "
+    "references none of them)")
 device_wait = REGISTRY.counter(
     "mo_device_wait_total",
     "host reads of a device value that block the statement's path, by "

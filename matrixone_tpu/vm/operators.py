@@ -182,6 +182,12 @@ class ScanOp(Operator):
         from matrixone_tpu.utils.fault import INJECTOR
         INJECTOR.trigger("scan.before")
         qnames = [n for n, _ in self.node.schema]
+        meta = getattr(self.rel, "meta", None)
+        M.scan_columns.inc(len(self.node.columns), outcome="read")
+        if meta is not None:
+            M.scan_columns.inc(
+                max(len(meta.schema) - len(self.node.columns), 0),
+                outcome="pruned")
         read_args = (self.ctx.table_read_args(self.node.table)
                      if self.ctx is not None else {})
         if self.node.as_of_ts is not None:
@@ -202,7 +208,6 @@ class ScanOp(Operator):
             # selected structurally (only_part) and no row moves; the
             # row-level mask below stays on as the correctness backstop
             # for any segment without a part id
-            meta = getattr(self.rel, "meta", None)
             pspec = getattr(meta, "partition", None) \
                 if meta is not None else None
             hs_aligned = (pspec is not None and pspec.kind == "hash"
