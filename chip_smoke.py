@@ -13,7 +13,9 @@ is compared with a plain reference computed here, in numpy / pandas /
   write_read  INSERT/UPDATE/DELETE in one transaction; every acknowledged
               row read back by a second connection after COMMIT, after
               checkpoint, and after the engine is reopened from disk
-  vector      1M x 768 IVF-Flat through SQL against numpy
+  vector      1M x 768 loaded in 4 commits, checkpoint, Engine.open; then
+              IVF-Flat built and searched through SQL by the re-opened
+              engine, against numpy
   kernels     every Pallas kernel those phases traced: compiled for the
               chip (`tpu_custom_call`), not interpreted, equal to XLA
 
@@ -516,6 +518,9 @@ def check_same_lists(got_ids, ref):
 
 def phase_vector(meters, state, size, seed):
     from matrixone_tpu import client
+    from matrixone_tpu.frontend.server import MOServer
+    from matrixone_tpu.storage.engine import Engine
+    from matrixone_tpu.storage.fileservice import LocalFS
     srv, eng = state["srv"], state["eng"]
     with phase(meters, "vector") as out:
         n, dim, lists = size["vectors"], size["dim"], size["lists"]
@@ -529,6 +534,20 @@ def phase_vector(meters, state, size, seed):
         t0 = time.perf_counter()
         load_vectors(conn, eng, "docs", x, commits=4)
         out["load_seconds"] = time.perf_counter() - t0
+        # the state every deployment is in after its first restart: the
+        # index is built, and every search answered, by a re-opened engine
+        t0 = time.perf_counter()
+        conn.query("select mo_ctl('checkpoint')")
+        conn.close()
+        srv.stop()
+        eng.close()
+        eng = state["eng"] = Engine.open(LocalFS(state["workdir"]))
+        srv = state["srv"] = MOServer(engine=eng, port=0).start()
+        out["checkpoint_reopen_seconds"] = time.perf_counter() - t0
+        conn = client.connect(port=srv.port, timeout=3600.0)
+        _, rows = conn.query("select count(*) from docs")
+        assert int(rows[0][0]) == n, (rows, n)
+        out["rows_read_back_after_reopen"] = n
         t0 = time.perf_counter()
         conn.execute(f"create index docs_v using ivfflat on docs (v) "
                      f"lists = {lists} op_type = 'vector_l2_ops'")

@@ -15,7 +15,6 @@ import datetime
 from typing import List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from matrixone_tpu.container import Batch, Vector, dtypes as dt, from_device
@@ -1310,48 +1309,52 @@ class Session:
         ctl = self._try_mo_ctl(sel)
         if ctl is not None:
             return ctl
-        sv = serving if (serving is not None and self.txn is None) else None
-        lazy = sv is not None and sv.owns_pristine(sel)
-        if sv is not None and not sv.usable_for(sel):
-            sv = None
-        if sv is None and lazy:
-            # caches declined but the caller handed us the pristine
-            # template: bind a private substituted copy, never the
-            # shared template itself
-            sel = serving.instantiate(raise_errors=True)
-            lazy = False
-        ann = getattr(self, "_exec_ann", None)
-        # ---- result cache: serve the whole statement if every scanned
-        # table is still at the version the entry was stored under
-        if sv is not None and sv.result_enabled():
-            hit = sv.state.result_cache.get(
-                sv.result_key(), self._recompute_versions)
-            if hit is not None:
-                batch, stored = hit
-                # privileges gate CACHED results too: the entry's
-                # version tuple carries the scanned table names, so an
-                # unprivileged user in the same account can never read
-                # a colleague's warm rows
-                if self.auth is not None and not self.auth.is_admin:
-                    for ent in stored[1]:
-                        self._check("select", ent[0])
-                if ann is not None:
-                    ann["cache_hit"] = "result"
-                return Result(batch=batch)
-        # ---- plan cache: skip prepare/bind/optimize on a hit (only in
-        # template mode — raw-path literals carry no parameter tags)
-        node = None
-        plan_missed = False
-        if sv is not None and sv.template_mode and sv.plan_enabled():
-            gens = self._serving_gens()
-            outcome, node = sv.state.plan_cache.lookup(
-                sv.plan_key(), gens[0], gens[1], sv.full)
-            plan_missed = outcome == "miss"
-            if node is not None and ann is not None \
-                    and ann["cache_hit"] == "none":
-                ann["cache_hit"] = "plan"
+        from matrixone_tpu.utils import motrace
+        # spans over the statement's path outside planning and execution:
+        # under many callers a thread waits here for the caches' locks
+        with motrace.span("serving.lookup"):
+            sv = (serving if (serving is not None and self.txn is None)
+                  else None)
+            lazy = sv is not None and sv.owns_pristine(sel)
+            if sv is not None and not sv.usable_for(sel):
+                sv = None
+            if sv is None and lazy:
+                # caches declined but the caller handed us the pristine
+                # template: bind a private substituted copy, never the
+                # shared template itself
+                sel = serving.instantiate(raise_errors=True)
+                lazy = False
+            ann = getattr(self, "_exec_ann", None)
+            # ---- result cache: serve the whole statement if every scanned
+            # table is still at the version the entry was stored under
+            if sv is not None and sv.result_enabled():
+                hit = sv.state.result_cache.get(
+                    sv.result_key(), self._recompute_versions)
+                if hit is not None:
+                    batch, stored = hit
+                    # privileges gate CACHED results too: the entry's
+                    # version tuple carries the scanned table names, so an
+                    # unprivileged user in the same account can never read
+                    # a colleague's warm rows
+                    if self.auth is not None and not self.auth.is_admin:
+                        for ent in stored[1]:
+                            self._check("select", ent[0])
+                    if ann is not None:
+                        ann["cache_hit"] = "result"
+                    return Result(batch=batch)
+            # ---- plan cache: skip prepare/bind/optimize on a hit (only in
+            # template mode — raw-path literals carry no parameter tags)
+            node = None
+            plan_missed = False
+            if sv is not None and sv.template_mode and sv.plan_enabled():
+                gens = self._serving_gens()
+                outcome, node = sv.state.plan_cache.lookup(
+                    sv.plan_key(), gens[0], gens[1], sv.full)
+                plan_missed = outcome == "miss"
+                if node is not None and ann is not None \
+                        and ann["cache_hit"] == "none":
+                    ann["cache_hit"] = "plan"
         if node is None:
-            from matrixone_tpu.utils import motrace
             if lazy:
                 # instantiate the template only now: a plan-cache hit
                 # above never pays the AST deepcopy at all
@@ -1376,75 +1379,77 @@ class Session:
         # then publishes old rows under a key that matches the
         # post-commit state (the staleness chaos drill caught exactly
         # this).  Execution is then FROZEN at the captured ts.
-        versions = frozen = None
-        if sv is not None and sv.result_enabled():
-            versions, frozen = self._capture_versions(node)
-        ctx = self._ctx(frozen_ts=frozen)
-        node2 = self._maybe_distribute(node, ctx)
-        # ---- compiled-tree reuse: a plan-cache hit used to rebuild the
-        # full operator tree anyway; the tree of the last completed
-        # execution rides the plan-cache entry (identity-guard POP: a
-        # concurrent execution finds None and compiles its own)
-        op = None
-        tree_cacheable = (sv is not None and sv.template_mode
-                          and sv.plan_enabled() and node2 is node)
-        tree_vars = self._tree_vars_sig() if tree_cacheable else None
-        if tree_cacheable:
-            from matrixone_tpu.utils import keys as keyaudit
-            if keyaudit.armed():
-                # each build-time knob re-read INDEPENDENTLY of
-                # _tree_vars_sig: a knob that starts steering tree
-                # construction without riding the signature (the
-                # kill-switches-not-in-_tree_vars_sig bug class)
-                # mismatches here instead of reusing a wrong tree
-                keyaudit.audit(
-                    "serving/plan_cache.py:tree",
-                    (sv.plan_key(), gens[0], gens[1], tree_vars),
-                    self._tree_vars_deps())
-            cached = sv.state.plan_cache.take_tree(
-                sv.plan_key(), gens[0], gens[1], tree_vars)
-            if cached is not None:
-                op = sv.state.plan_cache.rebind_tree(cached, sv.full)
-                if op is not None:
-                    from matrixone_tpu.vm.compile import retarget_tree
-                    retarget_tree(op, ctx)
-                    # the tree's plan nodes are the authoritative ones
-                    # for this execution (params patched in place)
-                    node = cached["plan"]
-        built = None
-        if op is None:
-            op = compile_plan(node2, ctx)
-            node = node2
+        with motrace.span("vm.compile"):
+            versions = frozen = None
+            if sv is not None and sv.result_enabled():
+                versions, frozen = self._capture_versions(node)
+            ctx = self._ctx(frozen_ts=frozen)
+            node2 = self._maybe_distribute(node, ctx)
+            # ---- compiled-tree reuse: a plan-cache hit used to rebuild the
+            # full operator tree anyway; the tree of the last completed
+            # execution rides the plan-cache entry (identity-guard POP: a
+            # concurrent execution finds None and compiles its own)
+            op = None
+            tree_cacheable = (sv is not None and sv.template_mode
+                              and sv.plan_enabled() and node2 is node)
+            tree_vars = self._tree_vars_sig() if tree_cacheable else None
             if tree_cacheable:
-                built = {"op": op, "plan": node2}
-        else:
-            built = cached
+                from matrixone_tpu.utils import keys as keyaudit
+                if keyaudit.armed():
+                    # each build-time knob re-read INDEPENDENTLY of
+                    # _tree_vars_sig: a knob that starts steering tree
+                    # construction without riding the signature (the
+                    # kill-switches-not-in-_tree_vars_sig bug class)
+                    # mismatches here instead of reusing a wrong tree
+                    keyaudit.audit(
+                        "serving/plan_cache.py:tree",
+                        (sv.plan_key(), gens[0], gens[1], tree_vars),
+                        self._tree_vars_deps())
+                cached = sv.state.plan_cache.take_tree(
+                    sv.plan_key(), gens[0], gens[1], tree_vars)
+                if cached is not None:
+                    op = sv.state.plan_cache.rebind_tree(cached, sv.full)
+                    if op is not None:
+                        from matrixone_tpu.vm.compile import retarget_tree
+                        retarget_tree(op, ctx)
+                        # the tree's plan nodes are the authoritative ones
+                        # for this execution (params patched in place)
+                        node = cached["plan"]
+            built = None
+            if op is None:
+                op = compile_plan(node2, ctx)
+                node = node2
+                if tree_cacheable:
+                    built = {"op": op, "plan": node2}
+            else:
+                built = cached
         out_batches = []
         for ex in op.execute():
             # KILL lands between device batches (queryservice): the pull
             # loop is the engine's natural preemption point
             self._procs.check_killed(self.conn_id)
             out_batches.append(self._to_host(ex, node.schema))
-        if tree_cacheable and built is not None:
-            sv.state.plan_cache.put_tree(sv.plan_key(), built, gens[0],
-                                         gens[1], tree_vars)
-        if not out_batches:
-            empty = {n: Vector.from_values([], d) for n, d in node.schema}
-            result = Result(batch=Batch(empty))
-        elif len(out_batches) == 1:
-            result = Result(batch=out_batches[0])
-        else:
-            # concatenate host batches
-            cols = {}
-            for n, d in node.schema:
-                vals = []
-                for b in out_batches:
-                    vals.extend(b.columns[n].to_pylist())
-                cols[n] = Vector.from_values(vals, d)
-            result = Result(batch=Batch(cols))
-        if versions is not None and result.batch is not None:
-            sv.state.result_cache.put(sv.result_key(), result.batch,
-                                      versions)
+        with motrace.span("serving.store"):
+            if tree_cacheable and built is not None:
+                sv.state.plan_cache.put_tree(sv.plan_key(), built, gens[0],
+                                             gens[1], tree_vars)
+            if not out_batches:
+                empty = {n: Vector.from_values([], d) for n, d in node.schema}
+                result = Result(batch=Batch(empty))
+            elif len(out_batches) == 1:
+                result = Result(batch=out_batches[0])
+            else:
+                # concatenate host batches
+                cols = {}
+                for n, d in node.schema:
+                    vals = []
+                    for b in out_batches:
+                        vals.extend(b.columns[n].to_pylist())
+                    cols[n] = Vector.from_values(vals, d)
+                result = Result(batch=Batch(cols))
+            if versions is not None and result.batch is not None:
+                sv.state.result_cache.put(sv.result_key(), result.batch,
+                                          versions)
         return result
 
     def _tree_vars_sig(self) -> tuple:
@@ -1669,11 +1674,11 @@ class Session:
 
     def _to_host(self, ex, schema) -> Batch:
         from matrixone_tpu.ops import filter as F
-        # compact masked rows before leaving device
-        n_out = jnp.sum(ex.mask.astype(jnp.int32))
-        cap = ex.padded_len
-        db = F.compact(ex.batch, ex.mask, cap)
-        return from_device(db, ex.dicts, schema=dict(schema))
+        from matrixone_tpu.utils import motrace
+        with motrace.span("result.fetch"):
+            # compact masked rows before leaving device
+            db = F.compact(ex.batch, ex.mask, ex.padded_len)
+            return from_device(db, ex.dicts, schema=dict(schema))
 
     # --------------------------------------------------------------- ddl
     def _create_table(self, stmt: ast.CreateTable) -> Result:

@@ -7,11 +7,24 @@
         -> Scan(table)                      [no pushed filters]
 
 into the same tree with the Scan replaced by a VectorTopK source that runs
-the IVF index (vectorindex/ivf_flat) and yields only ~k candidate rows
-(all table columns fetched by row id + the index distance). The Project
-then recomputes the exact distance over k rows (free exact re-rank) and
-the TopK re-orders them — so the rewrite can only change WHICH k rows are
-returned (index recall), never their values or order semantics.
+the vector index and yields only ~k candidate rows, fetched by row id.
+
+An IVF-Flat index holds the vectors themselves, so it re-ranks its
+candidates exactly in the search's own device program and yields them
+nearest first with their distance as the column `P.VECTOR_DIST`; the
+Project reads that column wherever it had `distance(vec_col, const_vec)`,
+the source takes the TopK's own limit and offset (the TopK leaves the
+plan), and the table is asked only for the k rows and the columns the
+statement's text names (`select id ... order by l2_distance(v, ...)` never
+reads `v`).  That holds only where the index's order IS the statement's:
+`l2_distance`, `l2_distance_sq` and `cosine_distance` ascend with the
+index's score.  `inner_product` does not (a `vector_ip_ops` index ranks by
+1 - x.q, largest product first, while `ORDER BY inner_product(...)` asks
+for the smallest first), and IVF-PQ and HNSW yield no distance: there the
+Project recomputes the function over the fetched candidates (PQ's exact
+re-rank) and the TopK re-orders them.  Either way the k rows are ordered
+by the statement's own key, exactly — so the rewrite can only change WHICH
+k rows are returned (index recall), never their values or order semantics.
 
 `prune_columns` is the last pass of planning (reference:
 plan/query_builder.go remapAllColRefs + the column-pruning half of
@@ -25,12 +38,16 @@ from __future__ import annotations
 
 from typing import Optional
 
+from matrixone_tpu.container import dtypes as dt
 from matrixone_tpu.sql import plan as P
-from matrixone_tpu.sql.expr import (BoundCol, BoundFunc, BoundLiteral,
-                                    columns_used)
+from matrixone_tpu.sql.expr import (BoundCast, BoundCol, BoundFunc,
+                                    BoundLiteral, columns_used)
 
 _DIST_METRIC = {"l2_distance": "l2", "l2_distance_sq": "l2",
                 "cosine_distance": "cosine", "inner_product": "ip"}
+#: the distance functions that ascend with the index's own score, so that
+#: an index which yields its rows nearest first may stand in for the TopK
+_INDEX_ORDERED = ("l2_distance", "l2_distance_sq", "cosine_distance")
 
 
 def apply_indices(node: P.PlanNode, catalog, nprobe: int = 8,
@@ -85,12 +102,37 @@ def apply_indices(node: P.PlanNode, catalog, nprobe: int = 8,
             # (Project recompute + TopK) recovers ADC quantization loss
             factor = overfetch * (3 if ix.algo == "ivfpq" else 1)
             k = (node.k + node.offset) * factor
-            proj.child = P.VectorTopK(
+            source = P.VectorTopK(
                 table=scan.table, index_name=ix.name,
                 query_vector=list(vec_e.value), k=k, metric=metric,
                 columns=scan.columns, schema=scan.schema, nprobe=nprobe)
-            return node
+            proj.child = source
+            if ix.algo != "ivfflat" or dist.op not in _INDEX_ORDERED:
+                return node
+            # the index re-ranks exactly, yields the distance and the rows
+            # nearest first, which is the statement's order: it takes the
+            # TopK's place
+            source.dist_op = dist.op
+            source.limit, source.offset = node.k, node.offset
+            source.columns = scan.columns + [P.VECTOR_DIST]
+            source.schema = scan.schema + [(P.VECTOR_DIST, dt.FLOAT64)]
+            column = BoundCol(P.VECTOR_DIST, dt.FLOAT64)
+            proj.exprs = [_replace(e, dist, column) for e in proj.exprs]
+            return proj
     return node
+
+
+def _replace(e, target, column):
+    """`e` with every occurrence of the expression `target` (under
+    functions and casts) replaced by `column`; other nodes are kept as
+    they are, not copied."""
+    if e == target:
+        return column
+    if isinstance(e, BoundFunc):
+        e.args = [_replace(a, target, column) for a in e.args]
+    elif isinstance(e, BoundCast):
+        e.arg = _replace(e.arg, target, column)
+    return e
 
 
 def _try_fulltext(node: P.TopK, catalog, skip_tables) -> "P.PlanNode | None":

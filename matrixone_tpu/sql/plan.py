@@ -179,10 +179,24 @@ class UdfAggregate(PlanNode):
     schema: Schema
 
 
+#: the column a VectorTopK yields beside the table's own: the distance of
+#: each candidate row, as the statement's distance function defines it
+VECTOR_DIST = "__vec_dist"
+
+
 @dataclasses.dataclass
 class VectorTopK(PlanNode):
     """Index-accelerated `ORDER BY distance(col, const) LIMIT k` — the
-    reference's applyIndices rewrite (plan/apply_indices_ivfflat.go)."""
+    reference's applyIndices rewrite (plan/apply_indices_ivfflat.go).
+
+    The source yields the index's k candidate rows: the table columns in
+    `columns`, fetched by row id, and, where `dist_op` is set (an index
+    that holds the vectors themselves, IVF-Flat, under a distance function
+    that ascends with the index's score: not `inner_product`), the column
+    VECTOR_DIST with `dist_op(vector column, query_vector)` of each row, recomputed
+    from the index's own vectors in float32.  The Project above reads that
+    column where it would have recomputed the distance, so a statement
+    that does not name the vector column does not read it."""
     table: str
     index_name: str
     query_vector: list
@@ -191,6 +205,9 @@ class VectorTopK(PlanNode):
     columns: List[str]
     schema: Schema
     nprobe: int = 8
+    dist_op: Optional[str] = None
+    limit: Optional[int] = None
+    offset: int = 0
 
 
 @dataclasses.dataclass
@@ -254,6 +271,8 @@ def explain(node: PlanNode, indent: int = 0, annotate=None) -> str:
         extra = f" kind={node.kind}"
     elif isinstance(node, VectorTopK):
         extra = f" index={node.index_name} k={node.k} metric={node.metric}"
+        if node.limit is not None:
+            extra += f" limit={node.limit} offset={node.offset}"
     elif isinstance(node, FulltextTopK):
         extra = f" index={node.index_name} k={node.k} query={node.query!r}"
     extra += _udf_call_notes(node)

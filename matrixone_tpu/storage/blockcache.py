@@ -148,6 +148,30 @@ class BlockCache:
             _metrics_dev(outcome="upload")
         return dev
 
+    def get_resident(self, key: tuple, count: bool = True):
+        """The array for `key` from whichever tier holds it, host tier
+        first, as it is: numpy from the host tier, a device array from the
+        device tier, None where neither has it.  Never uploads and never
+        counts a device-tier outcome: a by-row-id fetch gathers a few rows
+        out of whatever is resident (`LazyColumns.host`)."""
+        a = None
+        with self._lock:
+            if os.environ.get("MO_BLOCK_CACHE_DISABLE") != "1":
+                a = self._host.get(key)
+                if a is not None:
+                    self._host.move_to_end(key)
+                else:
+                    a = self._dev.get(key)
+                    if a is not None:
+                        self._dev.move_to_end(key)
+            if count and a is not None:
+                self.hits += 1
+            elif count:
+                self.misses += 1
+        if count:
+            (_metrics_hit if a is not None else _metrics_miss)()
+        return a
+
     def contains(self, key: tuple) -> bool:
         """Either-tier presence probe: no counting, no upload — drives
         the scan read-ahead decision (LazyColumns.cold_columns)."""
@@ -162,25 +186,42 @@ class BlockCache:
         """Admit one decoded host column to both tiers; returns the
         device-resident array (what the scan hands to from_numpy)."""
         value = np.asarray(value)
-        nb = int(value.nbytes)
         with self._lock:
             san.mutating(self)
-            if key not in self._host:
-                budget = _budget_bytes()
-                while self._host and self.host_used_bytes + nb > budget:
-                    k, _v = self._host.popitem(last=False)
-                    self.host_used_bytes -= self._host_sizes.pop(k)
-                    self.host_evictions += 1
-                self._host[key] = value
-                self._host_sizes[key] = nb
-                self.host_used_bytes += nb
-                self.host_peak_bytes = max(self.host_peak_bytes,
-                                           self.host_used_bytes)
+            self._admit_host_locked(key, value)
             dev = self._dev.get(key)
             if dev is not None:
                 self._note_peak_locked()
                 return dev
         return self._upload_and_admit(key, value)
+
+    def put_host(self, key: tuple, value: np.ndarray) -> np.ndarray:
+        """Admit one decoded column to the HOST tier alone, and only where
+        it fits the tier: a by-row-id fetch of a column larger than the
+        whole budget reads it without turning every other entry out, and
+        nothing is uploaded.  -> the host array."""
+        value = np.asarray(value)
+        if int(value.nbytes) <= _budget_bytes():
+            with self._lock:
+                san.mutating(self)
+                self._admit_host_locked(key, value)
+                self._note_peak_locked()
+        return value
+
+    def _admit_host_locked(self, key: tuple, value: np.ndarray) -> None:
+        if key in self._host:
+            return
+        nb = int(value.nbytes)
+        budget = _budget_bytes()
+        while self._host and self.host_used_bytes + nb > budget:
+            k, _v = self._host.popitem(last=False)
+            self.host_used_bytes -= self._host_sizes.pop(k)
+            self.host_evictions += 1
+        self._host[key] = value
+        self._host_sizes[key] = nb
+        self.host_used_bytes += nb
+        self.host_peak_bytes = max(self.host_peak_bytes,
+                                   self.host_used_bytes)
 
     def _upload_and_admit(self, key: tuple, host_value):
         """host array -> device array, admitted to the device tier
@@ -373,55 +414,65 @@ class _ObjectSource:
         return self._raw
 
     def column(self, col: str, kind: str) -> np.ndarray:
-        got = CACHE.get((self._tok, self.path, col, kind))
+        """The column as a ready-to-batch device array (the scan's way
+        in): a miss decodes and admits to both tiers."""
+        key = (self._tok, self.path, col, kind)
+        got = CACHE.get(key)
         if got is not None:
             return got
         with self._load_lock:        # one decode per object per miss burst
-            got = CACHE.get((self._tok, self.path, col, kind),
-                            count=False)   # recheck: not a second miss
+            got = CACHE.get(key, count=False)   # recheck: not a second miss
             if got is not None:
                 return got
-            from matrixone_tpu.utils import motrace
-            with motrace.span("blockcache.load", col=col):
-                return self._load(col, kind)
+            return self._load(col, CACHE.put)[kind == "validity"]
 
-    def _load(self, col: str, kind: str) -> np.ndarray:
-        """The miss path (under `_load_lock`): read, decode, admit to
-        both tiers."""
+    def host_pair(self, col: str):
+        """(data, validity) of the column for a by-row-id read
+        (`LazyColumns.host_pair`): whatever is resident, host tier first,
+        else ONE decode for the two, admitted to the host tier alone where
+        it fits.  Nothing is uploaded."""
+        keys = [(self._tok, self.path, col, kind)
+                for kind in ("data", "validity")]
+        got = [CACHE.get_resident(k) for k in keys]
+        if got[0] is not None and got[1] is not None:
+            return tuple(got)
+        with self._load_lock:
+            got = [CACHE.get_resident(k, count=False) for k in keys]
+            if got[0] is not None and got[1] is not None:
+                return tuple(got)
+            return self._load(col, CACHE.put_host)
+
+    def _load(self, col: str, admit):
+        """The miss path (under `_load_lock`): read, decode, and `admit`
+        (`CACHE.put`: both tiers; `CACHE.put_host`: the host tier).
+        -> (data, validity) of `col` as admitted."""
         from matrixone_tpu.storage import objectio
-        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import metrics as M, motrace
         t0 = time.perf_counter()
-        raw = self._header()
-        if raw.get("v", 1) < 2:
-            # legacy whole-IPC object: one decode populates EVERY
-            # column (a per-column loop would re-download the full
-            # object per column)
-            _m, a_all, v_all = objectio.read_object(self.fs, self.path)
+        with motrace.span("blockcache.load", col=col):
+            raw = self._header()
+            if raw.get("v", 1) < 2:
+                # legacy whole-IPC object: one decode populates EVERY
+                # column (a per-column loop would re-download the full
+                # object per column)
+                _m, a_all, v_all = objectio.read_object(self.fs, self.path)
+            elif col in raw["cols"]:
+                a_all, v_all = {}, {}
+                a_all[col], v_all[col] = objectio.read_column_block(
+                    self.fs, self.path, raw, col)
+            else:
+                a_all = {}
             if col not in a_all:
-                raise KeyError(
-                    f"column {col!r} not in object {self.path}")
+                raise KeyError(f"column {col!r} not in object {self.path}")
             out = None
             for c in a_all:
-                d = CACHE.put((self._tok, self.path, c, "data"),
-                              a_all[c])
-                v = CACHE.put((self._tok, self.path, c, "validity"),
-                              v_all[c])
+                d = admit((self._tok, self.path, c, "data"), a_all[c])
+                v = admit((self._tok, self.path, c, "validity"), v_all[c])
                 if c == col:
-                    out = d if kind == "data" else v
+                    out = (d, v)
                 self._account(d, v)
-            self._account_time(t0, M)
-            return out
-        if col not in raw["cols"]:
-            raise KeyError(
-                f"column {col!r} not in object {self.path}")
-        data, valid = objectio.read_column_block(self.fs, self.path,
-                                                 raw, col)
-        data = CACHE.put((self._tok, self.path, col, "data"), data)
-        valid = CACHE.put((self._tok, self.path, col, "validity"),
-                          valid)
-        self._account(data, valid)
         self._account_time(t0, M)
-        return data if kind == "data" else valid
+        return out
 
     def _account(self, data, valid) -> None:
         nb = int(data.nbytes) + int(valid.nbytes)
@@ -449,6 +500,13 @@ class LazyColumns(Mapping):
 
     def __getitem__(self, col: str) -> np.ndarray:
         return self._source.column(col, self._kind)
+
+    def host_pair(self, col: str):
+        """(data, validity) of the column for a by-row-id read
+        (`fetch_rows`, an index build): numpy from the host tier or one
+        fresh decode, a device array only where the device tier alone
+        holds it.  No upload."""
+        return self._source.host_pair(col)
 
     def __iter__(self) -> Iterator[str]:
         return iter(self._source.columns)

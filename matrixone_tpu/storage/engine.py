@@ -46,6 +46,15 @@ Schema = List[Tuple[str, DType]]
 ROWID = "__rowid"
 
 
+def _host_pair(seg: "Segment", col: str):
+    """(data, validity) of one column of a segment for a read by row id:
+    the RAM arrays of a fresh segment, or what `LazyColumns.host_pair`
+    finds for an object-backed one (no upload)."""
+    if seg.is_lazy:
+        return seg.arrays.host_pair(col)
+    return seg.arrays[col], seg.validity[col]
+
+
 
 def schema_to_json(schema: Schema) -> list:
     """One canonical (de)serialization for table schemas — WAL records,
@@ -574,11 +583,19 @@ class MVCCTable:
     def visible_gids(self, gids: np.ndarray,
                      snapshot_ts: Optional[int] = None,
                      extra_deletes: Optional[np.ndarray] = None) -> np.ndarray:
-        """Filter gids to rows visible at the snapshot: owning segment
-        committed <= ts and not tombstoned (incl. txn-local deletes)."""
+        """Filter gids to rows visible at the snapshot."""
+        gids = np.asarray(gids, np.int64)
+        return gids[self.visible_mask(gids, snapshot_ts, extra_deletes)]
+
+    def visible_mask(self, gids: np.ndarray,
+                     snapshot_ts: Optional[int] = None,
+                     extra_deletes: Optional[np.ndarray] = None) -> np.ndarray:
+        """True for each gid whose row is visible at the snapshot: owning
+        segment committed <= ts and not tombstoned (incl. txn-local
+        deletes).  Host arithmetic only."""
         gids = np.asarray(gids, np.int64)
         if len(gids) == 0:
-            return gids
+            return np.zeros(0, np.bool_)
         src_segs, src_tombs = self._view_at(snapshot_ts)
         bases = np.array([s.base_gid for s in src_segs], np.int64)
         seg_ts = np.array([s.commit_ts for s in src_segs], np.int64)
@@ -589,44 +606,76 @@ class MVCCTable:
         dead = self._dead_gids(snapshot_ts, extra_deletes, src_tombs)
         if len(dead):
             ok = ok & ~np.isin(gids, dead)
-        return gids[ok]
+        return ok
 
     def fetch_rows(self, gids: np.ndarray, columns: List[str]):
-        """Host gather of rows by global id (vector-index result fetch,
-        delta-replay delete decode).  Returns (arrays, validity) in gid
-        order.  Gids a merge compacted out of the live list resolve
-        through the snapshot fences (gid ranges are never reused)."""
+        """Host gather of rows by global id (vector- and fulltext-index
+        result fetch, mview and CDC delta decode).  Returns (arrays,
+        validity) as host arrays in gid order, duplicates included.
+
+        What it reads: the gids are grouped by owning segment, and each
+        (segment, column) is taken ONCE and its rows gathered with one
+        indexing operation.  An object-backed segment's column comes from
+        whichever cache tier holds it (host tier first; a device-tier
+        array costs one gather and one copy of the gathered rows back),
+        else from one decode that is admitted to the host tier alone and
+        only where it fits: nothing is uploaded, and a column larger than
+        the tier (a 250,000 x 768 vector column) is read without turning
+        everything else out.  Gids a merge compacted out of the live list
+        resolve through the snapshot fences (gid ranges are never
+        reused)."""
         gids = np.asarray(gids, np.int64)
-        bases = np.array([s.base_gid for s in self.segments], np.int64)
-        seg_idx = np.searchsorted(bases, gids, side="right") - 1
-        owners: List[Segment] = []
-        for gi, si in zip(gids, seg_idx):
-            seg = self.segments[si] if si >= 0 else None
-            if seg is None or gi >= seg.base_gid + seg.n_rows:
-                seg = self._gid_fence_segment(int(gi))
-            if seg is None:
-                raise KeyError(f"gid {int(gi)} not found in "
-                               f"{self.meta.name!r} (live or fenced)")
-            owners.append(seg)
-        arrays = {c: [] for c in columns}
-        validity = {c: [] for c in columns}
+        segs, owner = self._owners(gids)
+        order = np.argsort(owner, kind="stable")
+        cuts = np.searchsorted(owner[order], np.arange(len(segs) + 1))
+        schema = dict(self.meta.schema)
+        arrays, validity = {}, {}
         for c in columns:
-            dtype = dict(self.meta.schema)[c]
-            parts_a, parts_v = [], []
-            for gi, seg in zip(gids, owners):
-                off = int(gi - seg.base_gid)
-                parts_a.append(seg.arrays[c][off])
-                parts_v.append(seg.validity[c][off])
-            if parts_a:
-                arrays[c] = np.stack(parts_a) if np.ndim(parts_a[0]) \
-                    else np.asarray(parts_a)
-                validity[c] = np.asarray(parts_v, np.bool_)
-            else:
+            out_a = None
+            out_v = np.zeros(len(gids), np.bool_)
+            for seg, lo, hi in zip(segs, cuts[:-1], cuts[1:]):
+                at = order[lo:hi]                # positions in the output
+                off = gids[at] - seg.base_gid
+                data, valid = _host_pair(seg, c)
+                part = np.asarray(data[off])
+                if out_a is None:
+                    out_a = np.empty((len(gids),) + part.shape[1:],
+                                     part.dtype)
+                out_a[at] = part
+                out_v[at] = np.asarray(valid[off])
+            if out_a is None:
+                dtype = schema[c]
                 shape = (0, dtype.dim) if dtype.is_vector else (0,)
-                np_t = np.int32 if dtype.is_varlen else dtype.np_dtype
-                arrays[c] = np.zeros(shape, np_t)
-                validity[c] = np.zeros(0, np.bool_)
+                out_a = np.zeros(shape, np.int32 if dtype.is_varlen
+                                 else dtype.np_dtype)
+            arrays[c], validity[c] = out_a, out_v
         return arrays, validity
+
+    def _owners(self, gids: np.ndarray):
+        """-> (segments, owner): the distinct segments that own `gids`
+        and, for each gid, the index of its segment in that list.  Live
+        segments by array arithmetic over their gid ranges; a gid no live
+        segment covers (compacted away) through the fences."""
+        live = self.segments
+        bases = np.array([s.base_gid for s in live], np.int64)
+        ends = bases + np.array([s.n_rows for s in live], np.int64)
+        si = np.searchsorted(bases, gids, side="right") - 1
+        fenced = (si < 0) | (gids >= ends[np.clip(si, 0, None)]) \
+            if len(live) else np.ones(len(gids), np.bool_)
+        every = list(live)
+        at = {}                          # id(fenced segment) -> its index
+        owner = si.astype(np.int64)
+        for i in np.flatnonzero(fenced):
+            seg = self._gid_fence_segment(int(gids[i]))
+            if seg is None:
+                raise KeyError(f"gid {int(gids[i])} not found in "
+                               f"{self.meta.name!r} (live or fenced)")
+            if id(seg) not in at:
+                at[id(seg)] = len(every)
+                every.append(seg)
+            owner[i] = at[id(seg)]
+        used = np.unique(owner)
+        return [every[u] for u in used], np.searchsorted(used, owner)
 
     def read_texts(self, col: str):
         """Decoded visible strings (+ gids) for a varchar column
@@ -649,23 +698,30 @@ class MVCCTable:
     def read_column_f32(self, col: str):
         """Dense f32 matrix of VISIBLE rows (tombstones excluded) plus the
         gid of each matrix row — index builds must not index deleted rows,
-        and search results map back to rows via the gids."""
+        and search results map back to rows via the gids.  Read on the
+        host: an object-backed segment's column comes from the host tier
+        or one decode (`LazyColumns.host_pair`), never up to the device
+        and back, and each segment is copied straight into the result."""
         d = dict(self.meta.schema)[col].dim
         dead = self._dead_gids(None, None)
-        mats, gids = [], []
+        keeps, gids = [], []
         for seg in self.segments:
             g = np.arange(seg.base_gid, seg.base_gid + seg.n_rows,
                           dtype=np.int64)
             keep = ~np.isin(g, dead) if len(dead) else None
-            m = seg.arrays[col]
-            if keep is not None and not keep.all():
-                m, g = m[keep], g[keep]
-            mats.append(m)
-            gids.append(g)
-        if not mats:
+            if keep is not None and keep.all():
+                keep = None
+            keeps.append(keep)
+            gids.append(g if keep is None else g[keep])
+        if not gids:
             return np.zeros((0, d), np.float32), np.zeros(0, np.int64)
-        return (np.concatenate(mats).astype(np.float32),
-                np.concatenate(gids))
+        out = np.empty((sum(len(g) for g in gids), d), np.float32)
+        lo = 0
+        for seg, keep, g in zip(self.segments, keeps, gids):
+            m = np.asarray(_host_pair(seg, col)[0])
+            out[lo:lo + len(g)] = m if keep is None else m[keep]
+            lo += len(g)
+        return out, np.concatenate(gids)
 
     # -------------------------------------------------- convenience write
     # (autocommit single-statement writes go through the Engine; these are

@@ -302,7 +302,16 @@ device_wait = REGISTRY.counter(
     "site: zonemap (chunk min/max/all-valid), flags (fused all-valid "
     "flags), limit (fused LIMIT rows seen), finalize (the fused carry "
     "handed to the result path, whose fetch is the statement's last "
-    "wait)")
+    "wait), vector_search (the candidates of a vector index search and "
+    "its exact re-rank), vector_delta (the exact scan of an index's "
+    "delta segment)")
+vector_fetch_rows = REGISTRY.counter(
+    "mo_vector_fetch_rows_total",
+    "candidate rows a VectorTopK fetched from its table by row id")
+vector_fetch_bytes = REGISTRY.counter(
+    "mo_vector_fetch_bytes_total",
+    "host bytes (data and validity) MVCCTable.fetch_rows gathered for a "
+    "VectorTopK: rows x the widths of the columns the statement names")
 scan_prefetch = REGISTRY.counter(
     "mo_scan_prefetch_total",
     "scan read-ahead outcomes: chunks served ready vs waited-on")

@@ -226,13 +226,21 @@ def _probe(index: IvfFlatIndex, q: jnp.ndarray, nprobe: int,
 
 
 def _score_chunk(index: IvfFlatIndex, qc, pc, cs, pmask, k: int,
-                 compute_dtype):
+                 compute_dtype, exact: bool = False, clusters=None):
     """Score one query chunk's probed clusters and return its top-k.
 
     qc [qc, d] queries, pc [qc, nprobe] probed cluster ids, cs [qc, nprobe]
     probe-stage scores, pmask [qc, nprobe] live-probe mask (False lanes are
     ignored entirely — the sharded path masks probes owned by other
     devices). Per-query scoring + two-stage top-k (see module docstring).
+
+    With `exact` the k survivors are re-scored from the index's own
+    vectors (centroid + f32 residual) in elementwise float32 and re-sorted
+    in the same program, so the distances returned are those of the stored
+    rows to float32 rounding and no caller has to fetch the rows to
+    re-rank them.  `clusters` [qc, nprobe] are the probes' ids in
+    `index.centroids` where `pc` indexes another table (the sharded view's
+    local slots); it defaults to `pc`.
     """
     query_chunk, nprobe = pc.shape
     pad = index.max_cluster_size
@@ -266,13 +274,31 @@ def _score_chunk(index: IvfFlatIndex, qc, pc, cs, pmask, k: int,
     c1f = c1.reshape(query_chunk, nprobe * kk)
     top_s, top_pos = jax.lax.top_k(s1f, min(k, nprobe * kk))
     top_cand = jnp.take_along_axis(c1f, top_pos, axis=1)
-    return -top_s, index.ids[top_cand].astype(jnp.int32)
+    top_ids = index.ids[top_cand].astype(jnp.int32)
+    if not exact:
+        return -top_s, top_ids
+    with jax.named_scope("ivf_rerank_exact"):
+        own_cluster = jnp.take_along_axis(
+            pc if clusters is None else clusters, top_pos // kk, axis=1)
+        x = index.centroids[own_cluster] \
+            + index.vectors[top_cand].astype(jnp.float32)   # [qc, k, d]
+        q32 = qc.astype(jnp.float32)[:, None, :]
+        if index.metric == METRIC_L2:
+            diff = x - q32
+            exact_d = jnp.sum(diff * diff, axis=-1)
+        else:
+            exact_d = 1.0 - jnp.sum(x * q32, axis=-1)
+        exact_d = jnp.where(jnp.isfinite(top_s), exact_d, jnp.inf)
+        order = jnp.argsort(exact_d, axis=1)
+        return (jnp.take_along_axis(exact_d, order, axis=1),
+                jnp.take_along_axis(top_ids, order, axis=1))
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "query_chunk",
-                                   "compute_dtype", "use_pallas"))
+                                   "compute_dtype", "use_pallas", "exact"))
 def _search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
-            query_chunk: int, compute_dtype, use_pallas: bool):
+            query_chunk: int, compute_dtype, use_pallas: bool,
+            exact: bool = False):
     b, d = queries.shape
     q = queries.astype(jnp.float32)
     if index.metric == METRIC_COSINE:
@@ -287,7 +313,7 @@ def _search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
     def step(_, inp):
         qc, pc, cs = inp
         return None, _score_chunk(index, qc, pc, cs, pmask, k,
-                                  compute_dtype)
+                                  compute_dtype, exact)
 
     _, (dists, ids) = jax.lax.scan(
         step, None, (q_chunks, probe_chunks, cscore_chunks))
@@ -296,8 +322,13 @@ def _search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
 
 def search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
            query_chunk: int = 32, compute_dtype=jnp.bfloat16,
-           use_pallas: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+           use_pallas: bool = False, exact: bool = False
+           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched IVF search -> (distances [b,k], row_positions [b,k] int32).
+
+    With `exact` the k results carry float32 distances recomputed from
+    the stored vectors and are ordered by them (`_score_chunk`): the
+    search and its exact re-rank are one device program.
 
     Distances are squared l2 (metric=l2) or 1-ip (cosine/ip). Any batch
     size b works: queries are padded internally to the next power of two
@@ -315,7 +346,7 @@ def search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
         M.vector_search_pad_rows.inc(target - b)
     M.vector_search_queries.inc(b)
     dists, ids = _search(index, q, k, nprobe, qc_eff, compute_dtype,
-                         use_pallas)
+                         use_pallas, exact)
     if target != b:
         dists, ids = dists[:b], ids[:b]
     return dists, ids

@@ -147,10 +147,11 @@ def shard_ivf(index: IvfFlatIndex, mesh) -> ShardedIvfIndex:
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "query_chunk",
-                                   "compute_dtype", "probe_capacity"))
+                                   "compute_dtype", "probe_capacity",
+                                   "exact"))
 def _search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
                     nprobe: int, query_chunk: int, compute_dtype,
-                    probe_capacity: Optional[int]):
+                    probe_capacity: Optional[int], exact: bool = False):
     mesh = sidx.mesh
     b, d = queries.shape
     L = sidx.local_offsets.shape[1] - 1
@@ -189,13 +190,14 @@ def _search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
         pcs = pc_local.reshape(n_chunks, query_chunk, lp)
         css = cscores.reshape(n_chunks, query_chunk, lp)
         owns = own.reshape(n_chunks, query_chunk, lp)
+        gcs = probes.reshape(n_chunks, query_chunk, lp)
 
         def step(_, inp):
-            qc, pcc, csc, ownc = inp
+            qc, pcc, csc, ownc, gcc = inp
             return None, _score_chunk(view, qc, pcc, csc, ownc, k,
-                                      compute_dtype)
+                                      compute_dtype, exact, clusters=gcc)
 
-        _, (dl, il) = jax.lax.scan(step, None, (qs, pcs, css, owns))
+        _, (dl, il) = jax.lax.scan(step, None, (qs, pcs, css, owns, gcs))
         dl = dl.reshape(b, -1)
         il = il.reshape(b, -1)
         # one small collective: every device merges the same S*k union
@@ -220,14 +222,17 @@ def _search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
 def search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
                    nprobe: int, query_chunk: int = 32,
                    compute_dtype=jnp.bfloat16,
-                   probe_capacity: Optional[int] = None
+                   probe_capacity: Optional[int] = None,
+                   exact: bool = False
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sharded IVF search -> (distances [b,k], row_positions [b,k]).
 
     Same batch contract as ivf_flat.search (internal power-of-two
     padding). probe_capacity=None preserves single-device-identical
     results; an integer < nprobe caps each device's probe budget for
-    ~nprobe/S per-device work at a small recall cost."""
+    ~nprobe/S per-device work at a small recall cost.  `exact` as in
+    ivf_flat.search: each shard re-scores its own survivors from its own
+    vectors, and the merge orders the union by those distances."""
     b, d = queries.shape
     target, qc_eff = _bucket_batch(b, query_chunk)
     q = jnp.asarray(queries, jnp.float32)
@@ -238,7 +243,7 @@ def search_sharded(sidx: ShardedIvfIndex, queries: jnp.ndarray, k: int,
         M.vector_search_pad_rows.inc(target - b)
     M.vector_search_queries.inc(b)
     dists, ids = _search_sharded(sidx, q, k, nprobe, qc_eff, compute_dtype,
-                                 probe_capacity)
+                                 probe_capacity, exact)
     if target != b:
         dists, ids = dists[:b], ids[:b]
     return dists, ids

@@ -145,3 +145,26 @@ def test_fused_q1_step_compiles_for_v5e(one_chip, monkeypatch):
                              if a.shape])
         assert jax.jit(fn).lower(*specs).compile().memory_analysis()
     assert rows == 1 << 20, f"largest step input has {rows} rows"
+
+
+def test_ivf_search_with_exact_rerank_compiles_for_v5e(one_chip):
+    """The program a vector top-k statement waits for: the IVF-Flat
+    search of one query with the exact float32 re-rank of its 60
+    candidates, at the benchmark's shapes (1M x 768 float32 residuals,
+    1,024 lists of at most 4,096 rows, nprobe 8).  It has to fit beside
+    the 3.1 GB index."""
+    from matrixone_tpu.vectorindex import ivf_flat
+    spec = _spec(one_chip)
+    n, d, lists, pad = 1_000_000, 768, 1024, 4096
+    index = ivf_flat.IvfFlatIndex(
+        centroids=spec((lists, d), jnp.float32),
+        vectors=spec((n, d), jnp.float32),
+        r_norm2=spec((n,), jnp.float32), r_dot_c=spec((n,), jnp.float32),
+        ids=spec((n,), jnp.int32), offsets=spec((lists + 1,), jnp.int32),
+        metric=ivf_flat.METRIC_L2, max_cluster_size=pad, n=n)
+    compiled = ivf_flat._search.lower(
+        index, spec((1, d), jnp.float32), k=60, nprobe=8, query_chunk=1,
+        compute_dtype=jnp.bfloat16, use_pallas=False, exact=True).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    assert "ivf_rerank_exact" in compiled.as_text()

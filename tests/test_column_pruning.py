@@ -326,9 +326,10 @@ def test_hash_exchanged_join_finds_its_column(monkeypatch):
 
 
 def test_vector_topk_source_is_narrowed():
-    """The index rewrite copies the Scan's columns into a VectorTopK; the
-    pass narrows that source the same way, and the ids are the exact
-    scan's."""
+    """The index rewrite copies the Scan's columns into a VectorTopK and,
+    for an IVF-Flat index, adds the distance the index yields; the pass
+    narrows that source the same way, so the vector column is fetched only
+    where the statement selects it, and the ids are the exact scan's."""
     import numpy as np
     s = Session()
     s.execute("create table vt (id bigint primary key, title varchar(20),"
@@ -346,9 +347,15 @@ def test_vector_topk_source_is_narrowed():
     s.execute("set ivf_nprobe = 2")
     src = next(n for n in _walk(_plan(s, sql))
                if isinstance(n, P.VectorTopK))
-    assert src.columns == ["id", "v"]
-    assert [n for n, _ in src.schema] == ["vt.id", "vt.v"]
+    assert src.dist_op == "l2_distance"
+    assert src.columns == ["id", P.VECTOR_DIST]
+    assert [n for n, _ in src.schema] == ["vt.id", P.VECTOR_DIST]
     assert s.execute(sql).rows() == exact
+    with_v = sql.replace("select id", "select id, v")
+    src = next(n for n in _walk(_plan(s, with_v))
+               if isinstance(n, P.VectorTopK))
+    assert src.columns == ["id", "v", P.VECTOR_DIST]
+    assert [r[0] for r in s.execute(with_v).rows()] == [r[0] for r in exact]
 
 
 def test_unknown_node_keeps_every_column(small):
