@@ -24,7 +24,9 @@ Key deviations, all deliberate for XLA:
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import functools
 from typing import Dict, Optional
 
 import jax
@@ -32,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from matrixone_tpu.container.dtypes import DType
-from matrixone_tpu.utils import qa
+from matrixone_tpu.utils import metrics as M, qa
 
 #: batch length buckets — powers of two from 1Ki to 1Mi. A batch of 13_000
 #: rows is padded to 16_384 so every operator's jit cache has at most
@@ -148,29 +150,63 @@ def _dtype_ok(have, want: np.dtype) -> bool:
             and have.itemsize < want.itemsize)
 
 
+@functools.partial(jax.jit, static_argnames=("padded", "poison"))
+def _pad_on_device(datas, vals, *, padded: int, poison: bool):
+    """Pad a chunk's device-resident columns to their bucket in one
+    program: each data array gets a tail of `qa.pad_fill`'s value (zeros,
+    or the dtype's canary when moqa is armed: `poison`), each validity a
+    tail of False (`None` = every real row present)."""
+    out_d, out_v = [], []
+    for d, v in zip(datas, vals):
+        n = d.shape[0]
+        fill = qa.canary_value(d.dtype) if poison else None
+        edges = [(0, padded - n, 0)] + [(0, 0, 0)] * (d.ndim - 1)
+        out_d.append(jax.lax.pad(
+            d, jnp.asarray(0 if fill is None else fill, d.dtype), edges))
+        out_v.append(jnp.arange(padded, dtype=jnp.int32) < n if v is None
+                     else jax.lax.pad(v.astype(jnp.bool_), jnp.bool_(False),
+                                      [(0, padded - n, 0)]))
+    return out_d, out_v
+
+
 def from_numpy(arrays: Dict[str, np.ndarray],
                dtypes: Dict[str, DType],
                validity: Optional[Dict[str, np.ndarray]] = None,
                n_rows: Optional[int] = None,
                pad_to: Optional[int] = None) -> DeviceBatch:
-    """Build a padded DeviceBatch from host numpy arrays (zero rows allowed)."""
+    """Build a padded DeviceBatch from host numpy arrays or device-resident
+    jax arrays (zero rows allowed).  A device array of an acceptable dtype
+    never passes through the host: at bucket length it is taken as it is,
+    under it the chunk's device columns are padded there by one program."""
     if n_rows is None:
         n_rows = len(next(iter(arrays.values()))) if arrays else 0
     padded = pad_to if pad_to is not None else bucket_length(max(n_rows, 1))
     cols = {}
+    ragged = []          # device-resident columns under the bucket length
+    paths = collections.Counter()
     for name, arr in arrays.items():
         dt = dtypes[name]
         val = None if validity is None else validity.get(name)
-        if (padded == n_rows and isinstance(arr, jax.Array)
-                and _dtype_ok(arr.dtype, np.dtype(dt.np_dtype))):
-            # already device-resident at the right dtype and length (the
-            # blockcache hands out ready-to-batch device arrays): skip
-            # the host round-trip entirely — this is the warm-scan path
-            jval = (val if isinstance(val, jax.Array)
-                    else jnp.ones(n_rows, jnp.bool_) if val is None
-                    else jnp.asarray(np.asarray(val, np.bool_)))
-            cols[name] = DeviceColumn(data=arr, validity=jval, dtype=dt)
+        on_device = isinstance(arr, jax.Array)
+        if on_device and _dtype_ok(arr.dtype, np.dtype(dt.np_dtype)):
+            if not isinstance(val, jax.Array) and val is not None:
+                val = jnp.asarray(np.asarray(val, np.bool_))
+            if padded == n_rows:
+                # already device-resident at the right dtype and length
+                # (the blockcache hands out ready-to-batch device arrays):
+                # skip the host round-trip entirely — the warm-scan path
+                paths["device"] += 1
+                jval = jnp.ones(n_rows, jnp.bool_) if val is None else val
+                cols[name] = DeviceColumn(data=arr, validity=jval, dtype=dt)
+            else:
+                # a segment's ragged last chunk, or one thinned by
+                # tombstones: padded on the device below, all columns of
+                # the chunk in one dispatch (the slot keeps the order)
+                paths["device_pad"] += 1
+                cols[name] = None
+                ragged.append((name, arr, val))
             continue
+        paths["roundtrip" if on_device else "host"] += 1
         arr = np.asarray(arr)
         if not _dtype_ok(arr.dtype, np.dtype(dt.np_dtype)):
             arr = np.asarray(arr, dtype=dt.np_dtype)
@@ -189,4 +225,12 @@ def from_numpy(arrays: Dict[str, np.ndarray],
         cols[name] = DeviceColumn(data=jnp.asarray(arr),
                                   validity=jnp.asarray(val),
                                   dtype=dt)
+    if ragged:
+        datas, vals = _pad_on_device(
+            tuple(a for _, a, _ in ragged), tuple(v for _, _, v in ragged),
+            padded=padded, poison=qa.armed())
+        for (name, _, _), d, v in zip(ragged, datas, vals):
+            cols[name] = DeviceColumn(data=d, validity=v, dtype=dtypes[name])
+    for path, k in paths.items():
+        M.from_numpy_columns.inc(k, path=path)
     return DeviceBatch(columns=cols, n_rows=jnp.asarray(n_rows, jnp.int32))

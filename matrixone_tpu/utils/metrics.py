@@ -296,6 +296,14 @@ scan_columns = REGISTRY.counter(
     "(the columns the planned Scan carries), pruned (the table's other "
     "columns, which sql/optimize.prune_columns dropped because the plan "
     "references none of them)")
+from_numpy_columns = REGISTRY.counter(
+    "mo_from_numpy_columns_total",
+    "columns container/device.from_numpy staged, once a column of every "
+    "call, by path: device (a device array already at bucket length, "
+    "taken as it is), device_pad (a shorter device array, padded to the "
+    "bucket on the device), host (a host array, padded and uploaded), "
+    "roundtrip (a device array pulled to the host and uploaded again, "
+    "e.g. for a dtype the column cannot keep: held to 0 on a scan)")
 device_wait = REGISTRY.counter(
     "mo_device_wait_total",
     "host reads of a device value that block the statement's path, by "
