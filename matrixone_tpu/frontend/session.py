@@ -1463,18 +1463,14 @@ class Session:
 
     def _tree_vars_deps(self) -> dict:
         """Every build-time knob a compiled operator tree bakes, NAMED:
-        pallas kernel selection, the fusion gates — incl. the
-        join/window/topk kill-switches the planner consults while
-        building fragments — and the join build budget (JoinOp
-        snapshots it at construction).  The armed key auditor
-        (utils/keys.py) hashes these per tree take/put; adding a
-        build-time knob means adding a row HERE (dict order is part of
-        the signature — append, don't reorder)."""
-        from matrixone_tpu.ops import pallas_kernels as PK
+        the fusion gates — incl. the join/window/topk kill-switches
+        the planner consults while building fragments — and the join
+        build budget (JoinOp snapshots it at construction).  The armed
+        key auditor (utils/keys.py) hashes these per tree take/put;
+        adding a build-time knob means adding a row HERE (dict order is
+        part of the signature — append, don't reorder)."""
         from matrixone_tpu.vm import fusion
         return {
-            "use_pallas": bool(PK.effective_use_pallas(
-                self.variables.get("use_pallas"))),
             "plan_fusion": fusion.enabled(self._ctx()),
             "fusion_join": fusion.join_fusion_enabled(),
             "fusion_window": fusion.window_fusion_enabled(),

@@ -75,17 +75,11 @@ def _masked(values: jnp.ndarray, mask: jnp.ndarray, fill) -> jnp.ndarray:
     return jnp.where(mask, values, jnp.asarray(fill, values.dtype))
 
 
-@partial(jax.jit, static_argnames=("max_groups", "use_pallas"))
-def seg_sum(values, gids, mask, max_groups: int, use_pallas: bool = False):
-    """Masked segment sum, through the hand-kernel dispatch seam
-    (ops/kernels.py). use_pallas (session `SET use_pallas = 1` OR the
-    MO_HAND_KERNELS policy, resolved in vm/compile and threaded through
-    AggOp as a static arg) routes float32 sums to the hand-tiled
-    one-hot-matmul kernel; exact int64/decimal and f64 sums always stay
-    on the XLA scatter path (MXU accumulation is float)."""
-    from matrixone_tpu.ops import kernels as HK
-    return HK.grouped_scatter_add(values, gids, mask, max_groups,
-                                  use_pallas=use_pallas)
+@partial(jax.jit, static_argnames=("max_groups",))
+def seg_sum(values, gids, mask, max_groups: int):
+    """Masked segment sum."""
+    return jax.ops.segment_sum(_masked(values, mask, 0), gids,
+                               num_segments=max_groups)
 
 
 @partial(jax.jit, static_argnames=("max_groups",))

@@ -43,22 +43,10 @@ def _matmul_xqT(x: jnp.ndarray, q: jnp.ndarray, compute_dtype) -> jnp.ndarray:
         preferred_element_type=jnp.float32, precision=precision)
 
 
-@partial(jax.jit, static_argnames=("compute_dtype", "use_pallas"))
+@partial(jax.jit, static_argnames=("compute_dtype",))
 def l2_distance_sq(x: jnp.ndarray, q: jnp.ndarray,
-                   compute_dtype=None, use_pallas=None) -> jnp.ndarray:
-    """Squared L2 distances [n, b] between rows of x [n,d] and q [b,d].
-
-    With use_pallas (session `SET use_pallas = 1`, or the MO_USE_PALLAS
-    env default when the kwarg is None) and tile-aligned shapes, the
-    exact-f32 path runs the hand-tiled Pallas kernel
-    (ops/pallas_kernels.py) instead of the XLA default — same math,
-    explicit VMEM staging."""
-    from matrixone_tpu.ops import kernels as HK
-    from matrixone_tpu.ops import pallas_kernels as PK
-    enabled = PK.use_pallas() if use_pallas is None else use_pallas
-    if enabled and compute_dtype is None and x.shape[0] % 1024 == 0:
-        return PK.l2_distance_sq_pallas(x, q, tile_m=1024,
-                                        interpret=HK.interpret())
+                   compute_dtype=None) -> jnp.ndarray:
+    """Squared L2 distances [n, b] between rows of x [n,d] and q [b,d]."""
     xq = _matmul_xqT(x, q, compute_dtype)
     x2 = jnp.sum(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     q2 = jnp.sum(jnp.square(q.astype(jnp.float32)), axis=-1)

@@ -1,44 +1,32 @@
-"""Hand-kernel dispatch seam: the ONE routing point between XLA's
-fused programs and the hand-written Pallas kernels for the two inner
-loops the profile says XLA loses on TPU — the hash-join probe's sorted
-search and the grouped-agg group-scatter.
+"""The one module that knows a hand-written kernel can exist.
 
-Paper L4 analogue: `cgo/xcall.c` — hand SIMD/CUDA kernels live NEXT TO
-the codegen'd operators behind one dispatch table, so "use the hand
-loop" is a routing decision, not a code fork.  Here likewise: callers
-(vm/join, ops/agg) call through this module and never name Pallas
-directly; the choice is
+A caller (`vectorindex/ivf_pq._search`) calls a function here and gets
+an answer; whether XLA or the Pallas kernel of `ops/pallas_kernels.py`
+computed it is chosen here, from what this module can observe: the
+platform of the process's devices, read once, and the shape of the
+arguments.  No session variable, environment variable or caller's
+argument names a kernel.  The choice is a pure function of things a
+compile key already holds (shapes, one platform a process), so no key
+carries it.
 
-  * `MO_HAND_KERNELS=0` — kill switch: always the XLA path (the
-    rollback story when a kernel misbehaves on new hardware);
-  * `MO_HAND_KERNELS=1` — force on (tier-1 runs the Pallas kernels in
-    interpret mode on cpu this way; the bit-identity drills and the
-    moqa padding canary ride it);
-  * unset / `auto` — on where the devices are TPUs, off on cpu
-    (XLA:CPU's native scatter/searchsorted beat interpreted Pallas by
-    orders of magnitude).
+A kernel is here because it beat the XLA code beside it on the chip, at
+the shapes its caller passes and at the precision of that code, by more
+than the spread of five alternating repeats (`tools/profile_pallas.py`;
+the table is in PERF.md, PR 31), and is chosen under the condition it was
+timed under.  The kernels that lost, or that nothing called, were
+deleted there: the join probe's sorted search, the pairwise L2 and its
+masked sibling, the one-hot segment sum.
 
-The platform is read ONCE from `jax.devices()` (`platform()`), and every
-production caller of a Pallas kernel passes `interpret=interpret()`:
-compiled on a TPU, interpreted only where a force switch
-(`MO_HAND_KERNELS=1`, `SET use_pallas = 1`, `MO_USE_PALLAS=1`) turned a
-kernel on for a platform that has no kernel compiler.  The auto route
-is on only on TPU, so it can never select interpret mode.
-
-Identity contract: `sorted_lookup` is bit-identical to the XLA path on
-EVERY backend by construction (integer count, no rounding, no order
-sensitivity — tools/precheck --kernel-smoke enforces it).
-`grouped_scatter_add` routes only float32 sums to the MXU one-hot
-kernel (same rule the session `SET use_pallas` path always had);
-exact int64/decimal/f64 sums stay on the XLA scatter unconditionally.
-The resolved routing is baked into traced executables, so every fused
-compile key carries `signature()` (vm/fusion, vm/fusion_join).
+On a platform with no kernel compiler the kernel runs only where a test
+substitutes the choice (`adc_kernel_chosen`), and then in interpret mode
+(`interpret()`).
 """
 
 from __future__ import annotations
 
 import functools
-import os
+
+_ADC_TILE = 128      # the tile the kernel was timed with on the chip
 
 
 @functools.lru_cache(maxsize=None)
@@ -50,70 +38,29 @@ def platform() -> str:
 
 
 def interpret() -> bool:
-    """The `interpret=` every production Pallas call passes (see the
-    module docstring): False on TPU, True only under a force switch
-    elsewhere."""
+    """The `interpret=` every Pallas call here passes: compiled on a TPU,
+    interpreted elsewhere (reached only through a test's substitute)."""
     return platform() != "tpu"
 
 
-def _flag() -> str:
-    return os.environ.get("MO_HAND_KERNELS", "auto").lower()
+def adc_kernel_chosen(candidates: int) -> bool:
+    """IVF-PQ's ADC scores ride the one-hot matmul on a TPU where the
+    candidate lists are padded to whole 128-lane tiles (`ivf_pq.build`
+    always pads so)."""
+    return platform() == "tpu" and candidates % _ADC_TILE == 0
 
 
-def enabled() -> bool:
-    """Resolve the hand-kernel routing for this process/backend.  Read
-    host-side at trace/compile time only; consumers record it in their
-    compile keys so a flip re-traces instead of colliding."""
-    v = _flag()
-    if v in ("1", "on", "true"):
-        return True
-    if v in ("0", "off", "false"):
-        return False
-    return platform() == "tpu"
-
-
-def signature() -> tuple:
-    """Compile-key component: the resolved routing (the kernels are
-    trace-time choices, invisible in input dtypes/shapes)."""
-    return ("hand_kernels", enabled())
-
-
-def sorted_lookup(sorted_vals, queries):
-    """searchsorted-left over the sorted build-side hashes (uint64):
-    the probe's per-row entry point into the hash run.  Pallas
-    count-less-than kernel when enabled, jnp.searchsorted otherwise —
-    bit-identical either way."""
+def adc_scores(codes, lut):
+    """scores[g, p] = sum_m lut[g, m, codes[g, p, m]]: codes [G, P, M]
+    uint8/int32 (G query-probe groups of P candidates), lut [G, M, 256]
+    float32."""
     import jax.numpy as jnp
-    if enabled():
+    if adc_kernel_chosen(codes.shape[1]):
         from matrixone_tpu.ops import pallas_kernels as PK
-        return PK.sorted_search_pallas(sorted_vals, queries,
-                                       interpret=interpret())
-    return jnp.searchsorted(sorted_vals, queries).astype(jnp.int32)
-
-
-def grouped_scatter_add(values, gids, mask, max_groups: int,
-                        use_pallas: bool = False):
-    """Masked segment sum — the grouped-agg group-scatter.  float32
-    values ride the one-hot-matmul Pallas kernel when routing says so;
-    every exact dtype (int64 counts/decimals, f64) stays on the XLA
-    scatter.  `use_pallas` must be resolved OUTSIDE any jit (it picks
-    the traced program): vm/compile ORs the session `SET use_pallas`
-    with `enabled()` and threads it as a static jit arg, so the routing
-    is part of the jit cache key — this function never reads the env."""
-    import jax.numpy as jnp
-    if (use_pallas and values.dtype == jnp.float32
-            and max_groups <= 4096 and values.shape[0] > 0):
-        from matrixone_tpu.ops import pallas_kernels as PK
-        n = values.shape[0]
-        tile = 512
-        padded = ((n + tile - 1) // tile) * tile
-        if padded != n:
-            values = jnp.pad(values, (0, padded - n))
-            gids = jnp.pad(gids, (0, padded - n))
-            mask = jnp.pad(mask, (0, padded - n))   # pads False
-        return PK.segment_sum_pallas(values, gids, mask,
-                                     num_segments=max_groups,
-                                     tile_n=tile, interpret=interpret())
-    import jax
-    v = jnp.where(mask, values, jnp.asarray(0, values.dtype))
-    return jax.ops.segment_sum(v, gids, num_segments=max_groups)
+        return PK.adc_score_pallas(codes, lut, tile_c=_ADC_TILE,
+                                   interpret=interpret())
+    gathered = jnp.take_along_axis(
+        lut[:, None, :, :],                          # [G, 1, M, 256]
+        codes[..., None].astype(jnp.int32),          # [G, P, M, 1]
+        axis=3)[..., 0]                              # [G, P, M]
+    return jnp.sum(gathered, axis=-1)

@@ -77,8 +77,7 @@ def execute_fragment(catalog, header: dict) -> Tuple[dict, bytes]:
         catalog = ScopedCatalog(catalog, header["account"])
     ctx = ExecContext(catalog=catalog, frozen_ts=snapshot_ts,
                       variables={"batch_rows":
-                                 int(header.get("batch_rows", 1 << 16)),
-                                 **header.get("session_vars", {})})
+                                 int(header.get("batch_rows", 1 << 16))})
     plan = plan_from_json(header["plan"])
     child_op = compile_plan(plan, ctx)
     sig = (table_signature(catalog, header["shard_table"], snapshot_ts)
@@ -550,19 +549,13 @@ def try_distribute(node, catalog, ctx, peers: FragmentPeers,
         else:
             snap = max(ctx.snapshot_ts or 0,
                        getattr(catalog, "committed_ts", 0)) or None
-        # forward session execution knobs so SET use_pallas behaves the
-        # same distributed as local (no silent local/remote divergence)
-        sess_vars = {k: v for k, v in (ctx.variables or {}).items()
-                     if k in ("use_pallas",)}
         if split.kind == "agg":
-            mat = _dist_aggregate(split, catalog, snap, peers, batch_rows,
-                                  sess_vars)
+            mat = _dist_aggregate(split, catalog, snap, peers, batch_rows)
         elif split.kind == "join":
             mat = _dist_shuffle_join(split, catalog, snap, peers,
-                                     batch_rows, sess_vars)
+                                     batch_rows)
         else:
-            mat = _dist_topk(split, catalog, snap, peers, batch_rows,
-                             sess_vars)
+            mat = _dist_topk(split, catalog, snap, peers, batch_rows)
     except Exception as e:     # noqa: BLE001 — fall back to local
         import sys
         print(f"[dist] fragment execution failed, running locally: "
@@ -586,7 +579,7 @@ def _check_sigs(results, addrs) -> None:
 
 
 def _dist_aggregate(split: _Split, catalog, snap, peers: FragmentPeers,
-                    batch_rows: int, sess_vars=None) -> P.Materialized:
+                    batch_rows: int) -> P.Materialized:
     agg: P.Aggregate = split.split
     n = len(peers.addrs)
     child_json = plan_to_json(agg.child)
@@ -601,7 +594,6 @@ def _dist_aggregate(split: _Split, catalog, snap, peers: FragmentPeers,
             "aggs": [agg_to_json(a) for a in agg.aggs],
             "snapshot_ts": snap,
             "batch_rows": batch_rows,
-            "session_vars": sess_vars or {},
             "shard_table": split.scan_table,
             "account": getattr(catalog, "_acct", None),
         })
@@ -751,7 +743,7 @@ def _merge_scalar(agg: P.Aggregate, results) -> P.Materialized:
 
 
 def _dist_topk(split: _Split, catalog, snap, peers: FragmentPeers,
-               batch_rows: int, sess_vars=None) -> P.PlanNode:
+               batch_rows: int) -> P.PlanNode:
     """Per-peer local top-(k+offset) over its shard, concatenated; the
     ORIGINAL TopK re-runs at the coordinator over the union (exact: every
     global top-k row is in its shard's local top-(k+offset))."""
@@ -767,7 +759,6 @@ def _dist_topk(split: _Split, catalog, snap, peers: FragmentPeers,
                            owners[i], n),
         "snapshot_ts": snap,
         "batch_rows": batch_rows,
-        "session_vars": sess_vars or {},
         "shard_table": split.scan_table,
         "account": getattr(catalog, "_acct", None),
     } for i in range(n)]
@@ -943,8 +934,7 @@ def run_shuffle_scan(catalog, header: dict) -> Tuple[dict, bytes]:
         catalog = ScopedCatalog(catalog, header["account"])
     ctx = ExecContext(catalog=catalog, frozen_ts=snapshot_ts,
                       variables={"batch_rows":
-                                 int(header.get("batch_rows", 1 << 16)),
-                                 **header.get("session_vars", {})})
+                                 int(header.get("batch_rows", 1 << 16))})
     plan = plan_from_json(header["plan"])
     op = compile_plan(plan, ctx)
     schema = plan.schema
@@ -1035,8 +1025,7 @@ def run_shuffle_join(catalog, header: dict) -> Tuple[dict, bytes]:
         schema=_schema_from_json(header["out_schema"]))
     ctx = ExecContext(catalog=catalog,
                       variables={"batch_rows":
-                                 int(header.get("batch_rows", 1 << 16)),
-                                 **header.get("session_vars", {})})
+                                 int(header.get("batch_rows", 1 << 16))})
     op = compile_plan(join, ctx)
     return _run_collect(op, join.schema)
 
@@ -1084,8 +1073,8 @@ def _shuffle_cleanup(peers: "FragmentPeers", sid) -> None:
 
 
 def _dist_shuffle_join(split: _Split, catalog, snap,
-                       peers: FragmentPeers, batch_rows: int,
-                       sess_vars=None) -> P.Materialized:
+                       peers: FragmentPeers,
+                       batch_rows: int) -> P.Materialized:
     from matrixone_tpu.cluster.rpc import parse_addr
     import uuid as _uuid
     join: P.Join = split.split
@@ -1101,7 +1090,6 @@ def _dist_shuffle_join(split: _Split, catalog, snap,
     rjson = plan_to_json(join.right)
     common = {
         "snapshot_ts": snap, "batch_rows": batch_rows,
-        "session_vars": sess_vars or {},
         "account": getattr(catalog, "_acct", None),
         "shuffle_id": sid, "n_buckets": n, "peer_addrs": peer_addrs,
     }

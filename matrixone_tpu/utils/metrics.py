@@ -423,7 +423,7 @@ fusion_exec = REGISTRY.counter(
 pallas_traces = REGISTRY.counter(
     "mo_pallas_trace_total",
     "Pallas kernels traced into a program, by kernel and by whether "
-    "the trace was for interpret mode (ops/pallas_kernels.py; counted "
+    "the trace was for interpret mode (chosen by ops/kernels.py; counted "
     "at trace time, so once per compiled program, not per dispatch)")
 
 # ---- Python/JAX UDF subsystem (udf/, reference: pkg/udf/pythonservice)

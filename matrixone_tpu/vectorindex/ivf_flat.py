@@ -208,17 +208,11 @@ def _bucket_batch(b: int, query_chunk: int) -> Tuple[int, int]:
     return target, 1 << (qc.bit_length() - 1)
 
 
-def _probe(index: IvfFlatIndex, q: jnp.ndarray, nprobe: int,
-           use_pallas: bool):
+def _probe(index: IvfFlatIndex, q: jnp.ndarray, nprobe: int):
     """Stage 1: centroid scores + top-nprobe clusters per query.
     Full f32 precision: these scores re-enter the candidate distances."""
     if index.metric == METRIC_L2:
-        # orient the tiled axis along nlist (the large dim) and let the
-        # shared gate in ops/distance.py decide pallas-vs-XLA — one
-        # dispatch point, and an explicit use_pallas=False here really
-        # disables the kernel even when the env default is on
-        cdist = D.l2_distance_sq(index.centroids, q,
-                                 use_pallas=use_pallas).T   # [b, nlist]
+        cdist = D.l2_distance_sq(index.centroids, q).T      # [b, nlist]
     else:
         cdist = -D.inner_product(q, index.centroids)
     cprobe_scores, probes = jax.lax.top_k(-cdist, nprobe)  # [b, nprobe]
@@ -295,15 +289,14 @@ def _score_chunk(index: IvfFlatIndex, qc, pc, cs, pmask, k: int,
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "query_chunk",
-                                   "compute_dtype", "use_pallas", "exact"))
+                                   "compute_dtype", "exact"))
 def _search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
-            query_chunk: int, compute_dtype, use_pallas: bool,
-            exact: bool = False):
+            query_chunk: int, compute_dtype, exact: bool = False):
     b, d = queries.shape
     q = queries.astype(jnp.float32)
     if index.metric == METRIC_COSINE:
         q = D.normalize(q)
-    cprobe_scores, probes = _probe(index, q, nprobe, use_pallas)
+    cprobe_scores, probes = _probe(index, q, nprobe)
     n_chunks = b // query_chunk
     q_chunks = q.reshape(n_chunks, query_chunk, d)
     probe_chunks = probes.reshape(n_chunks, query_chunk, nprobe)
@@ -322,8 +315,7 @@ def _search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
 
 def search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
            query_chunk: int = 32, compute_dtype=jnp.bfloat16,
-           use_pallas: bool = False, exact: bool = False
-           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+           exact: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched IVF search -> (distances [b,k], row_positions [b,k] int32).
 
     With `exact` the k results carry float32 distances recomputed from
@@ -334,9 +326,7 @@ def search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
     size b works: queries are padded internally to the next power of two
     (pad rows are zero queries whose results are stripped before return),
     so callers never carry padding code and compiled-shape reuse is
-    bounded at log2(max batch) entries. use_pallas (session
-    `SET use_pallas = 1`) runs the centroid probe through the hand-tiled
-    fused-epilogue kernel when nlist is tile-aligned.
+    bounded at log2(max batch) entries.
     """
     b, d = queries.shape
     target, qc_eff = _bucket_batch(b, query_chunk)
@@ -346,13 +336,13 @@ def search(index: IvfFlatIndex, queries: jnp.ndarray, k: int, nprobe: int,
         M.vector_search_pad_rows.inc(target - b)
     M.vector_search_queries.inc(b)
     dists, ids = _search(index, q, k, nprobe, qc_eff, compute_dtype,
-                         use_pallas, exact)
+                         exact)
     if target != b:
         dists, ids = dists[:b], ids[:b]
     return dists, ids
 
 
-_probe_jit = jax.jit(_probe, static_argnames=("nprobe", "use_pallas"))
+_probe_jit = jax.jit(_probe, static_argnames=("nprobe",))
 _score_jit = jax.jit(_score_chunk, static_argnames=("k", "compute_dtype"))
 
 
@@ -375,10 +365,9 @@ def search_profiled(index: IvfFlatIndex, queries: jnp.ndarray, k: int,
     score_fn = _score_jit
     pmask = jnp.ones((qc_eff, nprobe), jnp.bool_)
     # warm the compile caches so stage times measure execution, not XLA
-    jax.block_until_ready(probe_fn(index, q, nprobe=nprobe,
-                                   use_pallas=False))
+    jax.block_until_ready(probe_fn(index, q, nprobe=nprobe))
     t0 = time.perf_counter()
-    cs, probes = probe_fn(index, q, nprobe=nprobe, use_pallas=False)
+    cs, probes = probe_fn(index, q, nprobe=nprobe)
     jax.block_until_ready(probes)
     t_probe = time.perf_counter() - t0
     jax.block_until_ready(score_fn(index, q[:qc_eff], probes[:qc_eff],

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from matrixone_tpu.ops import distance as D
+from matrixone_tpu.ops import kernels as HK
 from matrixone_tpu.vectorindex import kmeans
 
 METRIC_L2 = "l2"
@@ -131,10 +132,10 @@ def build(dataset: jnp.ndarray, nlist: int, n_subspaces: int = 16,
 
 
 @partial(jax.jit, static_argnames=("k", "nprobe", "query_chunk",
-                                   "compute_dtype", "use_pallas"))
+                                   "compute_dtype"))
 def _search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
-            query_chunk: int = 32, compute_dtype=None,
-            use_pallas: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+            query_chunk: int = 32,
+            compute_dtype=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     b, d = queries.shape
     M = index.n_subspaces
     ds = d // M
@@ -176,21 +177,10 @@ def _search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
         cand = jnp.where(valid, cand, 0)             # [qc, nprobe, pad]
         cand_codes = index.codes[cand]               # [qc, nprobe, pad, M]
         # dist = sum_m LUT[..., m, code_m]
-        if use_pallas and pad % 128 == 0:
-            from matrixone_tpu.ops import kernels as HK
-            from matrixone_tpu.ops import pallas_kernels as PK
-            g = query_chunk * nprobe
-            dist = PK.adc_score_pallas(
-                cand_codes.reshape(g, pad, M),
-                lut.reshape(g, M, 256), tile_c=128,
-                interpret=HK.interpret()
-            ).reshape(query_chunk, nprobe, pad)
-        else:
-            gathered = jnp.take_along_axis(
-                lut[:, :, None, :, :],                   # [qc,np,1,M,256]
-                cand_codes[..., None].astype(jnp.int32),  # [qc,np,pad,M,1]
-                axis=4)[..., 0]                          # [qc,np,pad,M]
-            dist = jnp.sum(gathered, axis=-1)            # [qc, nprobe, pad]
+        g = query_chunk * nprobe
+        dist = HK.adc_scores(
+            cand_codes.reshape(g, pad, M), lut.reshape(g, M, 256)
+        ).reshape(query_chunk, nprobe, pad)
         dist = jnp.where(valid, dist, jnp.inf)
         # two-stage top-k (same shape argument as ivf_flat: the top-k of
         # the probe union is contained in the union of per-probe top-ks)
@@ -208,15 +198,12 @@ def _search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
 
 
 def search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
-           query_chunk: int = 32, compute_dtype=None,
-           use_pallas: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
+           query_chunk: int = 32,
+           compute_dtype=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Batched ADC search -> (approx distances [b,k], row positions [b,k]).
 
     Same batch contract as ivf_flat.search: any b works, padded
-    internally to the next power of two. use_pallas (session
-    `SET use_pallas = 1`) scores candidates through the hand-tiled
-    one-hot-matmul ADC kernel (ops/pallas_kernels.py) instead of the XLA
-    take_along_axis gather when the cluster pad is tile-aligned."""
+    internally to the next power of two."""
     from matrixone_tpu.utils import metrics as Mx
     from matrixone_tpu.vectorindex.ivf_flat import _bucket_batch
     b, d = queries.shape
@@ -227,8 +214,7 @@ def search(index: IvfPqIndex, queries: jnp.ndarray, k: int, nprobe: int,
         Mx.vector_search_pad_rows.inc(target - b)
     Mx.vector_search_queries.inc(b)
     dists, ids = _search(index, q, k, nprobe, query_chunk=qc_eff,
-                         compute_dtype=compute_dtype,
-                         use_pallas=use_pallas)
+                         compute_dtype=compute_dtype)
     if target != b:
         dists, ids = dists[:b], ids[:b]
     return dists, ids

@@ -76,12 +76,7 @@ def _compile_node(node: P.PlanNode, ctx: ExecContext) -> ops.Operator:
     if isinstance(node, P.UdfAggregate):
         return ops.UdfAggregateOp(node, _compile_node(node.child, ctx))
     if isinstance(node, P.Aggregate):
-        from matrixone_tpu.ops import kernels as HK
-        from matrixone_tpu.ops import pallas_kernels as PK
-        return ops.AggOp(node, _compile_node(node.child, ctx),
-                         use_pallas=PK.effective_use_pallas(
-                             (ctx.variables or {}).get("use_pallas"))
-                         or HK.enabled())
+        return ops.AggOp(node, _compile_node(node.child, ctx))
     if isinstance(node, P.Sort):
         return ops.SortOp(node, _compile_node(node.child, ctx))
     if isinstance(node, P.TopK):

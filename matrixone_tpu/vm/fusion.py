@@ -1250,7 +1250,7 @@ class FusedFragmentOp(O.Operator):
                       for i, e in self._dictdeps)
         return (self._plan_sig, rt_sig, colsig,
                 int(ex.mask.shape[0]), baked, dicts, sizes,
-                ENC.signature(), HK.signature())
+                ENC.signature())
 
     def _audit_deps(self, envs, rt_lift, scan_filters, sizes_flags):
         """Capture-relevant content RECOMPUTED FROM SOURCE STATE for
@@ -1275,9 +1275,9 @@ class FusedFragmentOp(O.Operator):
             "sizes_flags": sizes_flags,
             "chain_shape": self.describe(),
             "shard_ctx": self._shard_ctx(),
-            # trace-time dtype policy: bf16 lanes / hand-kernel routing
-            # are baked into the executable, invisible in input dtypes
-            "encoding_policy": (ENC.signature(), HK.signature()),
+            # trace-time dtype policy: bf16 lanes are baked into the
+            # executable, invisible in input dtypes
+            "encoding_policy": ENC.signature(),
         }
 
     def _audit_exprs(self) -> list:

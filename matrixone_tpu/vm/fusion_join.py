@@ -357,7 +357,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                tuple(FF._dict_key(d) for d in self._bkey_dicts),
                tuple(FF._dict_key(FF._static_dict(e, self._build_dicts))
                      for _i, e in binfo.dictdep),
-               FF.ENC.signature(), FF.HK.signature())
+               FF.ENC.signature())
         entry = FF.CACHE.entry(key)
         if keyaudit.armed():
             keyaudit.audit("vm/fusion_join.py:joinbuild", key, {
@@ -372,8 +372,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                       for lit in binfo.baked),
                 "lift_arity": len(lift_lits),
                 "rf_spec_indexes": tuple(i for i, _lk in specs),
-                "encoding_policy": (FF.ENC.signature(),
-                                    FF.HK.signature()),
+                "encoding_policy": FF.ENC.signature(),
             })
         bschema = tuple((nm, c.dtype)
                         for nm, c in build.batch.columns.items())
@@ -460,7 +459,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
             for k, bd in zip(node.left_keys, self._bkey_dicts))
         return (self._plan_sig, colsig, int(ex.mask.shape[0]), baked,
                 dicts, sizes_flags, mm, build_key, keydicts,
-                FF.ENC.signature(), FF.HK.signature())
+                FF.ENC.signature())
 
     def _make_probe_step(self, trig_schema, bschema, sizes, flags, envs,
                          mm):

@@ -45,7 +45,6 @@ import numpy as np
 from matrixone_tpu.container import dtypes as dt
 from matrixone_tpu.container.device import DeviceBatch, DeviceColumn
 from matrixone_tpu.ops import filter as F, hash as H
-from matrixone_tpu.ops import kernels as HK
 from matrixone_tpu.sql import plan as P
 from matrixone_tpu.vm.exprs import ExecBatch, eval_expr
 from matrixone_tpu.vm.operators import Operator, _broadcast_full, _concat_batches
@@ -245,10 +244,8 @@ def expand_probe(node, ex: ExecBatch, build: ExecBatch, sorted_hash,
     Pure (the overflow flag stays on device): JoinOp device_gets it,
     the fused probe program returns it as a traced output."""
     np_ = ex.padded_len
-    # entry point into the sorted hash run: routed through the
-    # hand-kernel seam (Pallas count-less-than on TPU, XLA searchsorted
-    # otherwise — bit-identical either way)
-    start = HK.sorted_lookup(sorted_hash, phash)          # [np]
+    # entry point into the sorted hash run (searchsorted-left)
+    start = jnp.searchsorted(sorted_hash, phash).astype(jnp.int32)  # [np]
     lane = jnp.arange(mm, dtype=jnp.int32)
     pos = start[:, None] + lane[None, :]                  # [np, mm]
     pos_c = jnp.clip(pos, 0, sorted_hash.shape[0] - 1)

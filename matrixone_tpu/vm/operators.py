@@ -609,15 +609,13 @@ class AggOp(Operator):
     def __init__(self, node: P.Aggregate, child: Operator,
                  max_groups: int = 4096,
                  max_device_groups: int = 1 << 21,
-                 spill_partitions: int = 16,
-                 use_pallas: bool = False):
+                 spill_partitions: int = 16):
         self.node = node
         self.child = child
         self.schema = node.schema
         self.max_groups = max_groups
         self.max_device_groups = max(max_groups, max_device_groups)
         self.spill_partitions = spill_partitions
-        self.use_pallas = use_pallas
         self._spill: Optional[_AggSpill] = None
 
     def _grow(self, needed: int, allow_spill: bool) -> None:
@@ -959,8 +957,7 @@ class AggOp(Operator):
         present = jnp.arange(mg, dtype=jnp.int32) < gi.num_groups
         partials = []
         for a, v in zip(self.node.aggs, values):
-            partials.append(_grouped_step(a, gi, v, mask, mg,
-                                          use_pallas=self.use_pallas))
+            partials.append(_grouped_step(a, gi, v, mask, mg))
         return {"keys": rep_k, "kvalid": rep_v, "present": present,
                 "partials": partials, "n": gi.num_groups}
 
@@ -1164,7 +1161,7 @@ def _host_bit_reduce(func: str, data, gids, mask, mg: int):
 
 
 def _grouped_step(a: AggCall, gi, col: Optional[DeviceColumn],
-                  row_mask, mg: int, use_pallas: bool = False):
+                  row_mask, mg: int):
     """Per-batch partial for one aggregate over PRE-EVALUATED values
     (col = _agg_value(...) or a revived spill chunk; None for count(*))."""
     if a.func == "count" and a.arg is None:
@@ -1173,8 +1170,7 @@ def _grouped_step(a: AggCall, gi, col: Optional[DeviceColumn],
     if a.func == "count":
         return {"count": A.seg_count(gi.gids, m, mg)}
     if a.func == "sum":
-        return {"sum": A.seg_sum(col.data, gi.gids, m, mg,
-                                 use_pallas=use_pallas),
+        return {"sum": A.seg_sum(col.data, gi.gids, m, mg),
                 "count": A.seg_count(gi.gids, m, mg)}
     if a.func == "avg":
         return {"sum": A.seg_sum(col.data.astype(jnp.float64)

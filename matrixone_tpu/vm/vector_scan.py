@@ -170,11 +170,6 @@ class VectorTopKOp(Operator):
             nprobe = min(self.node.nprobe, index.nlist)
             pool = nprobe * index.max_cluster_size
             k = min(self.node.k, index.n, pool) or 1
-            # session SET use_pallas = 1 routes the probe/ADC kernels
-            # through the hand-tiled Pallas paths (gpu_mode analogue)
-            from matrixone_tpu.ops import pallas_kernels as PK
-            up = PK.effective_use_pallas(
-                (self.ctx.variables or {}).get("use_pallas"))
             # no host-side padding: search buckets the batch internally
             sharded_ix = (self._sharded_view(ix, index)
                           if ix.algo == "ivfflat" else None)
@@ -187,11 +182,11 @@ class VectorTopKOp(Operator):
                         exact=exact)
                 elif ix.algo == "ivfpq":
                     found = ivf_pq.search(index, jnp.asarray(q), k=k,
-                                          nprobe=nprobe, use_pallas=up)
+                                          nprobe=nprobe)
                 else:
                     found = ivf_flat.search(
                         index, jnp.asarray(q), k=k, nprobe=nprobe,
-                        use_pallas=up, exact=exact)
+                        exact=exact)
             with motrace.span("vector.search.wait"):
                 dists, pos = jax.device_get(found)
             M.device_wait.inc(site="vector_search")

@@ -1,4 +1,4 @@
 set batch_rows = 4096;
 set ivf_nprobe = 16;
-set use_pallas = 0;
+set query_shards = 0;
 select 1;

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from matrixone_tpu.ops import agg, filter as F, sort as msort
 from matrixone_tpu.container.device import DeviceBatch, DeviceColumn
@@ -171,3 +172,61 @@ def test_sort_nulls_ordering():
     # DESC: nulls last
     order = msort.sort_indices([v], [va], [True], row_mask)
     assert np.asarray(order)[:4].tolist() == [2, 3, 0, 1]
+
+
+def _seg_sum_case(name):
+    """-> (values, gids, mask, max_groups): the inputs the deleted Pallas
+    segment-sum tests held their kernel to, for the XLA scatter."""
+    rng = np.random.default_rng(21)
+
+    def rows(n, groups, dtype=np.float32, live=0.8):
+        return (rng.standard_normal(n).astype(dtype),
+                rng.integers(0, groups, n).astype(np.int32),
+                rng.random(n) < live, groups)
+    if name == "f32_4096_rows_17_groups":
+        return rows(4096, 17)
+    if name == "f32_2048_rows_1_group":
+        return rows(2048, 1)
+    if name == "f32_8192_rows_512_groups":
+        return rows(8192, 512)
+    if name == "f32_1_group":
+        return rows(5000, 1)
+    if name == "f32_4000_groups":
+        return rows(5000, 4000)
+    if name == "f32_4096_groups":
+        return rows(5000, 4096)
+    if name == "zero_rows":
+        return (np.zeros(0, np.float32), np.zeros(0, np.int32),
+                np.zeros(0, bool), 8)
+    if name == "masked_rows_never_leak":
+        # every masked row carries an in-range gid and a large value
+        return (np.full(2048, 100.0, np.float32), np.zeros(2048, np.int32),
+                np.arange(2048) < 3, 4)
+    if name == "int64_exact":
+        return (np.array([1 << 40, 3, -7, 1 << 40], np.int64),
+                np.array([0, 0, 1, 1], np.int32),
+                np.array([True, True, True, False]), 2)
+    assert name == "float64_exact"
+    return (np.array([1e-17, 1.0, 1e-17], np.float64),
+            np.zeros(3, np.int32), np.ones(3, bool), 1)
+
+
+@pytest.mark.parametrize("name", [
+    "f32_4096_rows_17_groups", "f32_2048_rows_1_group",
+    "f32_8192_rows_512_groups", "f32_1_group", "f32_4000_groups",
+    "f32_4096_groups", "zero_rows", "masked_rows_never_leak", "int64_exact",
+    "float64_exact"])
+def test_seg_sum_matches_numpy(name):
+    v, g, m, groups = _seg_sum_case(name)
+    got = np.asarray(agg.seg_sum(jnp.asarray(v), jnp.asarray(g),
+                                 jnp.asarray(m), groups))
+    assert got.dtype == v.dtype and got.shape == (groups,)
+    if name == "float64_exact":      # one group: a left fold, as numpy's
+        assert got[0] == np.float64(1e-17) + 1.0 + 1e-17
+        return
+    want = np.zeros(groups, np.int64 if v.dtype == np.int64 else np.float64)
+    np.add.at(want, g[m], v[m])
+    if v.dtype == np.int64:
+        assert got.tolist() == want.tolist()
+    else:
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)

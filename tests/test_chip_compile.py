@@ -1,9 +1,12 @@
 """Compile-only tests for a described TPU v5e (`v5e:2x2`), no chip needed:
-the Pallas kernels of `ops/pallas_kernels.py` at the widths
-`chip_smoke.py` drives, and one fused TPC-H Q1 step at the SF1 batch
-shape.  The chip's compiler is installed in the sandbox; what it refuses
-here (int64 block indices, unaligned blocks, fast-memory overflow) it
-refuses on the chip, and interpret mode shows none of it.
+the Pallas kernel of `ops/pallas_kernels.py` and the programs the served
+path hands the chip, at the widths `chip_smoke.py` and the benchmark
+drive: one fused TPC-H Q1 step at the SF1 batch shape, Q3's fused join
+programs, the IVF-Flat and IVF-PQ searches over 1M x 768, the unfused
+float32 grouped step.  The chip's
+compiler is installed in the sandbox; what it refuses here (int64 block
+indices, unaligned blocks, fast-memory overflow, a program that does not
+fit) it refuses on the chip, and interpret mode shows none of it.
 
 The only file of its kind: the topology is described inside a
 module-scoped fixture (never at import — one process holds the TPU
@@ -21,7 +24,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from matrixone_tpu.ops import kernels as HK
-from matrixone_tpu.ops import pallas_kernels as PK
 
 
 @pytest.fixture(scope="module")
@@ -55,77 +57,82 @@ def _spec(one_chip):
                                                      sharding=one_chip)
 
 
-# (kernel, its keyword arguments, argument shapes and dtypes): the
-# widths are chip_smoke.py's — 768-d vectors, 1,024 lists, one query per
-# SQL statement or a 64-query batch, the seam's 512-row tile over 4,096
-# groups, an SF1 orders build side under a 2^20-row probe batch, and the
-# 96 subspaces IVF-PQ picks for 768 dimensions at its 128-lane pad
-KERNELS = {
-    "l2_one_query": (
-        PK.l2_distance_sq_pallas, dict(tile_m=1024),
-        [((1024, 768), jnp.float32), ((1, 768), jnp.float32)]),
-    "l2_query_batch": (
-        PK.l2_distance_sq_pallas, dict(tile_m=1024),
-        [((8192, 768), jnp.float32), ((64, 768), jnp.float32)]),
-    "l2_masked": (
-        PK.l2_distance_sq_masked_pallas, dict(tile_m=1024),
-        [((8192, 768), jnp.float32), ((64, 768), jnp.float32),
-         ((8192,), jnp.bool_)]),
-    "segment_sum_seam_tile": (
-        PK.segment_sum_pallas, dict(num_segments=4096, tile_n=512),
-        [((1 << 20,), jnp.float32), ((1 << 20,), jnp.int32),
-         ((1 << 20,), jnp.bool_)]),
-    "segment_sum_default_tile": (
-        PK.segment_sum_pallas, dict(num_segments=4096),
-        [((1 << 20,), jnp.float32), ((1 << 20,), jnp.int32),
-         ((1 << 20,), jnp.bool_)]),
-    "sorted_search_sf1": (
-        PK.sorted_search_pallas, {},
-        [((1_500_000,), jnp.uint64), ((1 << 20,), jnp.uint64)]),
-    "adc_768d": (
-        PK.adc_score_pallas, dict(tile_c=128),
-        [((256, 128, 96), jnp.uint8), ((256, 96, 256), jnp.float32)]),
-}
-
-
-@pytest.mark.parametrize("name", sorted(KERNELS))
-def test_pallas_kernel_compiles_for_v5e(one_chip, name):
-    fn, kwargs, shapes = KERNELS[name]
+def test_adc_kernel_compiles_for_v5e(one_chip):
+    """The ADC kernel at the 96 subspaces IVF-PQ picks for 768
+    dimensions, 256 query-probe groups, its 128-lane tile."""
+    from matrixone_tpu.ops import pallas_kernels as PK
     spec = _spec(one_chip)
     text = _compiled_text(
-        lambda *a: fn(*a, interpret=False, **kwargs),
-        *[spec(shape, dtype) for shape, dtype in shapes])
+        lambda codes, lut: PK.adc_score_pallas(codes, lut, tile_c=128,
+                                               interpret=False),
+        spec((256, 128, 96), jnp.uint8), spec((256, 96, 256), jnp.float32))
     assert "tpu_custom_call" in text
 
 
-def test_seam_routes_the_probe_to_the_kernel_on_tpu(one_chip, monkeypatch):
-    """Where the devices are TPUs the seam's auto route compiles the
-    Pallas kernel (never interprets it); the test stands in for the
-    platform, the program has no option for that."""
-    monkeypatch.setattr(HK, "platform", lambda: "tpu")
-    monkeypatch.delenv("MO_HAND_KERNELS", raising=False)
-    assert HK.enabled() and not HK.interpret()
-    spec = _spec(one_chip)
-    text = _compiled_text(HK.sorted_lookup,
-                          spec((1_500_000,), jnp.uint64),
-                          spec((1 << 20,), jnp.uint64))
-    assert "tpu_custom_call" in text
+def _lowered_for(one_chip, fn, compiled):
+    """`fn`, a step the CPU run of a statement traced, compiled again
+    for the described chip at the shapes that run gave it."""
+    args, _kwargs = compiled.args_info
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    return specs, jax.jit(fn).lower(*specs).compile()
+
+
+@pytest.fixture(scope="module")
+def q3_steps():
+    """name -> [(step function, its CPU compile)] of the fused programs a
+    Q3 statement traces under the policies a TPU resolves."""
+    from matrixone_tpu.frontend.session import Session
+    from matrixone_tpu.utils import tpch
+    from matrixone_tpu.vm import fusion as FF
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(HK, "platform", lambda: "tpu")
+        mp.setenv("MO_PLAN_FUSION", "1")
+        mp.setenv("MO_FUSION_MIN_ROWS", "0")
+        s = Session()
+        try:
+            s.execute("set batch_rows = 8192")
+            tpch.load_lineitem(s.catalog, 20_000, seed=2)
+            tpch.load_tpch_q3(s.catalog, 4_000, seed=2)
+            FF.CACHE.clear()    # so that this statement traces its own
+            assert len(s.execute(tpch.Q3_SQL).rows()) == 10
+        finally:
+            s.close()
+    steps = {}
+    for e in FF.CACHE._lru.snapshot():
+        for slot, compiled in e["compiled"].items():
+            steps.setdefault(e["fn"][slot].__name__, []).append(
+                (e["fn"][slot], compiled))
+    return steps
+
+
+@pytest.mark.parametrize("program", ["frag_join_build",
+                                     "frag_join_stream_step"])
+def test_q3_fused_join_programs_hold_no_custom_call(one_chip, q3_steps,
+                                                    program):
+    """Q3's fused build and probe steps (the probe enters the sorted
+    hash run through `jnp.searchsorted`), lowered for the described
+    v5e: they compile, and no hand-written kernel is in them."""
+    assert q3_steps.get(program), f"Q3 traced no {program}"
+    for fn, compiled in q3_steps[program]:
+        _specs, lowered = _lowered_for(one_chip, fn, compiled)
+        assert "tpu_custom_call" not in lowered.as_text()
 
 
 def test_fused_q1_step_compiles_for_v5e(one_chip, monkeypatch):
     """One fused TPC-H Q1 step (scan batch of 2^20 rows, DECIMAL money
     columns: filter + dense group-by + int64 sums).  The test stands in
     for the platform, so the CPU run of the statement traces the step
-    under the policies a TPU resolves (narrow encodings, hand kernels,
-    carry donation); that step is then lowered for the described chip."""
+    under the policies a TPU resolves (narrow encodings, carry
+    donation); that step is then lowered for the described chip."""
     from matrixone_tpu.frontend.session import Session
     from matrixone_tpu.ops import encodings as ENC
     from matrixone_tpu.utils import tpch_full as T
     from matrixone_tpu.vm import fusion as FF
     monkeypatch.setattr(HK, "platform", lambda: "tpu")
-    for knob in ("MO_HAND_KERNELS", "MO_NARROW_ENCODINGS"):
-        monkeypatch.delenv(knob, raising=False)
-    assert HK.enabled() and ENC.enabled()
+    monkeypatch.delenv("MO_NARROW_ENCODINGS", raising=False)
+    assert ENC.enabled()
     s = Session()
     T.load_tpch(s.catalog, tables={
         "lineitem": T.gen_tpch(0.18, seed=1)["lineitem"]})
@@ -137,22 +144,21 @@ def test_fused_q1_step_compiles_for_v5e(one_chip, monkeypatch):
     assert steps, "Q1 did not run fused"
     rows = 0
     for fn, compiled in steps:
-        args, _kwargs = compiled.args_info
-        specs = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                           sharding=one_chip), args)
+        specs, lowered = _lowered_for(one_chip, fn, compiled)
         rows = max([rows] + [a.shape[0] for a in jax.tree.leaves(specs)
                              if a.shape])
-        assert jax.jit(fn).lower(*specs).compile().memory_analysis()
+        assert lowered.memory_analysis()
     assert rows == 1 << 20, f"largest step input has {rows} rows"
 
 
-def test_ivf_search_with_exact_rerank_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("batch", [1, 128])
+def test_ivf_search_with_exact_rerank_compiles_for_v5e(one_chip, batch):
     """The program a vector top-k statement waits for: the IVF-Flat
-    search of one query with the exact float32 re-rank of its 60
-    candidates, at the benchmark's shapes (1M x 768 float32 residuals,
-    1,024 lists of at most 4,096 rows, nprobe 8).  It has to fit beside
-    the 3.1 GB index."""
+    search (centroid probe, bfloat16 list scan) with the exact float32
+    re-rank of its 60 candidates, at the benchmark's shapes (1M x 768
+    float32 residuals, 1,024 lists of at most 4,096 rows, nprobe 8), for
+    one query a statement and for a 128-query batch in chunks of 32.  It
+    has to fit beside the 3.1 GB index."""
     from matrixone_tpu.vectorindex import ivf_flat
     spec = _spec(one_chip)
     n, d, lists, pad = 1_000_000, 768, 1024, 4096
@@ -163,8 +169,60 @@ def test_ivf_search_with_exact_rerank_compiles_for_v5e(one_chip):
         ids=spec((n,), jnp.int32), offsets=spec((lists + 1,), jnp.int32),
         metric=ivf_flat.METRIC_L2, max_cluster_size=pad, n=n)
     compiled = ivf_flat._search.lower(
-        index, spec((1, d), jnp.float32), k=60, nprobe=8, query_chunk=1,
-        compute_dtype=jnp.bfloat16, use_pallas=False, exact=True).compile()
+        index, spec((batch, d), jnp.float32), k=60, nprobe=8,
+        query_chunk=min(batch, 32), compute_dtype=jnp.bfloat16,
+        exact=True).compile()
     mem = compiled.memory_analysis()
-    assert mem.temp_size_in_bytes < 1 << 30, mem.temp_size_in_bytes
+    assert mem.temp_size_in_bytes < (1 << 30) * min(batch, 32), \
+        mem.temp_size_in_bytes
     assert "ivf_rerank_exact" in compiled.as_text()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def test_ivf_pq_search_compiles_for_v5e(one_chip, monkeypatch):
+    """IVF-PQ over 1M x 768 as SQL builds it: 96 subspaces of 8
+    dimensions (`indexing._pick_subspaces(768)`), 1,024 lists padded to
+    1,152 rows, nprobe 8, one query: the lookup tables and the ADC
+    scores of the candidates' code bytes, which on a TPU (the test
+    stands in for the platform) are the kernel's."""
+    from matrixone_tpu.indexing import _pick_subspaces
+    from matrixone_tpu.vectorindex import ivf_pq
+    monkeypatch.setattr(HK, "platform", lambda: "tpu")
+    spec = _spec(one_chip)
+    n, d, lists, pad = 1_000_000, 768, 1024, 1152
+    m = _pick_subspaces(d)
+    assert m == 96
+    index = ivf_pq.IvfPqIndex(
+        centroids=spec((lists, d), jnp.float32),
+        codebooks=spec((m, 256, d // m), jnp.float32),
+        codes=spec((n, m), jnp.uint8), ids=spec((n,), jnp.int32),
+        offsets=spec((lists + 1,), jnp.int32),
+        metric=ivf_pq.METRIC_L2, max_cluster_size=pad, n=n)
+    compiled = ivf_pq._search.lower(
+        index, spec((1, d), jnp.float32), k=60, nprobe=8,
+        query_chunk=1).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_unfused_float32_grouped_step_compiles_for_v5e(one_chip):
+    """`AggOp`'s per-batch partial for `sum(<FLOAT column>)` over a 2^20
+    row batch in 4,096 groups: a float32 scatter-add and a count."""
+    from types import SimpleNamespace
+
+    from matrixone_tpu.container import dtypes as dt
+    from matrixone_tpu.container.device import DeviceColumn
+    from matrixone_tpu.sql.expr import AggCall, BoundCol
+    from matrixone_tpu.vm.operators import _grouped_step
+    spec = _spec(one_chip)
+    call = AggCall("sum", BoundCol("x", dt.FLOAT32), False, dt.FLOAT32)
+
+    def step(gids, data, validity, row_mask):
+        return _grouped_step(call, SimpleNamespace(gids=gids),
+                             DeviceColumn(data, validity, dt.FLOAT32),
+                             row_mask, 4096)
+    rows = 1 << 20
+    text = _compiled_text(step, spec((rows,), jnp.int32),
+                          spec((rows,), jnp.float32),
+                          spec((rows,), jnp.bool_), spec((rows,), jnp.bool_))
+    assert "tpu_custom_call" not in text

@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from matrixone_tpu.ops import distance as D
 
@@ -68,15 +69,19 @@ def test_hash_determinism_and_spread(rng):
     assert counts.min() > 400
 
 
-def test_pallas_l2_matches_xla(rng):
-    from matrixone_tpu.ops import pallas_kernels as PK
-    x = rng.standard_normal((2048, 128)).astype(np.float32)
-    q = rng.standard_normal((16, 128)).astype(np.float32)
-    got = np.asarray(PK.l2_distance_sq_pallas(jnp.asarray(x), jnp.asarray(q),
-                                              tile_m=512, interpret=True))
-    ref = np.asarray(D.l2_distance_sq(jnp.asarray(x), jnp.asarray(q)))
-    np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-4)
-    # clamped non-negative even for self-pairs
-    got2 = np.asarray(PK.l2_distance_sq_pallas(jnp.asarray(x), jnp.asarray(x[:16]),
-                                               tile_m=512, interpret=True))
-    assert (got2 >= 0).all()
+@pytest.mark.parametrize("n,d,b", [(2048, 64, 16), (2048, 128, 16),
+                                   (1024, 16, 4)])
+def test_l2_distance_sq_matches_float64_numpy(n, d, b):
+    """The shapes the deleted Pallas l2 kernel was held to, for the XLA
+    formulation that stays: float64 numpy is the reference, and
+    self-pairs clamp at zero."""
+    rng = np.random.default_rng(n + d + b)
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    q = np.concatenate([rng.standard_normal((b - 1, d)).astype(np.float32),
+                        x[:1]])
+    got = np.asarray(D.l2_distance_sq(jnp.asarray(x), jnp.asarray(q)))
+    want = ((x.astype(np.float64)[:, None, :]
+             - q.astype(np.float64)[None, :, :]) ** 2).sum(-1)
+    assert got.dtype == np.float32 and got.shape == (n, b)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    assert (got >= 0).all() and got[0, b - 1] <= 1e-4

@@ -9,6 +9,7 @@ per build finalize, not one per batch)."""
 import datetime
 import os
 
+import numpy as np
 import pytest
 
 from matrixone_tpu.frontend import Session
@@ -351,3 +352,62 @@ def test_build_livesync_one_sync_per_finalize():
     got, overflowed, spans = _livesync_spans(
         [_mask_batch(64, 64) for _ in range(30)], 1000)
     assert overflowed and len(spans) == 1
+
+
+def _hashes(rng, n):
+    return rng.integers(0, 1 << 63, size=n, dtype=np.uint64)
+
+
+def _dup_run(rng):
+    run = np.sort(np.concatenate([                  # a run over 1,024
+        np.full(900, _hashes(rng, 1)[0]), _hashes(rng, 1500),
+        np.full(4, np.uint64(0xFFFFFFFFFFFFFFFF))]))   # NULL-hash sentinel
+    return run, np.concatenate([
+        rng.choice(run, 700), _hashes(rng, 300),
+        np.array([0, 1, 0xFFFFFFFFFFFFFFFF], np.uint64)])
+
+
+def _straddle(_rng):
+    s = np.array([(1 << 63) - 2, (1 << 63) - 1, 1 << 63, (1 << 63) + 1],
+                 dtype=np.uint64)
+    return s, np.concatenate([s, s + np.uint64(1), np.zeros(1, np.uint64)])
+
+
+def _present(rng):
+    srt = np.sort(_hashes(rng, 1100))
+    return srt, srt[::3]
+
+
+# name -> rng -> (sorted build hashes, probe hashes)
+_PROBE_ENTRY_CASES = {
+    "empty_build": lambda r: (_hashes(r, 0), _hashes(r, 7)),
+    "all_equal_build": lambda r: (
+        np.full(2500, 77, np.uint64),
+        np.array([0, 76, 77, 78], np.uint64)),
+    "under_min_and_over_max": lambda r: (
+        np.sort(_hashes(r, 1100) | np.uint64(1 << 20)) >> np.uint64(1),
+        np.array([0, 1, (1 << 64) - 1, (1 << 64) - 2], np.uint64)),
+    "either_side_of_2_63": _straddle,
+    "lengths_off_1024": lambda r: (np.sort(_hashes(r, 2500)),
+                                   _hashes(r, 1003)),
+    "duplicate_run_straddles_1024": _dup_run,
+    "present_keys": _present,
+    "int32_at_sf1_orders_x_2_20": lambda r: (
+        np.sort(_hashes(r, 1_500_000)), _hashes(r, 1 << 20)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PROBE_ENTRY_CASES))
+def test_probe_entry_is_searchsorted_left(case):
+    """What `expand_probe` relies on for its entry into the sorted hash
+    run: `jnp.searchsorted(..).astype(int32)` over uint64 hashes is
+    numpy's searchsorted-left (the inputs the deleted Pallas sorted
+    search was held to)."""
+    import jax
+    import jax.numpy as jnp
+    srt, q = _PROBE_ENTRY_CASES[case](np.random.default_rng(31))
+    got = jax.jit(lambda s, p: jnp.searchsorted(s, p).astype(jnp.int32))(
+        jnp.asarray(srt), jnp.asarray(q))
+    assert got.dtype == jnp.int32
+    want = np.searchsorted(srt, q, side="left")
+    assert np.array_equal(np.asarray(got).astype(np.int64), want)

@@ -1,101 +1,28 @@
-"""Hand-kernel dispatch seam (ops/kernels.py) + narrow encodings
+"""The kernel choice (ops/kernels.py) + narrow encodings
 (ops/encodings.py).
 
-The seam's contract is routing, not math: `sorted_lookup` must be
-bit-identical to `jnp.searchsorted(side='left')` whichever way it
-routes (tier-1 runs the Pallas kernel in interpret mode on cpu), the
-grouped scatter must keep every exact dtype on the XLA path, and the
-kill switch must actually switch.  The encodings policy must narrow
-dict codes losslessly, narrow ONLY f32 lanes to bf16, and surface the
-resolved policy in signatures the compile keys carry.
+`ops/kernels.py` chooses between XLA and a Pallas kernel from the
+platform and the shape; a test stands in for the platform to see what
+the choice is.  The
+encodings policy must narrow dict codes losslessly, narrow ONLY f32 lanes
+to bf16, and surface the resolved policy in signatures the compile keys
+carry.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from matrixone_tpu.ops import encodings as ENC
 from matrixone_tpu.ops import kernels as HK
-from matrixone_tpu.ops import pallas_kernels as PK
 
 
-def _hashes(seed: int, n: int) -> np.ndarray:
-    rng = np.random.default_rng(seed)
-    a = rng.integers(0, 1 << 63, size=n, dtype=np.uint64)
-    a[: n // 5] = a[0]                          # duplicate run
-    a[-4:] = np.uint64(0xFFFFFFFFFFFFFFFF)      # NULL-hash sentinel
-    return np.sort(a)
-
-
-def test_sorted_search_pallas_bit_identical():
-    srt = _hashes(11, 2500)                     # not tile-aligned
-    rng = np.random.default_rng(12)
-    q = np.concatenate([
-        rng.choice(srt, size=700),
-        rng.integers(0, 1 << 64, size=300, dtype=np.uint64),
-        np.array([0, 1, (1 << 64) - 1], dtype=np.uint64),
-    ])
-    got = np.asarray(PK.sorted_search_pallas(
-        jnp.asarray(srt), jnp.asarray(q), interpret=True))
-    want = np.asarray(jnp.searchsorted(jnp.asarray(srt),
-                                       jnp.asarray(q)))
-    assert np.array_equal(got.astype(np.int64), want.astype(np.int64))
-
-
-def test_sorted_lookup_routes_and_agrees(monkeypatch):
-    srt = jnp.asarray(_hashes(13, 1100))
-    q = jnp.asarray(_hashes(14, 900))
-    monkeypatch.setenv("MO_HAND_KERNELS", "0")
-    off = np.asarray(HK.sorted_lookup(srt, q))
-    monkeypatch.setenv("MO_HAND_KERNELS", "1")
-    on = np.asarray(HK.sorted_lookup(srt, q))   # interpret mode on cpu
-    assert np.array_equal(off.astype(np.int64), on.astype(np.int64))
-
-
-def test_kill_switch_and_signature(monkeypatch):
-    monkeypatch.setenv("MO_HAND_KERNELS", "0")
-    assert not HK.enabled()
-    assert HK.signature() == ("hand_kernels", False)
-    monkeypatch.setenv("MO_HAND_KERNELS", "1")
-    assert HK.enabled()
-    assert HK.signature() == ("hand_kernels", True)
-    monkeypatch.delenv("MO_HAND_KERNELS", raising=False)
-    # auto = backend routing: off on the cpu test mesh
-    assert HK.enabled() == (jax.default_backend() == "tpu")
-
-
-@pytest.mark.parametrize("n", [4096, 4000, 1])   # aligned, padded, tiny
-def test_grouped_scatter_pallas_matches_xla(n):
-    rng = np.random.default_rng(21)
-    v = rng.integers(0, 16, size=n).astype(np.float32)  # exact in f32
-    g = rng.integers(0, 19, size=n).astype(np.int32)
-    m = rng.random(n) < 0.8
-    got = np.asarray(HK.grouped_scatter_add(
-        jnp.asarray(v), jnp.asarray(g), jnp.asarray(m), 19,
-        use_pallas=True))
-    want = np.asarray(jax.ops.segment_sum(
-        jnp.where(jnp.asarray(m), jnp.asarray(v), 0.0),
-        jnp.asarray(g), num_segments=19))
-    assert np.array_equal(got, want)
-
-
-def test_grouped_scatter_exact_dtypes_stay_on_xla():
-    """int64 (counts / scaled decimals) and f64 sums must never route
-    to the f32 one-hot kernel — exactness is the contract."""
-    v = jnp.asarray(np.array([1 << 40, 3, -7, 1 << 40], dtype=np.int64))
-    g = jnp.asarray(np.array([0, 0, 1, 1], dtype=np.int32))
-    m = jnp.asarray(np.array([True, True, True, False]))
-    got = np.asarray(HK.grouped_scatter_add(v, g, m, 2,
-                                            use_pallas=True))
-    assert got.dtype == np.int64
-    assert got.tolist() == [(1 << 40) + 3, -7]
-    v64 = jnp.asarray(np.array([1e-17, 1.0, 1e-17], dtype=np.float64))
-    got64 = np.asarray(HK.grouped_scatter_add(
-        v64, jnp.asarray(np.zeros(3, np.int32)),
-        jnp.asarray(np.ones(3, bool)), 1, use_pallas=True))
-    assert got64.dtype == np.float64
-    assert got64[0] == np.float64(1e-17) + 1.0 + 1e-17
+@pytest.mark.parametrize("platform,candidates,kernel", [
+    ("tpu", 1152, True), ("tpu", 3968, True), ("tpu", 1000, False),
+    ("cpu", 1152, False)])
+def test_adc_choice(monkeypatch, platform, candidates, kernel):
+    monkeypatch.setattr(HK, "platform", lambda: platform)
+    assert HK.adc_kernel_chosen(candidates) is kernel
 
 
 def test_narrow_codes_lossless_and_width(monkeypatch):
@@ -141,30 +68,26 @@ def test_narrow_lane_f32_only(monkeypatch):
 
 def test_policies_ride_the_fused_compile_key(monkeypatch):
     """A flipped policy must RE-TRACE, not collide: the fragment audit
-    deps carry both signatures, so mokey's runtime auditor and the
+    deps carry the signature, so mokey's runtime auditor and the
     compile key see every flip."""
     from matrixone_tpu.vm import fusion as FF
     monkeypatch.setenv("MO_NARROW_ENCODINGS", "0")
-    monkeypatch.setenv("MO_HAND_KERNELS", "0")
-    key_off = (FF.ENC.signature(), FF.HK.signature())
+    key_off = FF.ENC.signature()
     monkeypatch.setenv("MO_NARROW_ENCODINGS", "1")
-    monkeypatch.setenv("MO_HAND_KERNELS", "1")
-    key_on = (FF.ENC.signature(), FF.HK.signature())
-    assert key_off != key_on
-    assert key_off == (("narrow", False), ("hand_kernels", False))
-    assert key_on == (("narrow", True), ("hand_kernels", True))
-    # and the fragment key/audit sites actually append them
+    key_on = FF.ENC.signature()
+    assert key_off == ("narrow", False)
+    assert key_on == ("narrow", True)
+    # and the fragment key/audit sites actually append it
     import inspect
     src = inspect.getsource(FF.FusedFragmentOp._runtime_key)
-    assert "ENC.signature()" in src and "HK.signature()" in src
+    assert "ENC.signature()" in src
     assert "encoding_policy" in inspect.getsource(
         FF.FusedFragmentOp._audit_deps)
 
 
 def test_hand_kernels_end_to_end_sql_lockstep(monkeypatch):
     """Whole-path lockstep on the cpu mesh: the same join+group query
-    answers identically with the seam forced on (interpret-mode Pallas
-    probe + scatter, narrow codes) and forced off."""
+    answers identically with narrow dict codes forced on and off."""
     from matrixone_tpu.frontend import Session
     from matrixone_tpu.storage.engine import Engine
 
@@ -187,9 +110,7 @@ def test_hand_kernels_end_to_end_sql_lockstep(monkeypatch):
 
     monkeypatch.setenv("MO_PLAN_FUSION", "1")
     monkeypatch.setenv("MO_FUSION_MIN_ROWS", "0")
-    monkeypatch.setenv("MO_HAND_KERNELS", "0")
     monkeypatch.setenv("MO_NARROW_ENCODINGS", "0")
     base = run()
-    monkeypatch.setenv("MO_HAND_KERNELS", "1")
     monkeypatch.setenv("MO_NARROW_ENCODINGS", "1")
     assert run() == base

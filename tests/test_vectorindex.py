@@ -2,6 +2,7 @@
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from matrixone_tpu.vectorindex import brute_force, ivf_flat, kmeans
 from matrixone_tpu.vectorindex.recall import recall_at_k
@@ -268,3 +269,41 @@ def test_hnsw_native_walker_matches_python_oracle():
     r_py = recall_at_k(ids_p, truth)
     assert r_nat >= 0.9, r_nat
     assert r_nat >= r_py - 0.05, (r_nat, r_py)
+
+
+@pytest.mark.parametrize("codes_dtype", ["uint8", "int32"])
+def test_ivf_pq_adc_scores_match_numpy_lut_sum(codes_dtype):
+    """The distances `ivf_pq.search` returns are the ADC scores of the
+    ids it returns: sum over subspaces of the lookup-table entry the
+    row's code byte selects, recomputed here in float64 numpy."""
+    import dataclasses
+
+    from matrixone_tpu.vectorindex import ivf_pq
+    rng = np.random.default_rng(8)
+    x = _clustered_data(rng, n=4000, d=32)
+    q = x[rng.integers(0, len(x), 6)] + np.float32(0.01)
+    index = ivf_pq.build(jnp.asarray(x), nlist=8, n_subspaces=8, n_iter=4,
+                         pq_iter=4, kmeans_sample=None, compute_dtype=None)
+    index = dataclasses.replace(
+        index, codes=index.codes.astype(codes_dtype))
+    nprobe = 3
+    dist, ids = ivf_pq.search(index, jnp.asarray(q), k=5, nprobe=nprobe)
+    dist, ids = np.asarray(dist), np.asarray(ids)
+    cent = np.asarray(index.centroids, np.float64)
+    books = np.asarray(index.codebooks, np.float64)       # [M, 256, ds]
+    codes = np.asarray(index.codes).astype(np.int64)
+    offsets = np.asarray(index.offsets)
+    pos_of = np.empty(len(x), np.int64)
+    pos_of[np.asarray(index.ids)] = np.arange(len(x))
+    m_sub, _, ds = books.shape
+    for i, qi in enumerate(q.astype(np.float64)):
+        probes = np.argsort(((cent - qi) ** 2).sum(-1))[:nprobe]
+        for j, row in enumerate(ids[i]):
+            pos = pos_of[row]
+            lst = np.searchsorted(offsets, pos, side="right") - 1
+            assert lst in probes
+            resid = (qi - cent[lst]).reshape(m_sub, ds)
+            lut = ((resid[:, None, :] - books) ** 2).sum(-1)   # [M, 256]
+            want = lut[np.arange(m_sub), codes[pos]].sum()
+            np.testing.assert_allclose(dist[i, j], want, rtol=1e-4,
+                                       atol=1e-4)

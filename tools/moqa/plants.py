@@ -75,7 +75,7 @@ def plant_pad_leak():
     orig_seg_sum = A.seg_sum
     orig_scalar_sum = A.scalar_sum
 
-    def leaky_seg_sum(values, gids, mask, max_groups, use_pallas=False):
+    def leaky_seg_sum(values, gids, mask, max_groups):
         # THE PLANT: mask dropped — padding rows contribute their raw
         # buffer contents to whatever group their garbage gid lands in
         return jax.ops.segment_sum(values, gids,

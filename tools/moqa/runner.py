@@ -16,10 +16,8 @@ diffs the row-sets exactly:
   udf-tier        MO_UDF_JIT=0 row loop vs jit tier
   canary          padding canary armed (utils/qa.py poisons padded
                   tails) vs disarmed — plus the canary audits; the
-                  armed run also forces MO_HAND_KERNELS=1 and
-                  MO_NARROW_ENCODINGS=1 so the poisoned tails sweep
-                  the Pallas sorted-search/group-scatter kernels and
-                  the narrow dict-code path, not just the XLA ops
+                  armed run also forces MO_NARROW_ENCODINGS=1 so the
+                  poisoned tails sweep the narrow dict-code path too
   narrow-encodings  MO_NARROW_ENCODINGS=1 fused path (int8/int16 dict
                   codes, bf16 float lanes) vs the wide baseline, swept
                   over GROUPED queries (the only shape where the
@@ -55,7 +53,7 @@ from tools.moqa import oracles as ORC
 #: path, jit UDF tier, no fusion
 ENV_BASELINE = {"MO_PLAN_FUSION": "0", "MO_DENSE_GROUPS": None,
                 "MO_FUSION_MIN_ROWS": None, "MO_UDF_JIT": None,
-                "MO_NARROW_ENCODINGS": None, "MO_HAND_KERNELS": None}
+                "MO_NARROW_ENCODINGS": None}
 
 #: per-pair env overrides (applied on top of the baseline)
 PAIR_ENV = {
@@ -64,12 +62,9 @@ PAIR_ENV = {
     "plan-cache": {},
     "result-cache": {},
     "udf-tier": {"MO_UDF_JIT": "0"},
-    # the armed replay also routes through the hand kernels (interpret
-    # mode off-TPU) and the narrow dict codes: the padding canary is
-    # exactly the instrument that catches a Pallas tile reading its
-    # padded tail
+    # the armed replay also runs the narrow dict codes
     "canary": {"MO_PLAN_FUSION": "1", "MO_FUSION_MIN_ROWS": "0",
-               "MO_HAND_KERNELS": "1", "MO_NARROW_ENCODINGS": "1"},
+               "MO_NARROW_ENCODINGS": "1"},
     "narrow-encodings": {"MO_NARROW_ENCODINGS": "1",
                          "MO_PLAN_FUSION": "1",
                          "MO_FUSION_MIN_ROWS": "0"},

@@ -213,28 +213,6 @@ def _key_leg():
     return run
 
 
-def _kernel_leg():
-    def run(print):
-        from tools import kernel_smoke
-        rep = kernel_smoke.run_smoke()
-        rc = 0
-        for e in rep["errors"]:
-            print(f"kernel-smoke: {e}")
-            rc = 1
-        if not rep["errors"]:
-            print(f"kernel-smoke: Pallas==XLA bit-identity ok "
-                  f"({rep['checks']} checks, {rep['seconds']}s)")
-        if rep["plant_caught"]:
-            print("kernel-smoke: planted side='right' mismatch "
-                  "caught ok")
-        else:
-            print("kernel-smoke: planted side='right' mismatch "
-                  "NOT caught")
-            rc = 1
-        return rc
-    return run
-
-
 def _crash_leg():
     def run(print):
         from tools import mocrash
@@ -287,11 +265,6 @@ def main(argv=None) -> int:
                     help="also run the mokey planted fixture pairs: "
                          "static pass over a planted temp tree + one "
                          "armed runtime audit round-trip (<30s)")
-    ap.add_argument("--kernel-smoke", action="store_true",
-                    help="also run the hand-kernel bit-identity drill: "
-                         "interpret-mode Pallas (sorted search + "
-                         "grouped scatter) vs the XLA fallback, exact "
-                         "compare + kill-switch routing (<30s)")
     ap.add_argument("--crash-smoke", action="store_true",
                     help="also run the mocrash crash-recovery smoke: "
                          "a capped clean sweep over every durability "
@@ -314,8 +287,6 @@ def main(argv=None) -> int:
         legs.append(("trace-smoke", _trace_leg(), True))
     if args.key_smoke:
         legs.append(("key-smoke", _key_leg(), True))
-    if args.kernel_smoke:
-        legs.append(("kernel-smoke", _kernel_leg(), True))
     if args.crash_smoke:
         legs.append(("crash-smoke", _crash_leg(), True))
 
