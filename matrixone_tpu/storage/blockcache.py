@@ -25,6 +25,16 @@ tiered caches, zonemap-pruned before the fetch
     eviction is always safe — the next access re-fetches (device-tier
     eviction re-uploads from the host tier; host-tier eviction
     re-decodes).
+  * `_ObjectSource` — the loader that one object's `arrays` and
+    `validity` views share.  Besides the decode it holds the one thing
+    about the object that no tier holds: `chunk_summaries`, per (column,
+    start, end) the count of valid rows and their min and max, which the
+    scan's chunk-level zonemap check fills on first use and reads ever
+    after (`engine._chunk_summaries`).  An object is immutable, so an
+    entry is never wrong; it is a few dozen bytes a chunk, is charged to
+    no tier and turned out by none, and dies with the segment: a merge's
+    new object, and another engine's object at the same path, have
+    loaders and summaries of their own.
 
 A `Segment` whose arrays/validity are `LazyColumns` behaves identically
 to a RAM segment everywhere (iter_chunks, fetch_rows, merges, index
@@ -402,6 +412,10 @@ class _ObjectSource:
         self.fs = fs
         self.path = path
         self.columns = columns
+        #: (column, start, end) -> (n_valid, min, max) of those rows of
+        #: the immutable object: the scan's chunk zonemaps, written once
+        #: each by `dict.setdefault` (atomic: scan threads need no lock)
+        self.chunk_summaries: Dict[tuple, tuple] = {}
         self._tok = _fs_token(fs)
         self._load_lock = san.lock("_ObjectSource._load_lock")
         self._raw = None          # parsed object header, fetched once
@@ -507,6 +521,11 @@ class LazyColumns(Mapping):
         fresh decode, a device array only where the device tier alone
         holds it.  No upload."""
         return self._source.host_pair(col)
+
+    @property
+    def chunk_summaries(self) -> Dict[tuple, tuple]:
+        """The object's kept chunk summaries (`_ObjectSource`)."""
+        return self._source.chunk_summaries
 
     def __iter__(self) -> Iterator[str]:
         return iter(self._source.columns)

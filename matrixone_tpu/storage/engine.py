@@ -22,6 +22,7 @@ committer-wins on row deletes), HLC commit ts, WAL append, apply, notify.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import json
 import os
@@ -30,6 +31,8 @@ import threading
 from matrixone_tpu.utils import san
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from matrixone_tpu.container import dtypes as dt
@@ -529,12 +532,15 @@ class MVCCTable:
     def _read_chunk(self, seg, start: int, end: int, data_cols,
                     want_rowid: bool, dead_filter, filters, qmap):
         """One chunk of one segment: column lookups (which fetch, decode
-        and upload what the block cache misses), slices, the tombstone
-        mask and the chunk's own zonemap check.  -> (arrays, validity,
-        dicts, n), or None when the chunk has nothing to scan."""
+        and upload what the block cache misses), the slice (`_chunk_columns`:
+        one program for all the device-resident columns of the chunk, a
+        view of a numpy one), the tombstone mask and the chunk's own
+        zonemap check.  An object-backed segment's check reads the chunk
+        as it was sliced, before the tombstone mask: its summary is kept
+        with the object and must not depend on the snapshot (a range over
+        more rows than are visible can only prune less).  -> (arrays,
+        validity, dicts, n), or None when the chunk has nothing to scan."""
         from matrixone_tpu.utils import metrics as M, motrace
-        gids = np.arange(seg.base_gid + start, seg.base_gid + end,
-                         dtype=np.int64)
         keep = None
         if dead_filter is not None:
             keep = ~dead_filter.test_range(seg.base_gid + start,
@@ -542,16 +548,23 @@ class MVCCTable:
             if not keep.any():
                 M.scan_chunks.inc(outcome="all_dead")
                 return None
-        arrays, validity = {}, {}
-        for c in data_cols:
-            a = seg.arrays[c][start:end]
-            v = seg.validity[c][start:end]
-            if keep is not None and not keep.all():
-                a, v = a[keep], v[keep]
-            arrays[c] = a
-            validity[c] = v
+            if keep.all():
+                keep = None
+        arrays, validity = sliced = _chunk_columns(seg, start, end,
+                                                   data_cols)
+        if keep is not None:
+            # a table thinned by tombstones: an eager gather a column
+            gathers = sum(isinstance(a, jax.Array)
+                          for d in sliced for a in d.values())
+            if gathers:
+                M.scan_slice_dispatch.inc(gathers, how="column")
+            arrays = {c: a[keep] for c, a in arrays.items()}
+            validity = {c: v[keep] for c, v in validity.items()}
         if want_rowid:
-            g = gids if keep is None or keep.all() else gids[keep]
+            g = np.arange(seg.base_gid + start, seg.base_gid + end,
+                          dtype=np.int64)
+            if keep is not None:
+                g = g[keep]
             arrays[ROWID] = g
             validity[ROWID] = np.ones(len(g), np.bool_)
         n = len(next(iter(arrays.values()))) if arrays else 0
@@ -559,8 +572,12 @@ class MVCCTable:
             return None
         if filters:
             with motrace.span("scan.zonemap"):
-                pruned = _zonemap_excludes(filters, arrays, validity,
-                                           qmap, dict(self.meta.schema))
+                kept = seg.arrays.chunk_summaries if seg.is_lazy else None
+                pruned = _zonemap_excludes(
+                    filters, *(sliced if kept is not None
+                               else (arrays, validity)),
+                    qmap, dict(self.meta.schema), kept=kept,
+                    rows=(start, end))
                 motrace.annotate(pruned=pruned)
             if pruned:
                 M.scan_chunks.inc(outcome="pruned_chunk")
@@ -801,32 +818,133 @@ def _zm_range_excludes(op, lo, hi, lv) -> bool:
     return not (lo <= lv <= hi)   # eq
 
 
-def _zonemap_excludes(filters, arrays, validity, qmap, schema) -> bool:
-    """Chunk-level prune on the chunk's own values.  Columns of an
-    object-backed segment are device arrays (the block cache's device
-    tier): each `.all()` and each min/max comparison is then a small
-    eager program and a wait for its answer, counted as such."""
+@functools.partial(jax.jit, static_argnames=("length",))
+def _slice_rows(cols, start, *, length: int):
+    """Rows [start, start + length) of every array of `cols` in one
+    program.  `start` is traced and `length` static, so that one program
+    serves every full chunk of a segment length and column set and a
+    second its ragged tail."""
+    return [jax.lax.dynamic_slice_in_dim(a, start, length) for a in cols]
+
+
+def _chunk_columns(seg, start: int, end: int, data_cols):
+    """-> (arrays, validity): rows [start, end) of `data_cols`, no pad.
+    The device-resident arrays among them (an object-backed segment served
+    by the block cache's device tier) are sliced by ONE dispatch, data
+    and validity of every column together, or taken as they are where the
+    chunk is its whole segment; a numpy array gives a view."""
     from matrixone_tpu.utils import metrics as M
-    for raw, op, col, lit in _zm_predicates(filters, qmap):
-        if raw not in arrays:
-            continue
-        v = validity[raw]
-        on_device = not isinstance(v, np.ndarray)
-        all_valid = bool(v.all())
-        if on_device:
-            M.device_wait.inc(site="zonemap")
-        vals = arrays[raw] if all_valid else arrays[raw][v]
-        if len(vals) == 0:
+    arrays, validity = {}, {}
+    for c in data_cols:
+        # data, then validity, column by column: the order of the block
+        # cache's lookups is the order of its LRU, and the tiers are in
+        # an equilibrium under it (PERF.md section 6, PR 32)
+        arrays[c] = seg.arrays[c]
+        validity[c] = seg.validity[c]
+    resident = [(d, c) for d in (arrays, validity) for c in data_cols
+                if isinstance(d[c], jax.Array)]
+    if resident and (start, end) != (0, seg.n_rows):
+        cut = _slice_rows(tuple(d[c] for d, c in resident),
+                          np.int32(start), length=end - start)
+        M.scan_slice_dispatch.inc(how="chunk")
+        for (d, c), a in zip(resident, cut):
+            d[c] = a
+    for d in (arrays, validity):
+        for c, a in d.items():
+            if not isinstance(a, jax.Array):
+                d[c] = a[start:end]
+    return arrays, validity
+
+
+@jax.jit
+def _summarize_on_device(datas, vals):
+    """(n_valid, min, max over the valid rows) of each column of a chunk,
+    in one program; min and max are None for a column that is not 1-d."""
+    from matrixone_tpu.ops.agg import _reduce_fill
+    out = []
+    for d, v in zip(datas, vals):
+        lo = hi = None
+        if d.ndim == 1:
+            lo = jnp.min(jnp.where(v, d, _reduce_fill(d.dtype, True)))
+            hi = jnp.max(jnp.where(v, d, _reduce_fill(d.dtype, False)))
+        out.append((jnp.sum(v), lo, hi))
+    return out
+
+
+def _summarize_on_host(data: np.ndarray, valid: np.ndarray):
+    n_valid = int(np.count_nonzero(valid))
+    if n_valid == 0 or data.ndim != 1:
+        return n_valid, None, None
+    vals = data if n_valid == len(data) else data[valid]
+    return n_valid, vals.min().item(), vals.max().item()
+
+
+def _chunk_summaries(cols, arrays, validity, kept, rows):
+    """-> {col: ((n_valid, min, max), source)} for a chunk's predicate
+    columns: what `_zonemap_excludes` needs of each, as Python scalars in
+    the column's stored units, and where it came from (the `source` of
+    `mo_scan_zonemap_checks_total`).
+
+    `kept` is the summary store of the chunk's immutable object
+    (`blockcache._ObjectSource.chunk_summaries`; None for a RAM segment)
+    and `rows` the chunk's (start, end) in it: an entry found there costs
+    a dictionary read.  The columns it lacks are summarized from the
+    arrays in hand, numpy ones on the host, device-resident ones together
+    in one program and one fetch, and stored.  Scan threads may fill one
+    key at once (the prefetch thread of a cold scan beside another
+    statement's own): they computed it from the same object, and
+    `setdefault` keeps one."""
+    from matrixone_tpu.utils import metrics as M
+    found, resident = {}, []
+    for c in cols:
+        got = None if kept is None else kept.get((c,) + rows)
+        if got is not None:
+            found[c] = (got, "memo")
+        elif isinstance(arrays[c], jax.Array):
+            resident.append(c)
+        else:
+            found[c] = (_summarize_on_host(arrays[c],
+                                           np.asarray(validity[c])), "host")
+    if resident:
+        fetched = jax.device_get(_summarize_on_device(
+            tuple(arrays[c] for c in resident),
+            tuple(validity[c] for c in resident)))
+        M.device_wait.inc(site="zonemap")
+        for c, (n_valid, lo, hi) in zip(resident, fetched):
+            found[c] = ((int(n_valid), None if lo is None else lo.item(),
+                         None if hi is None else hi.item()), "device")
+    if kept is not None:
+        for c, (summary, source) in found.items():
+            if source != "memo":
+                kept.setdefault((c,) + rows, summary)
+    return found
+
+
+def _zonemap_excludes(filters, arrays, validity, qmap, schema,
+                      kept=None, rows=None) -> bool:
+    """Chunk-level prune on the chunk's own values: a predicate excludes
+    the chunk where its column has no valid row, or where the range of
+    the valid rows cannot satisfy it.  For a chunk of an object-backed
+    segment (`kept`, `rows`: see `_chunk_summaries`) the numbers are
+    computed once, on first use, and kept with the immutable object:
+    every later statement checks host scalars, with no program and no
+    wait.  A RAM segment's (and an external table's) numpy chunk is
+    summarized on the host each time, uncached."""
+    from matrixone_tpu.utils import metrics as M
+    preds = [p for p in _zm_predicates(filters, qmap) if p[0] in arrays]
+    found = _chunk_summaries(dict.fromkeys(p[0] for p in preds),
+                             arrays, validity, kept, rows)
+    for raw, op, col, lit in preds:
+        (n_valid, lo, hi), source = found[raw]
+        M.scan_zonemap_checks.inc(source=source)
+        if n_valid == 0:
             return True
-        if vals.ndim != 1:
+        if lo is None:
             continue
         lv = _zm_normalize_lit(col, lit)
         if lv is None:
             continue
-        excluded = _zm_range_excludes(op, vals.min(), vals.max(), lv)
-        if on_device:
-            M.device_wait.inc(site="zonemap")
-        if excluded:
+        if _zm_range_excludes(op, lo, hi, lv):
             return True
     return False
 

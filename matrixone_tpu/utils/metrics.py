@@ -304,10 +304,26 @@ from_numpy_columns = REGISTRY.counter(
     "bucket on the device), host (a host array, padded and uploaded), "
     "roundtrip (a device array pulled to the host and uploaded again, "
     "e.g. for a dtype the column cannot keep: held to 0 on a scan)")
+scan_slice_dispatch = REGISTRY.counter(
+    "mo_scan_slice_dispatch_total",
+    "programs MVCCTable._read_chunk dispatched over a chunk's "
+    "device-resident columns, by how: chunk (the one program that slices "
+    "every data and validity array of the chunk), column (an eager "
+    "program a column: the tombstone gather of a chunk with dead rows; "
+    "no slice is made a column any more).  A chunk that is its whole "
+    "segment, and a numpy column, dispatch nothing")
+scan_zonemap_checks = REGISTRY.counter(
+    "mo_scan_zonemap_checks_total",
+    "zonemap predicates checked against a chunk's own min/max, once a "
+    "predicate a chunk, by source of the summary: memo (kept with the "
+    "immutable object from an earlier scan: no program, no wait), device "
+    "(filled now by one program and one fetch a chunk), host (numpy "
+    "columns, computed on the host)")
 device_wait = REGISTRY.counter(
     "mo_device_wait_total",
     "host reads of a device value that block the statement's path, by "
-    "site: zonemap (chunk min/max/all-valid), flags (fused all-valid "
+    "site: zonemap (a chunk's n_valid/min/max, once a chunk of an object "
+    "and then kept with it), flags (fused all-valid "
     "flags), limit (fused LIMIT rows seen), finalize (the fused carry "
     "handed to the result path, whose fetch is the statement's last "
     "wait), vector_search (the candidates of a vector index search and "

@@ -3,7 +3,8 @@ the Pallas kernel of `ops/pallas_kernels.py` and the programs the served
 path hands the chip, at the widths `chip_smoke.py` and the benchmark
 drive: one fused TPC-H Q1 step at the SF1 batch shape, Q3's fused join
 programs, the IVF-Flat and IVF-PQ searches over 1M x 768, the unfused
-float32 grouped step.  The chip's
+float32 grouped step, the scan's one-program chunk slice and chunk
+summary at an SF1 segment's shape.  The chip's
 compiler is installed in the sandbox; what it refuses here (int64 block
 indices, unaligned blocks, fast-memory overflow, a program that does not
 fit) it refuses on the chip, and interpret mode shows none of it.
@@ -226,3 +227,40 @@ def test_unfused_float32_grouped_step_compiles_for_v5e(one_chip):
                           spec((rows,), jnp.float32),
                           spec((rows,), jnp.bool_), spec((rows,), jnp.bool_))
     assert "tpu_custom_call" not in text
+
+
+#: one lineitem segment of TPC-H SF1 as the device tier holds it for Q1:
+#: four DECIMALs, two dictionary-code columns, a DATE, and their validity
+SF1_SEGMENT_ROWS = 1_500_304
+Q1_COLUMN_DTYPES = [jnp.int64] * 4 + [jnp.int32] * 3 + [jnp.bool_] * 7
+
+
+@pytest.mark.parametrize("length", [1 << 20, SF1_SEGMENT_ROWS - (1 << 20)],
+                         ids=["full_chunk", "ragged_tail"])
+def test_scan_chunk_slice_compiles_for_v5e(one_chip, length):
+    """`storage/engine._slice_rows`: the fourteen arrays of a Q1 chunk
+    cut out of their segment by one program, `start` traced."""
+    from matrixone_tpu.storage import engine as engmod
+    spec = _spec(one_chip)
+    cols = tuple(spec((SF1_SEGMENT_ROWS,), d) for d in Q1_COLUMN_DTYPES)
+    compiled = engmod._slice_rows.lower(
+        cols, spec((), jnp.int32), length=length).compile()
+    out = jax.tree.leaves(compiled.out_info)
+    assert [(o.shape, o.dtype) for o in out] \
+        == [((length,), jnp.dtype(d)) for d in Q1_COLUMN_DTYPES]
+    assert "dynamic-slice" in compiled.as_text()
+
+
+def test_scan_chunk_summary_compiles_for_v5e(one_chip):
+    """`storage/engine._summarize_on_device` over Q6's predicate columns
+    of a full chunk: a DATE and two DECIMALs, each (n_valid, min, max)."""
+    from matrixone_tpu.storage import engine as engmod
+    spec = _spec(one_chip)
+    dtypes = [jnp.int32, jnp.int64, jnp.int64]
+    compiled = engmod._summarize_on_device.lower(
+        tuple(spec((1 << 20,), d) for d in dtypes),
+        tuple(spec((1 << 20,), jnp.bool_) for _ in dtypes)).compile()
+    out = jax.tree.leaves(compiled.out_info)
+    assert [o.dtype for o in out] == [
+        jnp.dtype(d) for dt_ in dtypes for d in (jnp.int64, dt_, dt_)]
+    assert all(o.shape == () for o in out)
