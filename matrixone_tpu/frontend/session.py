@@ -1303,7 +1303,13 @@ class Session:
             node, self.catalog,
             nprobe=int(self.variables.get("ivf_nprobe", 8)),
             skip_tables=self._index_skip_tables())
-        return prune_columns(node)
+        node = prune_columns(node)
+        if self.txn is None:
+            # an open txn reads its own workspace, whose rows are held to
+            # the primary key only when it commits
+            from matrixone_tpu.sql.cbo import mark_unique_builds
+            node = mark_unique_builds(node, self.catalog)
+        return node
 
     def _select(self, sel: ast.Select, serving=None) -> Result:
         ctl = self._try_mo_ctl(sel)

@@ -43,6 +43,31 @@ def interpret() -> bool:
     return platform() != "tpu"
 
 
+#: widest key range a direct-address join table is made for: 2^22 int32
+#: slots are 16 MB of device memory.  A bound on memory, not a crossover:
+#: on the chip the table beat the one-lane sorted search at every size
+#: tried (SSB at SF1, five alternating pairs on the same constants: a
+#: statement 4.4-6.4x slower sorted, builds of 2,000 to 200,000 rows in
+#: tables of 2^12 to 2^18 slots; 18.7x, 5.70 s against 0.305 s, for a
+#: 1,500,000-row build in 2^21 slots probed by 6,000,000 rows;
+#: PERF.md section 6, PR 33)
+JOIN_DENSE_SPAN_MAX = 1 << 22
+
+
+def join_lookup(unique: bool, int_keys: int, span: int) -> str:
+    """How the fused join finds a probe key's build row: "dense", a
+    direct-address table indexed by key - min(key) (one gather a probe
+    row, no hash, no sort, nothing to verify), where the build was seen to
+    hold one row a key at most, on one integer key whose values span at
+    most `JOIN_DENSE_SPAN_MAX`: a dimension keyed 1..N, a date key; else
+    "sorted", the binary search into the sorted hashes.  All three are
+    what the build step observes or the plan declares; nothing names a
+    lookup from outside."""
+    if unique and int_keys == 1 and 0 < span <= JOIN_DENSE_SPAN_MAX:
+        return "dense"
+    return "sorted"
+
+
 def adc_kernel_chosen(candidates: int) -> bool:
     """IVF-PQ's ADC scores ride the one-hot matmul on a TPU where the
     candidate lists are padded to whole 128-lane tiles (`ivf_pq.build`

@@ -100,6 +100,11 @@ class Join(PlanNode):
     right_keys: List[BoundExpr]
     residual: Optional[BoundExpr]
     schema: Schema
+    # the build (right) side holds at most one row a join key: its keys
+    # cover a declared primary key that the subtree below keeps unique
+    # (sql/cbo.mark_unique_builds).  The probe then takes one lane a row
+    # and has no overflow to look for.
+    build_unique: bool = False
 
 
 @dataclasses.dataclass
@@ -268,7 +273,8 @@ def explain(node: PlanNode, indent: int = 0, annotate=None) -> str:
         extra = f" desc={node.descendings}" + (
             f" k={node.k}" if isinstance(node, TopK) else "")
     elif isinstance(node, Join):
-        extra = f" kind={node.kind}"
+        extra = f" kind={node.kind}" + (
+            " build=unique" if node.build_unique else "")
     elif isinstance(node, VectorTopK):
         extra = f" index={node.index_name} k={node.k} metric={node.metric}"
         if node.limit is not None:

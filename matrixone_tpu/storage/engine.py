@@ -193,6 +193,14 @@ class MVCCTable:
             if all(keyable(sd.get(c)) for c in meta.primary_key):
                 self._pk_cols = list(meta.primary_key)
 
+    @property
+    def enforced_key(self) -> List[str]:
+        """The declared primary key where `check_pk_unique` holds every
+        commit to it, else []: a key over a column that is neither
+        integer nor varlen (DATE, DECIMAL, TIMESTAMP) is declared and not
+        checked, so nothing may be derived from it."""
+        return [self._pk_col] if self._pk_col else list(self._pk_cols)
+
     def allocate_auto(self, n: int) -> np.ndarray:
         """Allocate n auto_increment values (reference: pkg/incrservice
         cached range allocator — single-process form). Serialized by the

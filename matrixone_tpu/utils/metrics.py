@@ -259,6 +259,20 @@ txn_commits = REGISTRY.counter(
     "mo_txn_commit_total", "transaction commits by outcome")
 join_spills = REGISTRY.counter(
     "mo_join_spill_total", "joins whose build side Grace-spilled to host")
+join_probe_rows = REGISTRY.counter(
+    "mo_join_probe_rows_total",
+    "rows of the fused join probe by stage: in (live probe rows offered) "
+    "and matched (lanes the probe emitted, after the residual)")
+join_probe_lanes = REGISTRY.counter(
+    "mo_join_probe_lanes_total",
+    "match lanes the fused probe expanded its live rows to (rows x lanes "
+    "a row; one a row against a build that is unique on the join keys)")
+join_probe_retries = REGISTRY.counter(
+    "mo_join_probe_retries_total",
+    "probe batches re-run with doubled lanes after a duplicate overflow")
+join_build_rows = REGISTRY.counter(
+    "mo_join_build_rows_total",
+    "live rows of the build sides the fused join finalized")
 blockcache_ops = REGISTRY.counter(
     "mo_blockcache_ops_total", "decoded-column cache lookups by outcome")
 blockcache_bytes = REGISTRY.counter(
@@ -328,7 +342,12 @@ device_wait = REGISTRY.counter(
     "handed to the result path, whose fetch is the statement's last "
     "wait), vector_search (the candidates of a vector index search and "
     "its exact re-rank), vector_delta (the exact scan of an index's "
-    "delta segment)")
+    "delta segment), join_rf (a fused build's scalars: runtime-filter "
+    "ranges, the key's range, live rows, column ranges; one a build), "
+    "join_flags (a fused probe's all-valid flags), join_overflow (a probe "
+    "batch's duplicate-overflow flag; never read against a unique build), "
+    "join_stats (a join's probe row counts, once after its last step), "
+    "agg_slots (which slots of the wide dense grouped aggregate hold rows)")
 vector_fetch_rows = REGISTRY.counter(
     "mo_vector_fetch_rows_total",
     "candidate rows a VectorTopK fetched from its table by row id")

@@ -191,113 +191,6 @@ where l_shipdate >= date '1994-01-01'
 """
 
 
-# --------------------------------------------------------------- SSB Q1.x
-
-LINEORDER_SCHEMA = [
-    ("lo_orderkey", dt.INT64),
-    ("lo_linenumber", dt.INT32),
-    ("lo_orderdate", dt.INT32),         # FK into date dim (yyyymmdd int)
-    ("lo_quantity", dt.INT64),
-    ("lo_extendedprice", dt.INT64),     # cents
-    ("lo_discount", dt.INT64),          # whole percent 0..10
-    ("lo_revenue", dt.INT64),
-]
-
-DATE_SCHEMA = [
-    ("d_datekey", dt.INT32),            # yyyymmdd
-    ("d_year", dt.INT32),
-    ("d_yearmonthnum", dt.INT32),
-    ("d_weeknuminyear", dt.INT32),
-]
-
-
-def load_ssb(catalog: Catalog, n_rows: int, seed: int = 0):
-    """Star-schema-benchmark shaped lineorder + date dim (spec domains for
-    the Q1.x columns; oracle = numpy over the same arrays)."""
-    rng = np.random.default_rng(seed)
-    years = np.arange(1992, 1999)
-    months = np.arange(1, 13)
-    days = np.arange(1, 29)
-    keys, yy, ym, wk = [], [], [], []
-    for y in years:
-        for m in months:
-            for d in days:
-                keys.append(y * 10000 + m * 100 + d)
-                yy.append(y)
-                ym.append(y * 100 + m)
-                wk.append(((m - 1) * 28 + d - 1) // 7 + 1)
-    date_arrays = {"d_datekey": np.asarray(keys, np.int32),
-                   "d_year": np.asarray(yy, np.int32),
-                   "d_yearmonthnum": np.asarray(ym, np.int32),
-                   "d_weeknuminyear": np.asarray(wk, np.int32)}
-    catalog.create_table(TableMeta("date_dim", DATE_SCHEMA, ["d_datekey"]),
-                         if_not_exists=True)
-    catalog.get_table("date_dim").insert_numpy(date_arrays)
-
-    qty = rng.integers(1, 51, n_rows).astype(np.int64)
-    price = rng.integers(90000, 10500001, n_rows).astype(np.int64)
-    disc = rng.integers(0, 11, n_rows).astype(np.int64)
-    odate = np.asarray(keys, np.int64)[
-        rng.integers(0, len(keys), n_rows)].astype(np.int32)
-    idx = np.arange(n_rows)
-    lo = {"lo_orderkey": (idx // 7 + 1).astype(np.int64),
-          "lo_linenumber": (idx % 7 + 1).astype(np.int32),
-          "lo_orderdate": odate,
-          "lo_quantity": qty,
-          "lo_extendedprice": price,
-          "lo_discount": disc,
-          "lo_revenue": price * (100 - disc) // 100}
-    catalog.create_table(TableMeta("lineorder", LINEORDER_SCHEMA,
-                                   ["lo_orderkey", "lo_linenumber"]),
-                         if_not_exists=True)
-    catalog.get_table("lineorder").insert_numpy(lo)
-    return lo, date_arrays
-
-
-SSB_Q11 = """
-select sum(lo_extendedprice * lo_discount) as revenue
-from lineorder join date_dim on lo_orderdate = d_datekey
-where d_year = 1993 and lo_discount between 1 and 3 and lo_quantity < 25
-"""
-
-SSB_Q12 = """
-select sum(lo_extendedprice * lo_discount) as revenue
-from lineorder join date_dim on lo_orderdate = d_datekey
-where d_yearmonthnum = 199401 and lo_discount between 4 and 6
-  and lo_quantity between 26 and 35
-"""
-
-SSB_Q13 = """
-select sum(lo_extendedprice * lo_discount) as revenue
-from lineorder join date_dim on lo_orderdate = d_datekey
-where d_weeknuminyear = 6 and d_year = 1994
-  and lo_discount between 5 and 7 and lo_quantity between 26 and 35
-"""
-
-
-def ssb_q1_oracle(lo, dates, q: str) -> int:
-    import numpy as _np
-    dk = dates["d_datekey"]
-    if q == "q11":
-        sel_dates = set(dk[dates["d_year"] == 1993].tolist())
-        m = (_np.isin(lo["lo_orderdate"], list(sel_dates))
-             & (lo["lo_discount"] >= 1) & (lo["lo_discount"] <= 3)
-             & (lo["lo_quantity"] < 25))
-    elif q == "q12":
-        sel_dates = set(dk[dates["d_yearmonthnum"] == 199401].tolist())
-        m = (_np.isin(lo["lo_orderdate"], list(sel_dates))
-             & (lo["lo_discount"] >= 4) & (lo["lo_discount"] <= 6)
-             & (lo["lo_quantity"] >= 26) & (lo["lo_quantity"] <= 35))
-    else:
-        sel_dates = set(dk[(dates["d_weeknuminyear"] == 6)
-                           & (dates["d_year"] == 1994)].tolist())
-        m = (_np.isin(lo["lo_orderdate"], list(sel_dates))
-             & (lo["lo_discount"] >= 5) & (lo["lo_discount"] <= 7)
-             & (lo["lo_quantity"] >= 26) & (lo["lo_quantity"] <= 35))
-    return int((lo["lo_extendedprice"][m].astype(object)
-                * lo["lo_discount"][m]).sum())
-
-
 # ------------------------------------------------------------- TPC-H Q3
 
 CUSTOMER_SCHEMA = [

@@ -45,6 +45,11 @@ class ExecBatch:
     batch: DeviceBatch
     dicts: Dict[str, List[str]]
     mask: jnp.ndarray
+    #: column -> (lo, hi) of its non-NULL integer values, where a producer
+    #: has observed them (the fused join, of its build side's columns): to
+    #: an integer column what `dicts` is to a string column, a bounded
+    #: code space (the grouped aggregate's wide dense path reads it)
+    ranges: Dict[str, tuple] = dataclasses.field(default_factory=dict)
 
     @property
     def padded_len(self) -> int:

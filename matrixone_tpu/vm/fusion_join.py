@@ -4,14 +4,21 @@ join barrier.
 A fusable `JoinOp` (inner / left / semi / anti with traceable keys and
 residual) splits into two traced pieces instead of splitting the plan:
 
-  * **build fragment** — key hash -> argsort -> sorted hash array (plus
-    the runtime-filter min/max ranges), traced ONCE per (build-side
-    shape bucket, dtype signature, key-dictionary content) and executed
-    as one device dispatch per build, carry-style like the fused grouped
-    aggregate;
-  * **probe fragment** — probe hash -> searchsorted -> duplicate-lane
-    expand -> key verify -> gather -> the downstream filter/project/
-    agg/topk chain, all ONE compiled program per probe batch.
+  * **build fragment** — the build side's batches placed into one batch
+    of a canonical length, then the key columns, the runtime-filter
+    min/max ranges, the key's own range and the range of every integer
+    column (one dispatch, one fetch of those scalars), then the lookup
+    structure `ops/kernels.join_lookup` chooses from what was observed: a
+    direct-address table for a build the plan declares unique on one
+    integer key of a small span (a dimension keyed 1..N, a date key), the
+    sorted hash array otherwise; traced ONCE per (build-side shape
+    bucket, dtype signature, key-dictionary content);
+  * **probe fragment** — probe key -> table gather, or probe hash ->
+    searchsorted -> duplicate-lane expand -> key verify; then gather ->
+    the downstream filter/project/agg/topk chain, all ONE compiled
+    program per probe batch.  A unique build (`Join.build_unique`) probes
+    one lane a row, has no overflow flag to fetch, and its output is not
+    compacted, so a star join's levels exchange batches of one shape.
 
 Both pieces call the SAME pure kernels `JoinOp` executes eagerly
 (vm/join.py: `build_key_columns`, `build_sorted_hash`, `expand_probe`,
@@ -19,13 +26,17 @@ Both pieces call the SAME pure kernels `JoinOp` executes eagerly
 degradation ladder is preserved bit-identically: a build side past the
 budget, an empty build, a trace failure, tiny probe batches, or
 `MO_FUSION_JOIN=0` all land on the original `JoinOp` (including its
-Grace spill path); duplicate fan-out past `max_matches` re-runs the
-SAME probe batch with a doubled lane budget (the overflow flag is a
-traced output of the probe program — one host sync, no extra dispatch).
+Grace spill path); against a build with duplicates, fan-out past
+`max_matches` re-runs the SAME probe batch with a doubled lane budget
+(the overflow flag is a traced output of the probe program — one host
+sync, no extra dispatch).  Spans `join.build`, `join.build.wait`,
+`join.probe.dispatch`, `join.probe.wait` and the `mo_join_*` counters say
+what a statement's joins cost (PERF.md section 3).
 """
 
 from __future__ import annotations
 
+import functools
 import itertools
 import time
 from typing import Dict, Iterator, List, Optional
@@ -41,7 +52,7 @@ from matrixone_tpu.vm import fusion as FF
 from matrixone_tpu.vm import join as J
 from matrixone_tpu.vm import operators as O
 from matrixone_tpu.vm.exprs import ExecBatch
-from matrixone_tpu.vm.operators import Operator, _concat_batches
+from matrixone_tpu.vm.operators import Operator
 
 #: join kinds the probe fragment traces; cross has no keys and full
 #: carries cross-batch build-matched state the host loop owns
@@ -65,6 +76,93 @@ def join_fusable(op) -> bool:
             and not FF._analyze_expr(node.residual, probe):
         return False
     return True
+
+
+#: fewest lanes of a fused build side.  A dimension whose chunks a filter
+#: prunes (one year's dates are one or two of its four segments) must
+#: not hand the probe program another build shape with every constant
+_MIN_BUILD_LANES = 4096
+
+
+@functools.partial(jax.jit, static_argnames=("lanes", "dtypes", "tails"))
+def _build_zeros(*, lanes, dtypes, tails):
+    """An empty build side of `lanes` lanes: (datas, valids, mask, rows)."""
+    return (tuple(jnp.zeros((lanes,) + t, d) for d, t in zip(dtypes, tails)),
+            tuple(jnp.zeros((lanes,), jnp.bool_) for _ in dtypes),
+            jnp.zeros((lanes,), jnp.bool_), jnp.zeros((), jnp.int32))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _build_place(acc, datas, valids, mask, n_rows, offset):
+    def put(buf, x):
+        return jax.lax.dynamic_update_slice_in_dim(buf, x, offset, 0)
+    bdatas, bvalids, bmask, bn = acc
+    return (tuple(put(b, x) for b, x in zip(bdatas, datas)),
+            tuple(put(b, x) for b, x in zip(bvalids, valids)),
+            put(bmask, mask), bn + n_rows.astype(jnp.int32))
+
+
+def _placed(batches: List[ExecBatch], names, lanes: int) -> ExecBatch:
+    """`batches` one after the other in ONE batch of `lanes` lanes, the
+    rest masked out.  An empty buffer, then one program a batch whose
+    offset is a traced scalar: the same two programs whether a filter
+    left one chunk or four, and wherever they land."""
+    first = [O._broadcast_full(batches[0].batch.columns[n],
+                               batches[0].padded_len) for n in names]
+    acc = _build_zeros(lanes=lanes,
+                       dtypes=tuple(str(c.data.dtype) for c in first),
+                       tails=tuple(tuple(c.data.shape[1:]) for c in first))
+    offset, dicts, ranges = 0, {}, {}
+    for ex in batches:
+        cols = [O._broadcast_full(ex.batch.columns[n], ex.padded_len)
+                for n in names]
+        acc = _build_place(acc, tuple(c.data for c in cols),
+                           tuple(c.validity for c in cols), ex.mask,
+                           jnp.asarray(ex.batch.n_rows), np.int32(offset))
+        offset += ex.padded_len
+        dicts.update(ex.dicts)
+        ranges.update(ex.ranges)
+    datas, valids, mask, n_rows = acc
+    db = DeviceBatch(columns={n: DeviceColumn(d, v, c.dtype)
+                              for n, c, d, v in zip(names, first, datas,
+                                                    valids)},
+                     n_rows=n_rows)
+    return ExecBatch(batch=db, dicts=dicts, mask=mask, ranges=ranges)
+
+
+def _at_first_lanes(batches, lanes: int):
+    """The probe side's batches, a segment's ragged last chunk padded to
+    the first chunk's `lanes` (the padding masked out), so that every
+    join level above, the chain and the aggregate compile ONE step a
+    statement shape and not one a chunk length: a probe step is seconds
+    of a cold run, and a star join has three or four."""
+    for ex in batches:
+        if lanes // 2 <= ex.padded_len < lanes:
+            ex = _placed([ex], list(ex.batch.columns), lanes)
+        yield ex
+
+
+def _canonical_build(batches: List[ExecBatch], schema) -> ExecBatch:
+    """The build side's batches as ONE batch of a length that does not
+    follow the constants: the bucket of their lanes, `_MIN_BUILD_LANES`
+    at least (`_concat_batches` compiles a concatenation for every
+    combination of lengths)."""
+    from matrixone_tpu.container.device import bucket_length
+    total = sum(ex.padded_len for ex in batches)
+    lanes = max(_MIN_BUILD_LANES, bucket_length(total))
+    if len(batches) == 1 and total == lanes:
+        return batches[0]
+    return _placed(batches, [n for n, _ in schema], lanes)
+
+
+def _scanned_table(node) -> str:
+    """The table a build side's plan scans, for the `join.build` span's
+    tag; a build that is itself a join is tagged with its probe side's."""
+    while node is not None:
+        if getattr(node, "table", None):
+            return node.table
+        node = getattr(node, "child", None) or getattr(node, "left", None)
+    return "-"
 
 
 class _IterSource(Operator):
@@ -107,6 +205,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         self._build_dicts: Dict[str, list] = {}
         self._cur_build: Optional[ExecBatch] = None
         self._bkey_dicts: List[Optional[list]] = []
+        self._build_ranges: Dict[str, tuple] = {}
 
     # ------------------------------------------------- analysis hooks
     def _source_schema(self):
@@ -193,7 +292,10 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         valids = self._flag_validities(ex)
         if valids is None:
             return False, tuple(a.arg is None for a in node.aggs)
-        got = np.asarray(jax.device_get(FF._allvalid_flags(valids)))
+        from matrixone_tpu.utils import motrace
+        with motrace.span("join.probe.wait"):
+            got = np.asarray(jax.device_get(FF._allvalid_flags(valids)))
+            M.device_wait.inc(site="join_flags")
         M.fusion_dispatch.inc(kind="step")
         self.last_stats["dispatches"] += 1
         ok = dict(zip(self._flag_cols, (bool(x) for x in got)))
@@ -227,7 +329,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
             sch += list(jn.right.schema)
         return ([n for n, _ in sch], [d for _, d in sch])
 
-    def _stream_batch(self, ex, payload, envs) -> ExecBatch:
+    def _stream_batch(self, ex, payload, envs, mm) -> ExecBatch:
         out_datas, out_valids, out_mask = payload
         names, dtypes = self._out_schema(ex)
         cols = {nm: DeviceColumn(d, v, t)
@@ -238,10 +340,18 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                  if t.is_varlen and env_final.get(nm) is not None}
         db = DeviceBatch(columns=cols,
                          n_rows=jnp.sum(out_mask.astype(jnp.int32)))
-        out = ExecBatch(batch=db, dicts=dicts, mask=out_mask)
+        ranges = {}
+        if not any(st.kind == "project" for st in self.stages):
+            ranges = {nm: r for nm, r in {**ex.ranges,
+                                          **self._build_ranges}.items()
+                      if nm in cols}
+        out = ExecBatch(batch=db, dicts=dicts, mask=out_mask,
+                        ranges=ranges)
         # same lane discipline as the per-operator probe: join output
-        # lanes are np*mm wide but usually sparse
-        return J._maybe_compact(out)
+        # lanes are mm*np wide but usually sparse.  One lane a probe row
+        # grows nothing, so nothing is compacted: the live count is a
+        # wait a batch, and its bucket a new shape a selectivity
+        return out if mm == 1 else J._maybe_compact(out)
 
     # ----------------------------------------------------- execution
     def execute(self):
@@ -249,44 +359,43 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         self.last_stats = {"mode": "none", "dispatches": 0,
                            "trace_ms": 0.0, "cache": "-",
                            "build_dispatches": 0}
+        from matrixone_tpu.utils import motrace
         join = self._join
         node = join.node
-        build_iter = self.right.execute()
-        build_batches, overflowed = J.stream_build_side(
-            build_iter, join.build_budget)
-        if overflowed or not build_batches:
+        # `join.build`: the build side's scan, the build programs'
+        # dispatches and the wait for the few scalars the host needs
+        with motrace.span("join.build", table=_scanned_table(node.right)):
+            build_iter = self.right.execute()
+            build_batches, overflowed = J.stream_build_side(
+                build_iter, join.build_budget)
+            bstate = None
+            if not overflowed and build_batches:
+                build = _canonical_build(build_batches, node.right.schema)
+                # build BEFORE the first probe pull: the build fragment
+                # pushes the runtime min/max filters onto the probe
+                # scans, and zonemap pruning only sees them for chunks
+                # not yet read
+                bstate = self._build_state(build)
+        if bstate is None:
             # over-budget (Grace spill) or empty build side: the
             # original JoinOp owns every one of those ladders
             M.fusion_exec.inc(mode="fallback")
             self.last_stats["mode"] = "fallback"
             yield from self._orig_join_chain(build_batches, build_iter)
             return
-        build = _concat_batches(build_batches, node.right.schema)
-        # build BEFORE the first probe pull: the build fragment pushes
-        # the runtime min/max filters onto the probe scans, and zonemap
-        # pruning only sees them for chunks not yet read
-        bstate = self._build_state(build)
         probe_iter = self.child.execute()
         first = next(probe_iter, None)
-        # degrade ladders below re-enter the ORIGINAL JoinOp: hand it
-        # the finalized build state so it neither re-runs the build
-        # math nor re-pushes the runtime filters
-        sorted_hash, order, bvalid, bkeys, _bkey = bstate
-        join._prepared_build = (build, sorted_hash, order, bvalid,
-                                bkeys, list(self._bkey_dicts))
-        if first is None:
-            M.fusion_exec.inc(mode="fallback")
-            self.last_stats["mode"] = "fallback"
-            yield from self._orig_join_chain([build], iter(()),
-                                             probe=([], iter(())))
-            return
-        if first.padded_len < FF.min_fused_rows():
-            M.fusion_exec.inc(mode="eager")
-            self.last_stats["mode"] = "eager"
+        if first is None or first.padded_len < FF.min_fused_rows():
+            # degrade ladders re-enter the ORIGINAL JoinOp with the
+            # finalized build state (`_handoff`)
+            join._prepared_build = self._handoff(build, bstate)
+            mode = "fallback" if first is None else "eager"
+            M.fusion_exec.inc(mode=mode)
+            self.last_stats["mode"] = mode
             yield from self._orig_join_chain(
-                [build], iter(()), probe=([first], probe_iter))
+                [build], iter(()),
+                probe=([] if first is None else [first], probe_iter))
             return
-        join._prepared_build = None
         yield from self._execute_join_fused(build, bstate, first,
                                             probe_iter)
 
@@ -310,10 +419,46 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         finally:
             join.left, join.right = saved_l, saved_r
 
+    def _run_program(self, entry, slot, fn_maker, name, args):
+        """Compile (once an entry) and dispatch one build-side program;
+        a trace failure runs the same function eagerly."""
+        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import motrace
+        fn = entry["fn"].get(slot)
+        if fn is None:
+            fn = FF._named(fn_maker(), name)
+            entry["fn"][slot] = fn
+        if not entry["failed"]:
+            if entry["compiled"].get(slot) is None:
+                t0 = time.perf_counter()
+                with motrace.span("fusion.compile", slot=slot):
+                    try:
+                        lowered = jax.jit(fn).lower(*args)
+                    except Exception:   # noqa: BLE001 — whatever the
+                        # tracer rejected, the eager call below computes
+                        # the identical result (same function)
+                        lowered = None
+                        self._note_trace_fail(entry)
+                    if lowered is not None:
+                        # the device compiler's refusal raises
+                        self._note_compiled(entry, slot, lowered.compile(),
+                                            t0)
+            if not entry["failed"]:
+                self.last_stats["build_dispatches"] += 1
+                return self._dispatch_entry(entry, slot, args)
+        M.fusion_dispatch.inc(kind="eager")
+        return fn(*args)
+
     def _build_state(self, build):
         """Trace (or reuse) the build fragment for this build batch and
-        execute it: ONE dispatch producing the sorted hash array, the
-        row order, the key columns and the runtime-filter ranges."""
+        execute it: one dispatch producing the key columns, the runtime-
+        filter ranges, the key's own range and the live row count, one
+        fetch of those few scalars, and then the lookup structure that
+        `ops/kernels.join_lookup` chooses from what was just observed: a
+        direct-address table for a unique integer key of a small span, the
+        sorted hash array otherwise.  -> dict(lookup, arrays, bvalid,
+        bkeys, key)."""
+        from matrixone_tpu.ops import kernels as HK
         from matrixone_tpu.utils import metrics as M
         from matrixone_tpu.utils import motrace
         node = self._join.node
@@ -323,6 +468,10 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
             O._expr_dict(k, build) if k.dtype.is_varlen else None
             for k in node.right_keys]
         specs = J.runtime_filter_specs(node)
+        dense_candidate = (node.build_unique and len(node.right_keys) == 1
+                           and node.right_keys[0].dtype.is_integer
+                           and node.left_keys[0].dtype.is_integer
+                           and node.kind != "full")
         # the build program depends ONLY on the build-key expressions —
         # its lifted-literal inputs (and baked values in the key) come
         # from them, never from the fragment's probe-side chain: two
@@ -357,7 +506,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                tuple(FF._dict_key(d) for d in self._bkey_dicts),
                tuple(FF._dict_key(FF._static_dict(e, self._build_dicts))
                      for _i, e in binfo.dictdep),
-               FF.ENC.signature())
+               dense_candidate, FF.ENC.signature())
         entry = FF.CACHE.entry(key)
         if keyaudit.armed():
             keyaudit.audit("vm/fusion_join.py:joinbuild", key, {
@@ -372,11 +521,14 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                       for lit in binfo.baked),
                 "lift_arity": len(lift_lits),
                 "rf_spec_indexes": tuple(i for i, _lk in specs),
+                "dense_candidate": dense_candidate,
                 "encoding_policy": FF.ENC.signature(),
             })
         bschema = tuple((nm, c.dtype)
                         for nm, c in build.batch.columns.items())
         bdicts = self._build_dicts
+        int_cols = tuple(nm for nm, t in bschema
+                         if t.is_integer and not t.is_varlen)
 
         def _join_build_step(datas, valids, n_rows, mask, lifted):
             binding = {id(lit): v
@@ -389,59 +541,97 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                                   n_rows=n_rows),
                                 dicts=bdicts, mask=mask)
                 bkeys, _ = J.build_key_columns(node, bex)
-                sorted_hash, order, bvalid = J.build_sorted_hash(
-                    bkeys, bex.mask)
+                if dense_candidate:
+                    # the lookup is chosen after the key's range is
+                    # seen: no hash and no sort in this program
+                    _h, bvalid = J.hash_valid_keys(bkeys, bex.mask)
+                    sorted_hash = order = None
+                    krange = J.value_range(bkeys[0], bvalid)
+                else:
+                    sorted_hash, order, bvalid = J.build_sorted_hash(
+                        bkeys, bex.mask)
+                    krange = None
                 lo, hi, anyv = J.runtime_filter_ranges(specs, bkeys,
                                                        bvalid)
+                # the range of every integer column of the build side:
+                # what a dictionary is to a string column, for the
+                # grouped aggregate above the join (`ExecBatch.ranges`)
+                colrange = tuple(
+                    J.value_range(cols[nm], bex.mask) for nm in int_cols)
                 return (sorted_hash, order, bvalid,
                         tuple(k.data for k in bkeys),
-                        tuple(k.validity for k in bkeys), lo, hi, anyv)
+                        tuple(k.validity for k in bkeys),
+                        (lo, hi, anyv, krange,
+                         jnp.sum(bvalid.astype(jnp.int32)), colrange))
 
-        fn = entry["fn"].get("build")
-        if fn is None:
-            fn = FF._named(_join_build_step, "frag_join_build")
-            entry["fn"]["build"] = fn
         args = (tuple(c.data for c in build.batch.columns.values()),
                 tuple(c.validity for c in build.batch.columns.values()),
                 jnp.asarray(build.batch.n_rows, jnp.int32), build.mask,
                 tuple(np.dtype(lit.dtype.np_dtype).type(lit.value)
                       for lit in lift_lits))
-        out = None
-        if not entry["failed"]:
-            compiled = entry["compiled"].get("build")
-            if compiled is None:
-                t0 = time.perf_counter()
-                with motrace.span("fusion.compile", slot="build"):
-                    try:
-                        lowered = jax.jit(fn).lower(*args)
-                    except Exception:   # noqa: BLE001 — whatever the
-                        # tracer rejected, the eager call below computes
-                        # the identical result (same function)
-                        lowered = None
-                        self._note_trace_fail(entry)
-                    if lowered is not None:
-                        # the device compiler's refusal raises
-                        compiled = lowered.compile()
-                        self._note_compiled(entry, "build", compiled, t0)
-            if not entry["failed"]:
-                out = self._dispatch_entry(entry, "build", args)
-                self.last_stats["build_dispatches"] += 1
-        if out is None:
-            out = fn(*args)
-            M.fusion_dispatch.inc(kind="eager")
         (sorted_hash, order, bvalid, bkdatas, bkvalids,
-         lo, hi, anyv) = out
+         scalars) = self._run_program(
+            entry, "build", lambda: _join_build_step, "frag_join_build",
+            args)
         bkeys = [DeviceColumn(d, v, k.dtype)
                  for d, v, k in zip(bkdatas, bkvalids,
                                     node.right_keys)]
+        # the one wait of a build: a handful of scalars
+        with motrace.span("join.build.wait"):
+            lo, hi, anyv, krange, n_valid, colrange = jax.device_get(
+                scalars)
+            M.device_wait.inc(site="join_rf")
+        M.join_build_rows.inc(int(n_valid))
+        self._build_ranges = {
+            nm: (int(r[0]), int(r[1]))
+            for nm, r in zip(int_cols, colrange) if int(r[0]) <= int(r[1])}
         if specs and node.kind in ("inner", "semi"):
-            got = jax.device_get((lo, hi, anyv))
             self._join.apply_runtime_filters(
-                specs, np.asarray(got[0]), np.asarray(got[1]),
-                bool(got[2]))
-        return sorted_hash, order, bvalid, bkeys, key
+                specs, np.asarray(lo), np.asarray(hi), bool(anyv))
+        state = {"lookup": "sorted", "arrays": (sorted_hash, order),
+                 "bvalid": bvalid, "bkeys": bkeys, "key": key}
+        if not dense_candidate:
+            return state
+        span = int(krange[1]) - int(krange[0]) + 1 if bool(anyv) else 1
+        nb = int(build.mask.shape[0])
+        kdtype = str(bkeys[0].data.dtype)
+        if HK.join_lookup(unique=True, int_keys=1, span=span) == "dense":
+            from matrixone_tpu.container.device import bucket_length
+            table_len = max(_MIN_BUILD_LANES, bucket_length(span))
+            klo = np.int64(krange[0]) if bool(anyv) else np.int64(0)
+            table, dup = self._run_program(
+                FF.CACHE.entry(("jointable", kdtype, nb, table_len)),
+                "table",
+                lambda: (lambda bkey, bv, lo_: J.build_dense_table(
+                    bkey, bv, lo_, table_len)),
+                "frag_join_table", (bkeys[0].data, bvalid, klo))
+            state.update(lookup="dense", arrays=(table, klo),
+                         table_len=table_len, dup=dup)
+            return state
+        state["arrays"] = self._run_program(
+            FF.CACHE.entry(("joinsort", kdtype, nb)), "sort",
+            lambda: (lambda bkey, bkv, mask: J.build_sorted_hash(
+                [DeviceColumn(bkey, bkv, node.right_keys[0].dtype)],
+                mask)[:2]),
+            "frag_join_sort",
+            (bkeys[0].data, bkeys[0].validity, build.mask))
+        return state
 
-    def _probe_runtime_key(self, ex, envs, mm, build_key, sizes_flags):
+    def _handoff(self, build, bstate):
+        """The finalized build as the ORIGINAL JoinOp takes it over on a
+        degradation: it neither re-runs the build math nor re-pushes the
+        runtime filters.  A direct-address build has no sorted hash yet;
+        the ladder is rare, so it is made here, eagerly."""
+        if bstate["lookup"] == "dense":
+            sorted_hash, order, _ = J.build_sorted_hash(bstate["bkeys"],
+                                                        build.mask)
+        else:
+            sorted_hash, order = bstate["arrays"]
+        return (build, sorted_hash, order, bstate["bvalid"],
+                bstate["bkeys"], list(self._bkey_dicts))
+
+    def _probe_runtime_key(self, ex, envs, mm, build_key, sizes_flags,
+                           lookup_sig=("sorted",)):
         cols = ex.batch.columns
         colsig = tuple((nm, int(c.dtype.oid), str(c.data.dtype),
                         tuple(c.data.shape))
@@ -458,11 +648,11 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
              if k.dtype.is_varlen else None)
             for k, bd in zip(node.left_keys, self._bkey_dicts))
         return (self._plan_sig, colsig, int(ex.mask.shape[0]), baked,
-                dicts, sizes_flags, mm, build_key, keydicts,
+                dicts, sizes_flags, mm, build_key, keydicts, lookup_sig,
                 FF.ENC.signature())
 
     def _make_probe_step(self, trig_schema, bschema, sizes, flags, envs,
-                         mm):
+                         mm, lookup="sorted"):
         chain = self._make_chain_fn(sizes, flags, envs)
         node = self._join.node
         lift_lits = list(self._lift_lits)
@@ -471,9 +661,8 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         kinds_collapse = node.kind in ("semi", "anti")
 
         def _join_probe_step(pdatas, pvalids, p_nrows, pmask, bdatas,
-                             bvalids, b_nrows, bmask, sorted_hash,
-                             border, bkdatas, bkvalids, lifted, seens,
-                             carry):
+                             bvalids, b_nrows, bmask, lookup_arrays,
+                             bkdatas, bkvalids, lifted, seens, carry):
             binding = {id(lit): v
                        for lit, v in zip(lift_lits, lifted)}
             with EX.lifted_literal_scope(binding):
@@ -493,16 +682,26 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                          for d, v, k in zip(bkdatas, bkvalids,
                                             node.right_keys)]
                 pkeys = J.probe_key_columns(node, pex, bkey_dicts)
-                phash, pvalid = J.hash_valid_keys(pkeys, pex.mask)
-                out, overflow, _bm = J.expand_probe(
-                    node, pex, build, sorted_hash, border, phash,
-                    pvalid, pkeys, bkeys, mm, None)
+                if lookup == "dense":
+                    table, klo = lookup_arrays
+                    out = J.expand_probe_dense(
+                        node, pex, build, table, klo, pkeys[0],
+                        pex.mask & pkeys[0].validity)
+                    overflow = jnp.zeros((), jnp.bool_)
+                else:
+                    sorted_hash, border = lookup_arrays
+                    phash, pvalid = J.hash_valid_keys(pkeys, pex.mask)
+                    out, overflow, _bm = J.expand_probe(
+                        node, pex, build, sorted_hash, border, phash,
+                        pvalid, pkeys, bkeys, mm, None)
                 if kinds_collapse:
                     oex = J.collapse_semi_anti(node, pex, out.mask, mm)
                 else:
                     oex = out
                 payload, out_seens = chain(oex, seens, carry)
-                return payload, out_seens, overflow
+                counts = jnp.stack([jnp.sum(pex.mask.astype(jnp.int32)),
+                                    jnp.sum(out.mask.astype(jnp.int32))])
+                return payload, out_seens, overflow, counts
 
         return _join_probe_step
 
@@ -511,20 +710,30 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         from matrixone_tpu.utils import motrace
         self.last_stats["mode"] = "fused"
         M.fusion_exec.inc(mode="fused")
-        sorted_hash, border, bvalid, bkeys, build_key = bstate
+        bkeys, build_key = bstate["bkeys"], bstate["key"]
+        lookup = bstate["lookup"]
+        lookup_sig = (lookup, bstate.get("table_len"))
+        unique = self._join.node.build_unique
+        counts: list = []        # (device [in, matched] of a batch, mm)
+        # device flags that a build held to be unique was not: read once,
+        # with the counts, and never expected to be true (`_count_probe`)
+        broken: list = [bstate["dup"]] if lookup == "dense" else []
         node = self._agg_op.node if self._agg_op is not None else None
         grouped = self._terminal == "agg_grouped"
         nkeys = len(node.group_keys) if grouped else 0
         key_dicts: List[Optional[list]] = [None] * nkeys
         bschema = tuple((nm, c.dtype)
                         for nm, c in build.batch.columns.items())
-        mm = self._join.max_matches
+        # a build that is unique on the join keys has one match a probe
+        # row at most: one lane, and no overflow to look for
+        mm = 1 if unique or lookup == "dense" else self._join.max_matches
         carry = None
         if self._terminal == "topk":
             carry = self._init_topk_carry()
         seens: tuple = tuple(np.int64(0) for _ in self._limit_stages)
         trace_sizes: object = ()
-        batches = itertools.chain([first], probe_iter)
+        batches = _at_first_lanes(itertools.chain([first], probe_iter),
+                                  first.padded_len)
         for ex in batches:
             envs = self._dict_envs(ex.dicts)
             sizes = None
@@ -543,9 +752,9 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                     # same build-state handoff as the execute() ladders:
                     # the original JoinOp must not redo the finalized
                     # build math or re-push the runtime filters
-                    self._join._prepared_build = (
-                        build, sorted_hash, border, bvalid, bkeys,
-                        list(self._bkey_dicts))
+                    self._join._prepared_build = self._handoff(build,
+                                                               bstate)
+                    self._count_probe(counts, broken)
                     yield from self._degrade_join_grouped(
                         carry, trace_sizes, key_dicts, build, ex,
                         batches)
@@ -557,7 +766,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                          for nm, c in ex.batch.columns.items())
             while True:
                 key = self._probe_runtime_key(ex, envs, mm, build_key,
-                                              (sizes, flags))
+                                              (sizes, flags), lookup_sig)
                 entry = FF.CACHE.entry(key)
                 if keyaudit.armed():
                     deps = self._audit_deps(envs, [], [],
@@ -571,16 +780,19 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                         for k, bd in zip(self._join.node.left_keys,
                                          self._bkey_dicts))
                     deps["max_matches"] = mm
+                    deps["lookup"] = lookup_sig
                     keyaudit.audit("vm/fusion_join.py:joinprobe", key,
                                    deps)
                 slot = "step"
                 if self._terminal == "agg_scalar":
                     slot = "step0" if carry is None else "stepN"
+                # (not through `_run_program`: mokey ties a traced
+                # closure to its key where both stand in one function)
                 fn = entry["fn"].get(slot)
                 if fn is None:
                     fn = FF._named(
                         self._make_probe_step(trig, bschema, sizes,
-                                              flags, envs, mm),
+                                              flags, envs, mm, lookup),
                         self._step_name(slot))
                     entry["fn"][slot] = fn
                 args = (tuple(c.data
@@ -594,7 +806,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                         tuple(c.validity for c in build.batch.columns
                               .values()),
                         jnp.asarray(build.batch.n_rows, jnp.int32),
-                        build.mask, sorted_hash, border,
+                        build.mask, bstate["arrays"],
                         tuple(k.data for k in bkeys),
                         tuple(k.validity for k in bkeys),
                         self._lifted_values([]), seens, carry)
@@ -617,28 +829,66 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                 self._note_compiled(entry, slot,
                                                     compiled, t0)
                     if not entry["failed"]:
-                        out = self._dispatch_entry(entry, slot, args)
+                        with motrace.span("join.probe.dispatch"):
+                            out = self._dispatch_entry(entry, slot, args)
                 if out is None:
                     out = fn(*args)
                     M.fusion_dispatch.inc(kind="eager")
-                payload, new_seens, overflow = out
-                if not bool(jax.device_get(overflow)):
+                payload, new_seens, overflow, n_in_matched = out
+                counts.append((n_in_matched, mm))
+                if mm == 1 and (unique or lookup == "dense"):
+                    seens = new_seens        # nothing can overflow
+                    broken.append(overflow)
+                    break
+                with motrace.span("join.probe.wait"):
+                    over = bool(jax.device_get(overflow))
+                    M.device_wait.inc(site="join_overflow")
+                if not over:
                     seens = new_seens
                     break
                 # duplicate fan-out past the lane budget: re-run the
                 # SAME batch with doubled lanes (the JoinOp ladder)
+                M.join_probe_retries.inc()
                 mm *= 2
             if self._terminal == "stream":
-                yield self._stream_batch(ex, payload, envs)
+                yield self._stream_batch(ex, payload, envs, mm)
             else:
                 carry = payload
             if self._limits_satisfied(seens):
                 if hasattr(probe_iter, "close"):
                     probe_iter.close()
                 break
+        self._count_probe(counts, broken)
         if self._terminal == "stream":
             return
         yield self._finalize(carry, trace_sizes, key_dicts)
+
+    def _count_probe(self, counts, broken=()) -> None:
+        """Probe rows in and matched, and the lanes they were expanded
+        to, from the per-batch device scalars: one fetch a join a
+        statement, after its last probe step was enqueued.  The same
+        fetch brings the `broken` flags of a build the plan held unique
+        (a second row of one key in the direct-address table, a second
+        match behind a one-lane probe): the engine checks that key at
+        every commit, so one that is true is a fault, and the statement
+        fails instead of answering short."""
+        from matrixone_tpu.utils import metrics as M
+        from matrixone_tpu.utils import motrace
+        if not counts:
+            return
+        with motrace.span("join.probe.wait"):
+            got, bad = jax.device_get(([c for c, _mm in counts],
+                                       list(broken)))
+            M.device_wait.inc(site="join_stats")
+        if any(bool(b) for b in bad):
+            raise RuntimeError(
+                "fused join: two build rows under one key (or one 64-bit "
+                "hash) of a primary key the plan took to be enforced")
+        M.join_probe_rows.inc(sum(int(g[0]) for g in got), stage="in")
+        M.join_probe_rows.inc(sum(int(g[1]) for g in got), stage="matched")
+        M.join_probe_lanes.inc(sum(int(g[0]) * mm
+                                   for g, (_c, mm) in zip(got, counts)))
+        counts.clear()
 
     def _degrade_join_grouped(self, carry, sizes, key_dicts, build, ex,
                               rest):

@@ -10,7 +10,11 @@ cross). TPU re-design:
 
 Duplicate fan-out beyond max_matches is detected on host and the probe
 re-runs with a doubled budget — the shape-bucketing trick the rest of the
-engine uses, applied to join multiplicity.
+engine uses, applied to join multiplicity.  Match lanes are laid out
+lane-major (`_lanes`).  A build that is unique on one integer key of a
+small span skips hash, sort and search: `build_dense_table` +
+`expand_probe_dense`, one gather a probe row (chosen by
+`ops/kernels.join_lookup`, used by the fused fragments).
 
 Build sides larger than the device budget Grace-spill (reference:
 colexec/spillutil/join_spill.go + spill_threshold.go): both sides are
@@ -222,16 +226,34 @@ def runtime_filter_ranges(specs, bkeys, bvalid):
     as traced outputs, the eager path device_gets them."""
     los, his = [], []
     for i, _lk in specs:
-        data = bkeys[i].data
-        big = jnp.iinfo(data.dtype).max
-        los.append(jnp.min(jnp.where(bvalid, data, big)).astype(jnp.int64))
-        his.append(jnp.max(jnp.where(bvalid, data,
-                                     -big - 1)).astype(jnp.int64))
+        lo, hi = value_range(bkeys[i], bvalid)
+        los.append(lo)
+        his.append(hi)
     lo = (jnp.stack(los) if los
           else jnp.zeros((0,), jnp.int64))
     hi = (jnp.stack(his) if his
           else jnp.zeros((0,), jnp.int64))
     return lo, hi, jnp.any(bvalid)
+
+
+def value_range(col: DeviceColumn, mask):
+    """(lo, hi) of an integer column's non-NULL live values as int64
+    scalars; lo > hi where there is none."""
+    live = mask & col.validity
+    big = jnp.iinfo(col.data.dtype).max
+    return (jnp.min(jnp.where(live, col.data, big)).astype(jnp.int64),
+            jnp.max(jnp.where(live, col.data, -big - 1)).astype(jnp.int64))
+
+
+def _lanes(x, mm: int):
+    """Each probe row's value on its `mm` match lanes, LANE-MAJOR: lane j
+    of probe row i is element j * np + i, so the expansion is a
+    concatenation.  The row-major [np, mm] interleaving it replaces (a
+    gather by repeat(arange(np), mm), gathers through [np, mm] indexes)
+    made the chip's compiler re-lay every lane array out around a minor
+    dimension of 4: over two minutes a probe step at a 2^20-row batch,
+    under a second this way (PERF.md section 6, PR 33)."""
+    return x if mm == 1 else jnp.concatenate([x] * mm, axis=0)
 
 
 def expand_probe(node, ex: ExecBatch, build: ExecBatch, sorted_hash,
@@ -240,43 +262,82 @@ def expand_probe(node, ex: ExecBatch, build: ExecBatch, sorted_hash,
     """One probe batch against a finalized build side: searchsorted ->
     expand `mm` duplicate lanes -> verify true key equality -> gather
     both sides -> residual -> left/full NULL-extension.  Returns
-    (out ExecBatch [np*mm lanes], overflow bool array, build_matched').
+    (out ExecBatch [mm*np lanes, lane-major], overflow bool array,
+    build_matched').
     Pure (the overflow flag stays on device): JoinOp device_gets it,
     the fused probe program returns it as a traced output."""
-    np_ = ex.padded_len
+    nb = sorted_hash.shape[0]
     # entry point into the sorted hash run (searchsorted-left)
     start = jnp.searchsorted(sorted_hash, phash).astype(jnp.int32)  # [np]
-    lane = jnp.arange(mm, dtype=jnp.int32)
-    pos = start[:, None] + lane[None, :]                  # [np, mm]
-    pos_c = jnp.clip(pos, 0, sorted_hash.shape[0] - 1)
-    cand_hash = sorted_hash[pos_c]
-    hash_ok = (cand_hash == phash[:, None]) & \
-        (pos < sorted_hash.shape[0]) & pvalid[:, None]
+    # every per-lane array is flat [mm*np], lane-major (see `_lanes`)
+    pos = jnp.concatenate([start + j for j in range(mm)])
+    pos_c = jnp.clip(pos, 0, nb - 1)
+    phash_l = _lanes(phash, mm)
+    hash_ok = (sorted_hash[pos_c] == phash_l) & (pos < nb) \
+        & _lanes(pvalid, mm)
     cand_rows = border[pos_c]                             # build row ids
     # verify true key equality (hash only routes)
     key_ok = hash_ok
     for pk, bk in zip(pkeys, bkeys):
-        pv = pk.data[:, None]
+        pv = _lanes(pk.data, mm)
         bv = bk.data[cand_rows]
-        if pk.data.dtype != bv.dtype:
-            ct = jnp.promote_types(pk.data.dtype, bv.dtype)
+        if pv.dtype != bv.dtype:
+            ct = jnp.promote_types(pv.dtype, bv.dtype)
             pv, bv = pv.astype(ct), bv.astype(ct)
         key_ok = key_ok & (pv == bv)
     # overflow: a (mm+1)-th duplicate would also match
-    extra = jnp.clip(start + mm, 0, sorted_hash.shape[0] - 1)
+    extra = jnp.clip(start + mm, 0, nb - 1)
     overflow = jnp.any(
-        (sorted_hash[extra] == phash) & (start + mm < sorted_hash.shape[0])
-        & pvalid)
+        (sorted_hash[extra] == phash) & (start + mm < nb) & pvalid)
+    out, build_matched = emit_lanes(node, ex, build, key_ok, cand_rows, mm,
+                                    build_matched)
+    return out, overflow, build_matched
 
-    match = key_ok.reshape(-1)                            # [np*mm]
-    probe_idx = jnp.repeat(jnp.arange(np_, dtype=jnp.int32), mm)
-    build_idx = cand_rows.reshape(-1)
 
+def build_dense_table(bkey, bvalid, lo, table_len: int):
+    """Direct-address table of a build that is unique on one integer key
+    whose values span at most `table_len`: table[key - lo] is the build
+    row holding that key, -1 where none does.  One scatter, no hash and
+    no sort.  `lo` is a device scalar (the smallest valid key).
+    -> (table, dup): `dup` says that two valid rows share a key (fewer
+    slots are filled than rows were placed), which the caller holds to
+    be impossible and must not let pass in silence."""
+    nb = bkey.shape[0]
+    slot = jnp.where(bvalid, bkey.astype(jnp.int64) - lo,
+                     table_len).astype(jnp.int32)
+    table = jnp.full((table_len,), -1, jnp.int32).at[slot].set(
+        jnp.arange(nb, dtype=jnp.int32), mode="drop")
+    dup = jnp.sum((table >= 0).astype(jnp.int32)) \
+        != jnp.sum(bvalid.astype(jnp.int32))
+    return table, dup
+
+
+def expand_probe_dense(node, ex: ExecBatch, build: ExecBatch, table, lo,
+                       pkey, pvalid):
+    """One probe batch against a direct-address table
+    (`build_dense_table`): a subtraction and one gather a probe row, one
+    lane a row, nothing to verify and nothing that can overflow.
+    -> out ExecBatch [np lanes]."""
+    slot = pkey.data.astype(jnp.int64) - lo
+    inside = pvalid & (slot >= 0) & (slot < table.shape[0])
+    row = table[jnp.clip(slot, 0, table.shape[0] - 1).astype(jnp.int32)]
+    out, _ = emit_lanes(node, ex, build, inside & (row >= 0),
+                        jnp.maximum(row, 0), 1, None)
+    return out
+
+
+def emit_lanes(node, ex: ExecBatch, build: ExecBatch, match, build_idx,
+               mm: int, build_matched):
+    """The probe's output from its match lanes: `match` [mm*np] says
+    which lane found its key, `build_idx` which build row.  Gathers both
+    sides, applies the residual, NULL-extends for left/full.
+    -> (out ExecBatch, build_matched')."""
+    np_ = ex.padded_len
     cols = {}
     for name, _ in node.left.schema:
         c = _broadcast_full(ex.batch.columns[name], np_)
-        cols[name] = DeviceColumn(c.data[probe_idx],
-                                  c.validity[probe_idx], c.dtype)
+        cols[name] = DeviceColumn(_lanes(c.data, mm),
+                                  _lanes(c.validity, mm), c.dtype)
     for name, _ in node.right.schema:
         c = _broadcast_full(build.batch.columns[name], build.padded_len)
         validity = c.validity[build_idx] & match
@@ -295,10 +356,10 @@ def expand_probe(node, ex: ExecBatch, build: ExecBatch, sorted_hash,
         # extension) — monotonic across overflow re-runs
         build_matched = build_matched.at[build_idx].max(out.mask)
     if node.kind in ("left", "full"):
-        matched_any = jnp.any(out.mask.reshape(np_, mm), axis=1)
-        lane0 = jnp.tile(lane == 0, (np_,))
-        null_emit = lane0 & ~jnp.repeat(matched_any, mm) & \
-            jnp.repeat(ex.mask, mm)
+        matched_any = jnp.any(out.mask.reshape(mm, np_), axis=0)
+        null_emit = jnp.concatenate(          # lane 0 carries the NULLs
+            [ex.mask & ~matched_any]
+            + [jnp.zeros((np_,), jnp.bool_)] * (mm - 1))
         # null-extended lanes: right-side columns must read as NULL
         for name, _ in node.right.schema:
             c = out.batch.columns[name]
@@ -306,14 +367,14 @@ def expand_probe(node, ex: ExecBatch, build: ExecBatch, sorted_hash,
                 c.data, c.validity & ~null_emit, c.dtype)
         out.mask = out.mask | null_emit
     out.batch.n_rows = jnp.sum(out.mask.astype(jnp.int32))
-    return out, overflow, build_matched
+    return out, build_matched
 
 
 def collapse_semi_anti(node, ex: ExecBatch, out_mask, mm: int):
     """Collapse match lanes back onto the probe rows: emit each left
     row once iff it has (semi) / lacks (anti) a surviving match."""
     np_ = ex.padded_len
-    matched_any = jnp.any(out_mask.reshape(np_, mm), axis=1)
+    matched_any = jnp.any(out_mask.reshape(mm, np_), axis=0)
     keep = (ex.mask & matched_any if node.kind == "semi"
             else ex.mask & ~matched_any)
     db = DeviceBatch(

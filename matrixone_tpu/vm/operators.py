@@ -15,6 +15,7 @@ pull loop (`vm/pipeline/pipeline.go:62`). Differences by design:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import threading
 import time
@@ -30,7 +31,7 @@ from matrixone_tpu.container.device import (DeviceBatch, DeviceColumn,
 from matrixone_tpu.container.dtypes import DType, TypeOid
 from matrixone_tpu.ops import agg as A, filter as F, sort as msort
 from matrixone_tpu.sql import plan as P
-from matrixone_tpu.sql.expr import AggCall, BoundExpr
+from matrixone_tpu.sql.expr import AggCall, BoundCol, BoundExpr
 from matrixone_tpu.vm.exprs import EvalError, ExecBatch, eval_expr
 
 
@@ -687,7 +688,7 @@ class AggOp(Operator):
 
     def _grouped_agg_inner(self, nkeys, key_dicts, seed=None):
         state = seed   # dict: keys:[arrays], kvalid:[arrays], partials per agg
-        dense = None       # small-key dense accumulator (no hash, no sort)
+        dense = None       # dense accumulator (no hash, no sort)
         # a seeded state is already in general form: the dense fast path
         # cannot absorb it, so it stays off for the remaining stream
         dense_checked = seed is not None
@@ -707,12 +708,13 @@ class AggOp(Operator):
                 dense_checked = True
                 dense = self._dense_init(ex)
             if dense is not None:
-                if self._dense_sizes(ex) == list(dense["sizes"]):
+                if self._dense_fits(dense, ex):
                     self._dense_step(dense, kdata, kvalid, ex.mask, values)
                     continue
                 # a key dictionary grew mid-stream (concurrent insert /
-                # union arm): the dense key space is stale — convert the
-                # partials to a standard group table and continue general
+                # union arm) or an integer key left the range the slots
+                # were laid out for: the dense key space is stale — convert
+                # the partials to a standard group table and continue general
                 state = self._dense_to_state(dense)
                 dense = None
             if self._spill is not None:
@@ -755,25 +757,58 @@ class AggOp(Operator):
             if pstate is not None and int(jax.device_get(pstate["n"])):
                 yield self._finalize(pstate, key_dicts)
 
-    # ---- dense small-key fast path (the Q1 shape: GROUP BY two dict-
-    # coded columns with additive aggregates). Group ids come from a
-    # mixed-radix expansion over the key dictionaries instead of
-    # hash+argsort, and the deduplicated partial lanes fold as fused
+    # ---- dense fast path: every group key has a bounded code space (a
+    # dictionary, a bool, or an integer column whose range its producer
+    # observed: `ExecBatch.ranges`), so the group id is a mixed-radix
+    # number over the key spaces instead of hash+argsort, with additive
+    # aggregates.  ONE ladder (init / fits / step / to_state), two folds
+    # chosen by the slot count.  Few slots (the Q1 shape: GROUP BY two
+    # dict-coded columns): the deduplicated partial lanes fold as fused
     # masked sums (ops/agg.dense_lane_partials); cross-chunk merge is an
-    # elementwise add of (G,)-sized partials — no re-grouping sort.
-    def _dense_sizes(self, ex) -> Optional[List[int]]:
-        """Per-key dense domain sizes, or None when a key has no bounded
-        code space (numeric keys, computed strings without a dict)."""
-        sizes = []
+    # elementwise add of (G,)-sized partials — no re-grouping sort.  Many
+    # (the star-join shape: 1,000 brands x 7 years), or a key that is an
+    # integer range: a batch folds in with ONE compiled program of
+    # scatter-adds (`_wide_fold`) into one int64 accumulator; no group
+    # count to wait for, the one wait is at the end: which slots hold rows.
+    WIDE_SLOTS_MAX = 1 << 23       # int64 cells of the accumulator: 64 MB
+
+    def _key_spaces(self, ex) -> Optional[List[tuple]]:
+        """Per key (size, lo, hi): a dictionary's length (lo and hi None),
+        or the observed range of a plain integer column with the size
+        rounded up to a power of two (a stable shape across parameter
+        draws); None when a key has no bounded code space (numeric keys
+        nobody observed, computed strings without a dict)."""
+        out = []
         for k in self.node.group_keys:
             d = _expr_dict(k, ex)
             if d is not None:
-                sizes.append(max(len(d), 1))
+                out.append((max(len(d), 1), None, None))
             elif k.dtype.oid == TypeOid.BOOL:
-                sizes.append(2)
+                out.append((2, None, None))
+            elif (isinstance(k, BoundCol) and k.dtype.is_integer
+                    and k.name in ex.ranges):
+                lo, hi = (int(v) for v in ex.ranges[k.name])
+                out.append((1 << max(hi - lo, 0).bit_length(), lo, hi))
             else:
                 return None
-        return sizes
+        return out
+
+    def _dense_fits(self, dense, ex) -> bool:
+        """Does this batch's code space fit the slots laid out?  A
+        dictionary must be the same length; an integer range must lie
+        inside [lo, lo + size)."""
+        spaces = self._key_spaces(ex)
+        if spaces is None:
+            return False
+        for (size, lo, hi), wsize, wlo in zip(spaces, dense["sizes"],
+                                              dense["los"]):
+            if (lo is None) != (wlo is None):
+                return False
+            if lo is None and size != wsize:
+                return False
+            if lo is not None and (lo < wlo or hi >= wlo + wsize):
+                return False
+        return True
 
     @staticmethod
     def _dense_fields(a: AggCall) -> List[tuple]:
@@ -787,7 +822,10 @@ class AggOp(Operator):
             return [(cls, "sum"), ("int", "count")]
         return [("float", "sum"), ("float", "sumsq"), ("int", "count")]
 
-    def _dense_init(self, ex) -> Optional[dict]:
+    def _dense_init(self, ex, scatter_ok: bool = True) -> Optional[dict]:
+        """The accumulator for this stream's key space, or None (the
+        general path).  `scatter_ok` False keeps to the masked-sum layout
+        (a shard's partial state is merged field by field)."""
         if os.environ.get("MO_DENSE_GROUPS") == "0":
             return None
         dense_funcs = {"count", "sum", "avg"} | STDDEV_AGGS
@@ -796,32 +834,47 @@ class AggOp(Operator):
             # needs per-group key sets — all take the general path
             if a.distinct or a.func not in dense_funcs:
                 return None
-        sizes = self._dense_sizes(ex)
-        if sizes is None:
+        spaces = self._key_spaces(ex)
+        if spaces is None:
             return None
-        g = 1
-        n_fields = 1
-        for s in sizes:
-            g *= s + 1
-        for a in self.node.aggs:
-            n_fields += len(self._dense_fields(a))
-        if g > int(os.environ.get("MO_DENSE_GROUPS_MAX", "256")) \
-                or g * n_fields > 4096:
+        sizes = tuple(s for s, _lo, _hi in spaces)
+        los = tuple(lo for _s, lo, _hi in spaces)
+        _strides, g = A.dense_slot_strides(sizes)
+        fields = [cf for a in self.node.aggs for cf in self._dense_fields(a)]
+        n_fields = 1 + len(fields)
+        if all(lo is None for lo in los) and g * n_fields <= 4096 \
+                and g <= int(os.environ.get("MO_DENSE_GROUPS_MAX", "256")):
             # the masked-sum family unrolls G x fields reductions at
-            # trace time — cap the XLA graph size
-            return None
-        # accumulators live at FULL (NULL-slotted) granularity; all-valid
-        # chunks compute in the compact key space and scatter into the
-        # matching full slots
-        partials = []
-        for a in self.node.aggs:
-            partials.append({f: jnp.zeros((g,), jnp.int64 if c == "int"
-                                          else jnp.float64)
-                             for c, f in self._dense_fields(a)})
-        return {"sizes": tuple(sizes), "partials": partials,
-                "rows": jnp.zeros((g,), jnp.int64)}
+            # trace time, hence the cap on the XLA graph size; its
+            # accumulators live at FULL (NULL-slotted) granularity;
+            # all-valid chunks compute in the compact key space and
+            # scatter into the matching full slots
+            partials = []
+            for a in self.node.aggs:
+                partials.append({f: jnp.zeros((g,), jnp.int64 if c == "int"
+                                              else jnp.float64)
+                                 for c, f in self._dense_fields(a)})
+            return {"sizes": sizes, "los": los, "partials": partials,
+                    "rows": jnp.zeros((g,), jnp.int64)}
+        if not scatter_ok or g * n_fields > self.WIDE_SLOTS_MAX \
+                or any(c != "int" for c, _f in fields):
+            return None                  # one int64 accumulator holds all
+        return {"sizes": sizes, "los": los,
+                "los_dev": jnp.asarray(np.asarray(
+                    [lo or 0 for lo in los], np.int64)),
+                "acc": jnp.zeros((g, n_fields), jnp.int64),
+                "outside": jnp.zeros((), jnp.bool_)}
 
     def _dense_step(self, dense, kdata, kvalid, mask, values) -> None:
+        if "acc" in dense:               # many slots: the scatter fold
+            fields, _at, _n = self._wide_fields()
+            dense["acc"], dense["outside"] = _wide_fold(
+                tuple(kdata), tuple(kvalid), mask,
+                tuple(None if v is None else v.data for v in values),
+                tuple(None if v is None else v.validity for v in values),
+                dense["los_dev"], dense["acc"], dense["outside"],
+                sizes=dense["sizes"], fields=fields)
+            return
         # ONE fused host sync answers every 'no NULLs here?' question for
         # the chunk: all-valid keys shrink the key space (no NULL slots)
         # and all-valid agg args collapse their count field into the
@@ -916,7 +969,10 @@ class AggOp(Operator):
         """Dense accumulator -> the standard state dict. `present` is
         scattered over the G slots (not front-packed); every consumer —
         _merge's re-group, _finalize's output mask, the session's
-        mask-compacting _to_host — works off the mask, so that's fine."""
+        mask-compacting _to_host — works off the mask, so that's fine.
+        (The scatter fold's many slots are front-packed instead.)"""
+        if "acc" in dense:
+            return self._wide_to_state(dense)
         sizes = dense["sizes"]
         strides, g = A.dense_slot_strides(sizes)
         present = dense["rows"] > 0
@@ -932,6 +988,54 @@ class AggOp(Operator):
         return {"keys": keys, "kvalid": kvalid, "present": present,
                 "partials": [dict(p) for p in dense["partials"]],
                 "n": n}
+
+    def _wide_fields(self):
+        """Per aggregate its fields' names, and the column of each in the
+        accumulator (the row count takes the last)."""
+        fields = tuple(tuple(f for _c, f in self._dense_fields(a))
+                       for a in self.node.aggs)
+        at, col = [], 0
+        for fs in fields:
+            at.append({f: col + i for i, f in enumerate(fs)})
+            col += len(fs)
+        return fields, at, col
+
+    #: fewest lanes of the state the wide path hands on: how many groups a
+    #: statement's constants leave must not be a new shape downstream
+    WIDE_STATE_LANES = 4096
+
+    def _wide_to_state(self, wide) -> dict:
+        """The filled slots as the standard state dict, front-packed: the
+        slots' row counts come to the host (the path's one wait), the few
+        filled ones are gathered."""
+        from matrixone_tpu.container.device import bucket_length
+        from matrixone_tpu.utils import metrics as M
+        rows, outside = jax.device_get((_wide_rows(wide["acc"]),
+                                        wide["outside"]))
+        M.device_wait.inc(site="agg_slots")
+        if bool(outside):
+            raise EvalError("grouped aggregate: a group key lies outside "
+                            "the range its producer observed")
+        filled = np.flatnonzero(np.asarray(rows) > 0)
+        n = len(filled)
+        cap = max(self.WIDE_STATE_LANES, bucket_length(n))
+        slot = np.zeros(cap, np.int64)
+        slot[:n] = filled
+        strides, _g = A.dense_slot_strides(wide["sizes"])
+        keys, kvalid = [], []
+        for k, size, lo, st in zip(self.node.group_keys, wide["sizes"],
+                                   wide["los"], strides):
+            code = (slot // st) % (size + 1)
+            kvalid.append(jnp.asarray(code < size))
+            keys.append(jnp.asarray((code + (lo or 0)).astype(
+                np.int32 if k.dtype.is_varlen else k.dtype.np_dtype)))
+        got = _wide_take(wide["acc"], slot.astype(np.int32))
+        _fields, at, _n = self._wide_fields()
+        partials = [{f: got[col] for f, col in cols.items()}
+                    for cols in at]
+        present = jnp.asarray(np.arange(cap) < n)
+        return {"keys": keys, "kvalid": kvalid, "present": present,
+                "partials": partials, "n": jnp.asarray(n, jnp.int32)}
 
     def _revive_values(self, vals):
         """Spilled (data, validity) np pairs -> DeviceColumns (dtype is
@@ -1051,9 +1155,9 @@ class AggOp(Operator):
                       else _agg_value(a, ex) for a in self.node.aggs]
             if not dense_checked:
                 dense_checked = True
-                dense = self._dense_init(ex)
+                dense = self._dense_init(ex, scatter_ok=False)
             if dense is not None:
-                if self._dense_sizes(ex) == list(dense["sizes"]):
+                if self._dense_fits(dense, ex):
                     self._dense_step(dense, kdata, kvalid, ex.mask,
                                      values)
                     continue
@@ -1089,6 +1193,105 @@ def _broadcast_full(col: DeviceColumn, n: int) -> DeviceColumn:
 
 
 # agg kernels: per-batch partial, merge, finalize -------------------------
+
+#: the wide dense fold packs a batch's live rows into lanes / this many
+#: lanes before it scatters them, when they fit (a star join leaves 1-3%
+#: of its lanes alive, and a scatter-add costs the chip by the lane, 47 ns:
+#: packed, the fold fell from 2.1 to 0.45 s of a 5 s slice; 16 itself was
+#: not swept)
+_WIDE_PACK = 16
+_PACK_ROW = 1024
+
+
+def _live_lanes(mask, cap: int):
+    """Where the first `cap` live lanes of `mask` are, by gathers alone:
+    -> (src [cap] lane numbers, n_live).  Lanes are taken as rows of
+    `_PACK_ROW`: a running count inside each row and over the rows'
+    totals, then for every output position a binary search for its row
+    and one inside the row.  No sort, no scatter, and no scan longer than
+    a row (the chip's compiler takes half a minute over a scan of 2^20)."""
+    rows = mask.shape[0] // _PACK_ROW
+    within = jnp.cumsum(mask.reshape(rows, _PACK_ROW).astype(jnp.int32),
+                        axis=1)
+    row_tot = within[:, -1]
+    row_end = jnp.cumsum(row_tot)
+    j = jnp.arange(cap, dtype=jnp.int32)
+    r = jnp.minimum(jnp.searchsorted(row_end, j, side="right"),
+                    rows - 1).astype(jnp.int32)
+    k = j - (row_end[r] - row_tot[r])          # rank inside the row
+    flat = within.reshape(-1)
+    lo = jnp.zeros((cap,), jnp.int32)
+    hi = jnp.full((cap,), _PACK_ROW - 1, jnp.int32)
+    for _ in range(_PACK_ROW.bit_length() - 1):
+        mid = (lo + hi) // 2
+        right = flat[r * _PACK_ROW + mid] <= k
+        lo = jnp.where(right, mid + 1, lo)
+        hi = jnp.where(right, hi, mid)
+    return r * _PACK_ROW + jnp.minimum(lo, _PACK_ROW - 1), row_end[-1]
+
+
+@jax.jit
+def _wide_rows(acc):
+    """The row count of every slot of the wide dense accumulator."""
+    return acc[:, -1]
+
+
+@jax.jit
+def _wide_take(acc, slots):
+    """The accumulator's columns at `slots`, one array a column."""
+    got = acc[slots]
+    return tuple(got[:, i] for i in range(acc.shape[1]))
+
+
+@functools.partial(jax.jit, static_argnames=("sizes", "fields"),
+                   donate_argnames=("acc",))
+def _wide_fold(kdata, kvalid, mask, vdata, vvalid, los, acc, outside, *,
+               sizes, fields):
+    """One batch folded into the wide dense accumulator
+    (`AggOp._wide_step`): `acc` is int64 [slots, fields + 1], every
+    aggregate's fields side by side and the row count last.  The mixed-
+    radix slot of every live row, then ONE scatter-add of all fields.  A
+    NULL key takes its key's extra slot; a masked row takes no slot.  A
+    batch whose live rows fit lanes / `_WIDE_PACK` is packed first
+    (`_live_lanes`) and only the packed lanes are scattered.  `outside`
+    remembers a valid key outside its code space (the producer's range
+    was wrong): the caller refuses the sums."""
+    strides, g = A.dense_slot_strides(sizes)
+    slot = jnp.zeros(mask.shape, jnp.int32)
+    for data, valid, lo, size, st in zip(kdata, kvalid, los, sizes,
+                                         strides):
+        code = data.astype(jnp.int64) - lo
+        outside = outside | jnp.any(mask & valid
+                                    & ((code < 0) | (code >= size)))
+        code = jnp.where(valid, jnp.clip(code, 0, size - 1), size)
+        slot = slot + code.astype(jnp.int32) * st
+    slot = jnp.where(mask, slot, g)                # dropped by the scatter
+    cols = []
+    for fs, data, valid in zip(fields, vdata, vvalid):
+        live = mask if valid is None else mask & valid
+        for f in fs:
+            cols.append(live.astype(jnp.int64) if f == "count"
+                        else jnp.where(live, data, 0).astype(jnp.int64))
+    cols.append(mask.astype(jnp.int64))
+    updates = jnp.stack(cols, axis=1)              # [lanes, fields + 1]
+
+    def scatter_all(acc):
+        return acc.at[slot].add(updates, mode="drop")
+
+    lanes = mask.shape[0]
+    if lanes % _PACK_ROW or lanes < (_PACK_ROW << 6):
+        return scatter_all(acc), outside
+    cap = lanes // _WIDE_PACK
+    src, n_live = _live_lanes(mask, cap)
+
+    def scatter_packed(acc):
+        at = jnp.where(jnp.arange(cap, dtype=jnp.int32) < n_live,
+                       slot[src], g)
+        return acc.at[at].add(updates[src], mode="drop")
+
+    return jax.lax.cond(n_live <= cap, scatter_packed, scatter_all,
+                        acc), outside
+
 
 def _agg_value(a: AggCall, ex: ExecBatch):
     if a.func in ("min", "max") and a.arg.dtype.is_varlen:

@@ -112,13 +112,121 @@ def q3_steps():
                                      "frag_join_stream_step"])
 def test_q3_fused_join_programs_hold_no_custom_call(one_chip, q3_steps,
                                                     program):
-    """Q3's fused build and probe steps (the probe enters the sorted
-    hash run through `jnp.searchsorted`), lowered for the described
-    v5e: they compile, and no hand-written kernel is in them."""
+    """Q3's fused build and probe steps (both builds are unique on an
+    integer key: a direct-address table, one gather a probe row),
+    lowered for the described v5e: they compile, and no hand-written
+    kernel is in them."""
     assert q3_steps.get(program), f"Q3 traced no {program}"
     for fn, compiled in q3_steps[program]:
         _specs, lowered = _lowered_for(one_chip, fn, compiled)
         assert "tpu_custom_call" not in lowered.as_text()
+
+
+@pytest.fixture(scope="module")
+def ssb_q41_steps():
+    """name -> [(step function, its CPU compile)] of the fused programs
+    SSB Q4.1 (four joins under a grouped aggregate) traces."""
+    from matrixone_tpu.frontend.session import Session
+    from matrixone_tpu.utils import ssb
+    from matrixone_tpu.vm import fusion as FF
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(HK, "platform", lambda: "tpu")
+        mp.setenv("MO_FUSION_MIN_ROWS", "0")
+        s = Session()
+        try:
+            s.execute("set batch_rows = 8192")
+            ssb.load_ssb(s.catalog, ssb.gen_ssb(0.005, 2))
+            FF.CACHE.clear()
+            assert s.execute(ssb.render("q4.1", ssb.PAPER_PARAMS["q4.1"])
+                             ).rows()
+        finally:
+            s.close()
+    steps = {}
+    for e in FF.CACHE._lru.snapshot():
+        for slot, compiled in e["compiled"].items():
+            steps.setdefault(e["fn"][slot].__name__, []).append(
+                (e["fn"][slot], compiled))
+    return steps
+
+
+def _rank3_index_gathers(text):
+    """Gathers of a lowered program whose index is [rows, lanes, 1]."""
+    import re
+    return re.findall(r"stablehlo\.gather.*tensor<\d+x\d+x1xi32>", text)
+
+
+@pytest.mark.parametrize("statement", ["tpch q3", "ssb q4.1"])
+def test_probe_steps_are_small_and_gather_through_flat_indexes(
+        one_chip, q3_steps, ssb_q41_steps, statement):
+    """What made a probe step compile for 132-138 s on the chip (PERF.md
+    section 6, PR 33), pinned by structure and not by the clock: no gather
+    through a [rows, lanes] index, no tensor with a minor dimension of 4
+    lanes, and a lowered step of a few hundred lines.  The steps also
+    compile for the described v5e, 16 times wider than the test traced
+    them."""
+    steps = q3_steps if statement == "tpch q3" else ssb_q41_steps
+    probes = steps.get("frag_join_stream_step", [])
+    assert len(probes) >= (2 if statement == "tpch q3" else 4)
+    for fn, compiled in probes:
+        args, _kwargs = compiled.args_info
+        wide = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                tuple(d * 16 if d >= 1024 else d for d in a.shape),
+                a.dtype, sharding=one_chip), args)
+        lowered = jax.jit(fn).lower(*wide)
+        text = lowered.as_text()
+        assert not _rank3_index_gathers(text)
+        assert "x4xi" not in text and "x4xui" not in text
+        assert text.count("\n") < 400
+        lowered.compile()
+
+
+def test_a_four_lane_probe_lowers_lane_major(one_chip):
+    """A build with duplicates still expands four lanes a probe row: the
+    lanes are concatenated ([4, rows], lane-major), never interleaved, so
+    no array of the lowered probe has 4 as its minor dimension."""
+    from matrixone_tpu.container import dtypes as dt
+    from matrixone_tpu.container.device import DeviceBatch, DeviceColumn
+    from matrixone_tpu.sql import plan as P
+    from matrixone_tpu.sql.expr import BoundCol
+    from matrixone_tpu.vm import join as J
+    from matrixone_tpu.vm.exprs import ExecBatch
+    rows, build_rows = 1 << 17, 1 << 14
+    left = P.Scan("l", ["k", "v"], [("l.k", dt.INT64), ("l.v", dt.INT64)])
+    right = P.Scan("r", ["k", "w"], [("r.k", dt.INT64), ("r.w", dt.INT64)])
+    node = P.Join("left", left, right, [BoundCol("l.k", dt.INT64)],
+                  [BoundCol("r.k", dt.INT64)], None,
+                  left.schema + right.schema)
+
+    def batch(names, k, v, mask):
+        valid = jnp.ones(k.shape, jnp.bool_)
+        cols = {names[0]: DeviceColumn(k, valid, dt.INT64),
+                names[1]: DeviceColumn(v, valid, dt.INT64)}
+        return ExecBatch(batch=DeviceBatch(columns=cols,
+                                           n_rows=jnp.sum(mask)),
+                         dicts={}, mask=mask)
+
+    def probe(pk, pv, pmask, bk, bw, bmask):
+        pex = batch(("l.k", "l.v"), pk, pv, pmask)
+        build = batch(("r.k", "r.w"), bk, bw, bmask)
+        bkeys, _ = J.build_key_columns(node, build)
+        sorted_hash, order, _bv = J.build_sorted_hash(bkeys, build.mask)
+        pkeys = J.probe_key_columns(node, pex, [None])
+        phash, pvalid = J.hash_valid_keys(pkeys, pex.mask)
+        out, overflow, _ = J.expand_probe(node, pex, build, sorted_hash,
+                                          order, phash, pvalid, pkeys,
+                                          bkeys, 4, None)
+        return out.mask, out.batch.columns["r.w"].data, overflow
+
+    spec = _spec(one_chip)
+    text = jax.jit(probe).lower(
+        spec((rows,), jnp.int64), spec((rows,), jnp.int64),
+        spec((rows,), jnp.bool_), spec((build_rows,), jnp.int64),
+        spec((build_rows,), jnp.int64), spec((build_rows,), jnp.bool_)
+    ).as_text()
+    assert f"tensor<{4 * rows}xi64>" in text       # four lanes a row
+    assert not _rank3_index_gathers(text)
+    assert "x4xi" not in text and "x4xui" not in text
 
 
 def test_fused_q1_step_compiles_for_v5e(one_chip, monkeypatch):

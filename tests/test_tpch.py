@@ -75,19 +75,6 @@ def test_q1_streaming_multi_batch():
     assert sorted(map(repr, small)) == sorted(map(repr, big))
 
 
-def test_ssb_q1x_exact():
-    s = Session()
-    lo, dates = tpch.load_ssb(s.catalog, 30_000, seed=5)
-    for q, sql in (("q11", tpch.SSB_Q11), ("q12", tpch.SSB_Q12),
-                   ("q13", tpch.SSB_Q13)):
-        got = s.execute(sql).rows()[0][0]
-        expect = tpch.ssb_q1_oracle(lo, dates, q)
-        if expect == 0:
-            assert got is None or got == 0, (q, got)
-        else:
-            assert got == expect, (q, got, expect)
-
-
 def test_q3_exact():
     import datetime
     s = Session()
