@@ -194,8 +194,11 @@ def prune_columns(node: P.PlanNode) -> P.PlanNode:
     that computes its output (Project, Aggregate, UdfAggregate) asks its
     child for exactly what its own expressions reference; a node that
     passes rows through (Filter, Sort, TopK, Limit, Sample, Window, Join)
-    adds its own references to its parent's.  Expressions are never
-    copied: the plan cache patches tagged parameter literals in place."""
+    adds its own references to its parent's.  A Join (not semi/anti, whose
+    output is its probe side) asks its inputs for that sum and hands up
+    only what its parent reads: its `schema` is what the probe gathers
+    (vm/join.output_schema).  Expressions are never copied: the plan cache
+    patches tagged parameter literals in place."""
     _prune(node, None)
     return node
 
@@ -250,6 +253,16 @@ def _prune(node: P.PlanNode, needed: Optional[set]) -> None:
         kept = {n for c in inputs for n, _ in c.schema}
         if isinstance(node, P.Window):
             kept |= {e[5] for e in node.entries}
+        if isinstance(node, P.Join) and not semi and needed is not None:
+            # a join computes its output (a gather a build column, a copy
+            # a probe column), so it hands up only what is read above it:
+            # its keys and residual are read inside, from its inputs
+            kept &= needed
+            if not kept:
+                # nothing is read from the rows (count(*)): they are still
+                # counted, through the probe column that costs least, and
+                # never through a build column, which would cost a gather
+                kept = {min(node.left.schema, key=_row_cost)[0]}
         node.schema = [(n, d) for n, d in node.schema if n in kept]
     else:
         # Distinct, Fill, Union, and anything this pass does not know:
@@ -275,17 +288,18 @@ def _narrow_source(node, needed: Optional[set]) -> None:
     if not pairs:
         # nothing is read from the rows (count(*)): the scan still has to
         # count them, through the column that costs the fewest bytes a row
-        pairs = [min(zip(node.columns, node.schema), key=_row_cost)]
+        pairs = [min(zip(node.columns, node.schema),
+                     key=lambda pair: _row_cost(pair[1]))]
     node.columns = [c for c, _ in pairs]
     node.schema = [s for _, s in pairs]
 
 
-def _row_cost(column_and_schema_entry) -> tuple:
-    """Order of the one column a scan keeps when none is needed: fixed
-    width before varlen and vector columns, then bytes a row; `min` keeps
-    the first of equals, so ties go by schema order and the plan cache and
-    the peers of a distributed scan agree."""
-    d = column_and_schema_entry[1][1]
+def _row_cost(schema_entry) -> tuple:
+    """Order of the one column a scan or a join keeps when none is needed:
+    fixed width before varlen and vector columns, then bytes a row; `min`
+    keeps the first of equals, so ties go by schema order and the plan
+    cache and the peers of a distributed scan agree."""
+    d = schema_entry[1]
     if d.is_varlen:
         return (1, 4)                      # dictionary codes
     width = d.np_dtype.itemsize * max(d.dim, 1)

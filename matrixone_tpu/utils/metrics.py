@@ -273,6 +273,13 @@ join_probe_retries = REGISTRY.counter(
 join_build_rows = REGISTRY.counter(
     "mo_join_build_rows_total",
     "live rows of the build sides the fused join finalized")
+join_build_columns = REGISTRY.counter(
+    "mo_join_build_columns_total",
+    "columns of executed joins' build sides by outcome, once per join: "
+    "gathered (those the probe gathers a lane: the join's output, which "
+    "sql/optimize.prune_columns narrows to what is read above it, and "
+    "what its residual reads), pruned (the build side's other columns: "
+    "keys and filter-only columns, which cost the probe nothing)")
 blockcache_ops = REGISTRY.counter(
     "mo_blockcache_ops_total", "decoded-column cache lookups by outcome")
 blockcache_bytes = REGISTRY.counter(

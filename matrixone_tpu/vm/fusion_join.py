@@ -233,7 +233,10 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                  if node.residual is not None else None,
                  tuple((nm, FF._tsig(t)) for nm, t in node.left.schema),
                  tuple((nm, FF._tsig(t))
-                       for nm, t in node.right.schema))]
+                       for nm, t in node.right.schema),
+                 # what the probe hands up: two statements that read
+                 # other columns above one join are two programs
+                 tuple(nm for nm, _ in J.output_schema(node)))]
 
     def _prelude_labels(self) -> List[str]:
         return ["JoinBuild", "JoinProbe"]
@@ -253,14 +256,11 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
         left join NULL-extends build columns, so they are never
         flaggable there; for semi/anti only probe columns exist."""
         jn = self._join.node
-        colmap = {nm: (frozenset([nm]), True) for nm, _ in jn.left.schema}
-        if jn.kind in ("inner",):
-            colmap.update({nm: (frozenset([nm]), True)
-                           for nm, _ in jn.right.schema})
-        else:
-            colmap.update({nm: (frozenset(), False)
-                           for nm, _ in jn.right.schema})
-        return colmap
+        probe_side = {nm for nm, _ in jn.left.schema}
+        return {nm: ((frozenset([nm]), True)
+                     if nm in probe_side or jn.kind == "inner"
+                     else (frozenset(), False))
+                for nm, _ in J.output_schema(jn)}
 
     def _flag_validities(self, ex):
         """Validity arrays for the flag columns, resolved across the two
@@ -318,15 +318,13 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                 return ([n for n, _ in st.schema],
                         [d for _, d in st.schema])
         # no projection: the stream payload's column ORDER is the
-        # probe-chain construction order — left schema then (for
-        # inner/left) right schema.  NOT jn.schema: after a CBO side
-        # swap the join node's declared order differs from the physical
-        # batch order, and a positional zip against it would hand every
-        # downstream operator the wrong column under each name
-        jn = self._join.node
-        sch = list(jn.left.schema)
-        if jn.kind not in ("semi", "anti"):
-            sch += list(jn.right.schema)
+        # probe-chain construction order — the join's output columns of
+        # the left schema, then (for inner/left) of the right schema.
+        # NOT jn.schema's order: after a CBO side swap the join node's
+        # declared order differs from the physical batch order, and a
+        # positional zip against it would hand every downstream operator
+        # the wrong column under each name
+        sch = J.output_schema(self._join.node)
         return ([n for n, _ in sch], [d for _, d in sch])
 
     def _stream_batch(self, ex, payload, envs, mm) -> ExecBatch:
@@ -383,6 +381,7 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
             self.last_stats["mode"] = "fallback"
             yield from self._orig_join_chain(build_batches, build_iter)
             return
+        J.count_build_columns(node)
         probe_iter = self.child.execute()
         first = next(probe_iter, None)
         if first is None or first.padded_len < FF.min_fused_rows():
@@ -781,6 +780,8 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
                                          self._bkey_dicts))
                     deps["max_matches"] = mm
                     deps["lookup"] = lookup_sig
+                    deps["join_output"] = tuple(
+                        nm for nm, _ in J.output_schema(self._join.node))
                     keyaudit.audit("vm/fusion_join.py:joinprobe", key,
                                    deps)
                 slot = "step"

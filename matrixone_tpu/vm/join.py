@@ -326,19 +326,56 @@ def expand_probe_dense(node, ex: ExecBatch, build: ExecBatch, table, lo,
     return out
 
 
+def _probe_columns(node):
+    """(probe side's, build side's [(name, dtype)], residual-only names) of
+    the columns a probe of `node` builds: those `node.schema` names, which
+    `sql/optimize.prune_columns` narrows to what is read above the join,
+    plus, for the residual's evaluation alone, what the residual reads.
+    By membership, in the PHYSICAL order, never zipped against
+    `node.schema`, whose order is the statement's and not the batch's (a
+    semi/anti join's schema names its probe side, so its build side is
+    built for the residual only)."""
+    from matrixone_tpu.sql.expr import columns_used
+    out = {n for n, _ in node.schema}
+    inside_only = (set(columns_used(node.residual)) - out
+                   if node.residual is not None else set())
+    built = out | inside_only
+    return ([c for c in node.left.schema if c[0] in built],
+            [c for c in node.right.schema if c[0] in built], inside_only)
+
+
+def output_schema(node) -> list:
+    """[(name, dtype)] of what a probe of `node` hands up, probe side then
+    build side: what it builds less what only its residual reads."""
+    left, right, inside_only = _probe_columns(node)
+    return [c for c in left + right if c[0] not in inside_only]
+
+
+def count_build_columns(node) -> None:
+    """`mo_join_build_columns_total`, once a join executed: the build
+    side's columns that a probe gathers and those it leaves alone."""
+    from matrixone_tpu.utils import metrics as M
+    gathered = len(_probe_columns(node)[1])
+    M.join_build_columns.inc(gathered, outcome="gathered")
+    M.join_build_columns.inc(len(node.right.schema) - gathered,
+                             outcome="pruned")
+
+
 def emit_lanes(node, ex: ExecBatch, build: ExecBatch, match, build_idx,
                mm: int, build_matched):
     """The probe's output from its match lanes: `match` [mm*np] says
-    which lane found its key, `build_idx` which build row.  Gathers both
-    sides, applies the residual, NULL-extends for left/full.
-    -> (out ExecBatch, build_matched')."""
+    which lane found its key, `build_idx` which build row.  Copies the
+    probe side's and gathers the build side's columns of the join's
+    output (`output_schema`), applies the residual, NULL-extends for
+    left/full.  -> (out ExecBatch, build_matched')."""
     np_ = ex.padded_len
+    left, right, inside_only = _probe_columns(node)
     cols = {}
-    for name, _ in node.left.schema:
+    for name, _ in left:
         c = _broadcast_full(ex.batch.columns[name], np_)
         cols[name] = DeviceColumn(_lanes(c.data, mm),
                                   _lanes(c.validity, mm), c.dtype)
-    for name, _ in node.right.schema:
+    for name, _ in right:
         c = _broadcast_full(build.batch.columns[name], build.padded_len)
         validity = c.validity[build_idx] & match
         cols[name] = DeviceColumn(c.data[build_idx], validity, c.dtype)
@@ -351,6 +388,8 @@ def emit_lanes(node, ex: ExecBatch, build: ExecBatch, match, build_idx,
     if node.residual is not None:
         pred = eval_expr(node.residual, out)
         out.mask = out.mask & F.predicate_mask(pred, db)
+        for name in inside_only:        # read inside, not handed up
+            del cols[name]
     if node.kind == "full":
         # record which build rows matched (post-residual, pre-null-
         # extension) — monotonic across overflow re-runs
@@ -361,10 +400,11 @@ def emit_lanes(node, ex: ExecBatch, build: ExecBatch, match, build_idx,
             [ex.mask & ~matched_any]
             + [jnp.zeros((np_,), jnp.bool_)] * (mm - 1))
         # null-extended lanes: right-side columns must read as NULL
-        for name, _ in node.right.schema:
-            c = out.batch.columns[name]
-            out.batch.columns[name] = DeviceColumn(
-                c.data, c.validity & ~null_emit, c.dtype)
+        for name, _ in right:
+            if name in cols:
+                c = cols[name]
+                cols[name] = DeviceColumn(c.data, c.validity & ~null_emit,
+                                          c.dtype)
         out.mask = out.mask | null_emit
     out.batch.n_rows = jnp.sum(out.mask.astype(jnp.int32))
     return out, build_matched
@@ -474,6 +514,15 @@ class _ReplayOp(Operator):
             yield ExecBatch(batch=db, dicts=dicts, mask=db.row_mask())
 
 
+def _null_column(dtype, lanes: int) -> DeviceColumn:
+    """An all-NULL column of `lanes` lanes (the other side of an outer
+    join's unmatched rows)."""
+    jt = jnp.int32 if dtype.is_varlen else dtype.jnp_dtype
+    shape = (lanes, dtype.dim) if dtype.is_vector else (lanes,)
+    return DeviceColumn(jnp.zeros(shape, jt), jnp.zeros((lanes,), jnp.bool_),
+                        dtype)
+
+
 class JoinOp(Operator):
     #: build rows beyond which the join Grace-spills both sides
     DEFAULT_BUILD_BUDGET = 1 << 22
@@ -493,12 +542,18 @@ class JoinOp(Operator):
         #: pushed the runtime filters; consumed (and cleared) by the
         #: next execute() iff the build batch is the very same object
         self._prepared_build = None
+        #: False on the grace path's partition joins: the join they are
+        #: parts of has counted `mo_join_build_columns_total`
+        self.counts_columns = True
         self.build_budget = self.DEFAULT_BUILD_BUDGET
         if ctx is not None and ctx.variables:
             self.build_budget = int(ctx.variables.get(
                 "join_build_budget", self.build_budget))
 
     def execute(self) -> Iterator[ExecBatch]:
+        if self.counts_columns and self._prepared_build is None:
+            # (a fused fragment that hands its build over has counted)
+            count_build_columns(self.node)
         # stream the build side counting live rows; past the budget,
         # switch to the Grace path (cross joins have no key to partition
         # by — they stay in-memory whatever the size)
@@ -556,22 +611,20 @@ class JoinOp(Operator):
             unmatched = build.mask & ~self._build_matched
             nb = build.padded_len
             cols = {}
-            for name, dtype in self.node.left.schema:
-                jt = jnp.int32 if dtype.is_varlen else dtype.jnp_dtype
-                shape = (nb, dtype.dim) if dtype.is_vector else (nb,)
-                cols[name] = DeviceColumn(jnp.zeros(shape, jt),
-                                          jnp.zeros((nb,), jnp.bool_), dtype)
-            for name, _ in self.node.right.schema:
-                c = _broadcast_full(build.batch.columns[name], nb)
-                cols[name] = DeviceColumn(c.data, c.validity, c.dtype)
-            db = DeviceBatch(columns=cols,
-                             n_rows=jnp.sum(unmatched.astype(jnp.int32)))
+            probe_side = {n for n, _ in self.node.left.schema}
             # probe-side varchar columns are all-NULL here but expressions
             # above the join still resolve them through their dictionary
             dicts = {**self._probe_dicts, **build.dicts}
-            for name, dtype in self.node.left.schema:
-                if dtype.is_varlen:
-                    dicts.setdefault(name, [""])
+            for name, dtype in output_schema(self.node):
+                if name in probe_side:
+                    cols[name] = _null_column(dtype, nb)
+                    if dtype.is_varlen:
+                        dicts.setdefault(name, [""])
+                else:
+                    cols[name] = _broadcast_full(build.batch.columns[name],
+                                                 nb)
+            db = DeviceBatch(columns=cols,
+                             n_rows=jnp.sum(unmatched.astype(jnp.int32)))
             yield ExecBatch(batch=db, dicts=dicts, mask=unmatched)
 
     # ------------------------------------------------------------- grace
@@ -603,6 +656,7 @@ class JoinOp(Operator):
                 # partition past the budget would recurse on identical
                 # hashes forever, so partitions never re-spill
                 sub.build_budget = 1 << 62
+                sub.counts_columns = False
                 yield from sub.execute()
         finally:
             spill.cleanup()
@@ -712,14 +766,10 @@ class JoinOp(Operator):
 
     def _null_extend_all(self, ex: ExecBatch) -> ExecBatch:
         np_ = ex.padded_len
-        cols = {}
-        for name, _ in self.node.left.schema:
-            cols[name] = _broadcast_full(ex.batch.columns[name], np_)
-        for name, dtype in self.node.right.schema:
-            jt = jnp.int32 if dtype.is_varlen else dtype.jnp_dtype
-            shape = (np_, dtype.dim) if dtype.is_vector else (np_,)
-            cols[name] = DeviceColumn(jnp.zeros(shape, jt),
-                                      jnp.zeros((np_,), jnp.bool_), dtype)
+        probe_side = {n for n, _ in self.node.left.schema}
+        cols = {name: (_broadcast_full(ex.batch.columns[name], np_)
+                       if name in probe_side else _null_column(dtype, np_))
+                for name, dtype in output_schema(self.node)}
         db = DeviceBatch(columns=cols, n_rows=ex.batch.n_rows)
         return ExecBatch(batch=db, dicts=dict(ex.dicts), mask=ex.mask)
 
@@ -732,12 +782,13 @@ class JoinOp(Operator):
             probe_idx = jnp.repeat(jnp.arange(np_, dtype=jnp.int32), nb)
             build_idx = jnp.tile(jnp.arange(nb, dtype=jnp.int32), (np_,))
             emit = jnp.repeat(ex.mask, nb) & jnp.tile(build.mask, (np_,))
+            left, right, inside_only = _probe_columns(self.node)
             cols = {}
-            for name, _ in self.node.left.schema:
+            for name, _ in left:
                 c = _broadcast_full(ex.batch.columns[name], np_)
                 cols[name] = DeviceColumn(c.data[probe_idx],
                                           c.validity[probe_idx], c.dtype)
-            for name, _ in self.node.right.schema:
+            for name, _ in right:
                 c = _broadcast_full(build.batch.columns[name], nb)
                 cols[name] = DeviceColumn(c.data[build_idx],
                                           c.validity[build_idx], c.dtype)
@@ -748,4 +799,6 @@ class JoinOp(Operator):
             if self.node.residual is not None:
                 pred = eval_expr(self.node.residual, out)
                 out.mask = out.mask & F.predicate_mask(pred, db)
+                for name in inside_only:
+                    del cols[name]
             yield _maybe_compact(out)

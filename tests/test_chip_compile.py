@@ -181,6 +181,79 @@ def test_probe_steps_are_small_and_gather_through_flat_indexes(
         lowered.compile()
 
 
+@pytest.fixture(scope="module")
+def ssb_probe_levels():
+    """template -> {dimension table: (probe step, its CPU compile)} of the
+    stream steps Q2.1 and Q4.1 trace, a level named by the table its
+    build side scans (asked of the operator that keys the program)."""
+    from matrixone_tpu.frontend.session import Session
+    from matrixone_tpu.utils import ssb
+    from matrixone_tpu.vm import fusion as FF
+    from matrixone_tpu.vm import fusion_join as FJ
+    levels = {}
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(HK, "platform", lambda: "tpu")
+        mp.setenv("MO_FUSION_MIN_ROWS", "0")
+        keyed = {}                               # build table -> program key
+        runtime_key = FJ.FusedJoinProbeOp._probe_runtime_key
+
+        def noting_the_table(op, *args, **kwargs):
+            key = runtime_key(op, *args, **kwargs)
+            keyed[FJ._scanned_table(op._join.node.right)] = key
+            return key
+        mp.setattr(FJ.FusedJoinProbeOp, "_probe_runtime_key",
+                   noting_the_table)
+        s = Session()
+        try:
+            s.execute("set batch_rows = 8192")
+            ssb.load_ssb(s.catalog, ssb.gen_ssb(0.005, 2))
+            for template in ("q2.1", "q4.1"):
+                FF.CACHE.clear()
+                keyed.clear()
+                assert s.execute(ssb.render(
+                    template, ssb.PAPER_PARAMS[template])).rows()
+                levels[template] = {
+                    table: (e["fn"]["step"], e["compiled"]["step"])
+                    for table, e in ((t, FF.CACHE.entry(k))
+                                     for t, k in keyed.items())
+                    if "step" in e["compiled"]}
+        finally:
+            s.close()
+    return levels
+
+
+def _probe_length_gathers(fn, compiled, one_chip):
+    """Gathers of the step lowered for the described chip whose result has
+    as many lanes as the probe batch (the lookup and a build column's data
+    and validity; a gather into a dictionary's few entries is not one)."""
+    import re
+    args, _kwargs = compiled.args_info
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        args)
+    lanes = args[3].shape[0]                      # the probe batch's mask
+    lowered = jax.jit(fn).lower(*specs)
+    return len(re.findall(rf"stablehlo\.gather.*-> tensor<{lanes}x[^>]*>",
+                          lowered.as_text())), lowered
+
+
+@pytest.mark.parametrize("template,table,gathers", [
+    ("q2.1", "part", 3), ("q2.1", "supplier", 1), ("q2.1", "dates", 3),
+    ("q4.1", "supplier", 1), ("q4.1", "customer", 3), ("q4.1", "part", 1),
+    ("q4.1", "dates", 3)])
+def test_a_probe_step_gathers_only_what_is_read_above_the_join(
+        one_chip, ssb_probe_levels, template, table, gathers):
+    """The lookup is one gather at probe length, and every build column
+    the join hands up is two more (data, validity): a level whose build
+    only filters (Q2.1's supplier) holds ONE, Q4.1's customer level
+    (`c_nation`) three, where all of the build side's columns were
+    gathered before (PERF.md section 6, PR 34)."""
+    fn, compiled = ssb_probe_levels[template][table]
+    n, lowered = _probe_length_gathers(fn, compiled, one_chip)
+    assert n == gathers, lowered.as_text()
+    lowered.compile()
+
+
 def test_a_four_lane_probe_lowers_lane_major(one_chip):
     """A build with duplicates still expands four lanes a probe row: the
     lanes are concatenated ([4, rows], lane-major), never interleaved, so

@@ -235,10 +235,13 @@ def test_schemas_consistent_after_pruning(tpch):
                 assert all(q.endswith("." + c) for (q, _), c in
                            zip(node.schema, node.columns))
             elif isinstance(node, P.Join):
+                # (a join hands up what is read above it: PR 34)
                 below = [n for n, _ in node.left.schema]
                 if node.kind not in ("semi", "anti"):
                     below += [n for n, _ in node.right.schema]
-                assert sorted(n for n, _ in node.schema) == sorted(below)
+                names = [n for n, _ in node.schema]
+                assert names and set(names) <= set(below)
+                assert len(set(names)) == len(names)
             elif isinstance(node, (P.Filter, P.Sort, P.TopK, P.Limit)):
                 # (by name: a CBO reorder leaves the pass-through schemas
                 # above it in their bound order)
