@@ -30,7 +30,7 @@ from typing import Dict, List, Optional, Set
 import numpy as np
 
 from matrixone_tpu.container.dtypes import VARCHAR
-from matrixone_tpu.storage.engine import TableMeta
+from matrixone_tpu.storage.engine import TableMeta, live_rows
 
 SYS_ACCOUNT = "sys"
 ADMIN_ROLE = "accountadmin"
@@ -124,8 +124,8 @@ class AccountManager:
         t = self.engine.get_table(table)
         cols = [c for c, _ in t.meta.schema]
         out: List[dict] = []
-        for arrays, validity, dicts, n in t.iter_chunks(
-                cols + ["__rowid"], 1 << 20):
+        for arrays, validity, dicts, n in map(live_rows, t.iter_chunks(
+                cols + ["__rowid"], 1 << 20)):
             decoded = {}
             for c in cols:
                 d = dicts.get(c, [])

@@ -389,8 +389,13 @@ class Session:
         if isinstance(stmt, ast.Insert):
             return self._insert(stmt)
         if isinstance(stmt, ast.Explain):
+            if isinstance(stmt.stmt, ast.Delete):
+                # the plan that finds a DELETE's victims
+                return Result(text=P.explain(self._dml_plan(
+                    stmt.stmt.table, stmt.stmt.where)[0]))
             if not isinstance(stmt.stmt, (ast.Select, ast.Union)):
-                raise BindError("EXPLAIN supports SELECT only for now")
+                raise BindError("EXPLAIN supports SELECT and DELETE only "
+                                "for now")
             node = self._plan_select(stmt.stmt)
             if stmt.analyze:
                 return Result(text=self._explain_analyze(node))
@@ -2250,8 +2255,13 @@ class Session:
 
     def _dml_plan(self, table_name: str, where, extra_exprs=None,
                   extra_names=None):
-        """Plan `SELECT __rowid [, extra...] FROM t WHERE ...` for DML."""
+        """Plan `SELECT __rowid [, extra...] FROM t WHERE ...` for DML,
+        its Scan like a SELECT's: the predicate pushed into it (zonemap
+        pruning) and its columns narrowed to what the plan reads, the
+        predicate's and the row id for a DELETE, every column an UPDATE
+        rewrites."""
         from matrixone_tpu.sql.binder import Scope
+        from matrixone_tpu.sql.optimize import prune_columns
         from matrixone_tpu.sql.expr import BoundCol
         table = self.catalog.get_table(table_name)
         scope = Scope()
@@ -2263,8 +2273,11 @@ class Session:
                        for c, d in table.meta.schema] + [(ROWID, dt.INT64)]
         node = P.Scan(table_name, scan_cols, scan_schema)
         if where is not None:
+            # into the Scan's own filters, as a SELECT's: the zonemaps
+            # then leave a keyed DELETE the chunks that can hold its rows
             pred = binder.bind_expr(where, scope)
-            node = P.Filter(node, pred, node.schema)
+            node = binder._pushdown_scan_filters(
+                P.Filter(node, pred, node.schema))
         exprs = [BoundCol(ROWID, dt.INT64)]
         names = [ROWID]
         out_types = [dt.INT64]
@@ -2273,8 +2286,42 @@ class Session:
             exprs.append(b)
             names.append(nm)
             out_types.append(b.dtype)
-        proj = P.Project(node, exprs, list(zip(names, out_types)))
+        proj = prune_columns(P.Project(node, exprs,
+                                       list(zip(names, out_types))))
         return proj, binder, scope
+
+    def _dml_find(self, txn, table: str, run_plan):
+        """The victims of a DELETE / UPDATE: `_plan_and_lock_rows` under
+        the span that times the search for their row ids."""
+        from matrixone_tpu.utils import motrace
+        with motrace.span("dml.find", table=table):
+            return self._plan_and_lock_rows(txn, table, run_plan)
+
+    def _dml_rows(self, proj, ctx):
+        """The rows a DELETE's or UPDATE's plan selects, a host Batch a
+        scan batch that holds any.  They are picked on the host from the
+        batch's mask: a batch is the scan's chunk (2^20 lanes) and holds
+        a handful of victims or none, and `_to_host`'s compaction would
+        be a program of that length (its cumsum alone compiles for 23 to
+        104 s on the chip: PERF.md section 6, PR 35)."""
+        from matrixone_tpu.container.device import DeviceBatch, DeviceColumn
+        for ex in compile_plan(proj, ctx).execute():
+            self._procs.check_killed(self.conn_id)       # KILL during DML
+            rows = np.flatnonzero(np.asarray(jax.device_get(ex.mask)))
+            if len(rows) == 0:
+                continue
+            cols = {}
+            for name, col in ex.batch.columns.items():
+                if not col.is_const:
+                    col = DeviceColumn(
+                        data=np.asarray(jax.device_get(col.data))[rows],
+                        validity=np.asarray(
+                            jax.device_get(col.validity))[rows],
+                        dtype=col.dtype)
+                cols[name] = col
+            yield from_device(
+                DeviceBatch(columns=cols, n_rows=np.int32(len(rows))),
+                ex.dicts, schema=dict(proj.schema))
 
     def _delete(self, stmt: ast.Delete) -> Result:
         self._reject_mview_write(stmt.table)
@@ -2282,15 +2329,11 @@ class Session:
         proj, _, _ = self._dml_plan(stmt.table, stmt.where)
 
         def run_plan(ctx):
-            op = compile_plan(proj, ctx)
-            gids = []
-            for ex in op.execute():
-                self._procs.check_killed(self.conn_id)   # KILL during DML
-                b = self._to_host(ex, proj.schema)
-                gids.extend(b.columns[ROWID].data.tolist())
-            return np.asarray(gids, np.int64), None
+            gids = [b.columns[ROWID].data
+                    for b in self._dml_rows(proj, ctx)]
+            return np.concatenate([np.zeros(0, np.int64), *gids]), None
 
-        gids, _ = self._plan_and_lock_rows(txn, stmt.table, run_plan)
+        gids, _ = self._dml_find(txn, stmt.table, run_plan)
         txn.delete_rows(stmt.table, gids)
         if self.txn is None:
             txn.commit()
@@ -2311,17 +2354,14 @@ class Session:
                                     extra_exprs, extra_names)
 
         def run_plan(ctx):
-            op = compile_plan(proj, ctx)
             gids, new_cols = [], {c: [] for c, _ in schema}
-            for ex in op.execute():
-                self._procs.check_killed(self.conn_id)   # KILL during DML
-                b = self._to_host(ex, proj.schema)
+            for b in self._dml_rows(proj, ctx):
                 gids.extend(b.columns[ROWID].data.tolist())
                 for c, _ in schema:
                     new_cols[c].extend(b.columns[c].to_pylist())
             return np.asarray(gids, np.int64), new_cols
 
-        gids, new_cols = self._plan_and_lock_rows(txn, stmt.table, run_plan)
+        gids, new_cols = self._dml_find(txn, stmt.table, run_plan)
         if len(gids) == 0:
             return Result(affected=0)
         # rows must round-trip through the table's SQL types (e.g. the

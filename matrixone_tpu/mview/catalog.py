@@ -71,9 +71,11 @@ def _table_version(t) -> tuple:
 
 
 def _scan_rows(t) -> List[dict]:
+    from matrixone_tpu.storage.engine import live_rows
     cols = [c for c, _ in _SCHEMA]
     rows: List[dict] = []
-    for arrays, validity, dicts, n in t.iter_chunks(cols, 1 << 16):
+    for arrays, validity, dicts, n in map(
+            live_rows, t.iter_chunks(cols, 1 << 16)):
         for i in range(n):
             row = {}
             for c, d in _SCHEMA:
@@ -128,11 +130,11 @@ def lookup(catalog, name: str) -> Optional[MViewDef]:
 
 def gids_for_name(catalog, name: str) -> np.ndarray:
     """Global row ids of the view's catalog row(s) (DROP path)."""
-    from matrixone_tpu.storage.engine import ROWID
+    from matrixone_tpu.storage.engine import ROWID, live_rows
     t = catalog.get_table(MVIEW_TABLE)
     out = []
-    for arrays, validity, dicts, n in t.iter_chunks([ROWID, "name"],
-                                                    1 << 16):
+    for arrays, validity, dicts, n in map(
+            live_rows, t.iter_chunks([ROWID, "name"], 1 << 16)):
         d = dicts["name"]
         for i in range(n):
             if validity["name"][i] and \

@@ -787,7 +787,7 @@ class MViewService:
         `full` rewrites everything (init / restart rebuild)."""
         if not keys and not full:
             return
-        from matrixone_tpu.storage.engine import ROWID
+        from matrixone_tpu.storage.engine import ROWID, live_rows
         spec = rt.spec
         t = self.engine.get_table(rt.name)
         names = [c for c, _ in t.meta.schema]
@@ -800,8 +800,8 @@ class MViewService:
         sd = dict(t.meta.schema)
         # existing rows for the touched keys (small: the view output)
         gids: List[int] = []
-        for arrays, validity, dicts, n in t.iter_chunks(
-                key_cols + [ROWID], 1 << 20):
+        for arrays, validity, dicts, n in map(live_rows, t.iter_chunks(
+                key_cols + [ROWID], 1 << 20)):
             for i in range(n):
                 key = []
                 for c in key_cols:

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import List
 
 KEYWORDS = {
@@ -35,10 +36,33 @@ class LexError(ValueError):
     pass
 
 
+# What a bulk INSERT is made of, a regex match a token or two: a plain
+# number or a string without quote or backslash inside, when the `,` or `)`
+# that ends it follows; or a bare `(`, `)`, `,`.  Anything else (signs,
+# exponents, escapes, words) goes through the loop below, which these
+# patterns never contradict.
+_BULK = re.compile(
+    r"\s*(?:(?:([0-9]+(?:\.[0-9]+)?)|'([^'\\]*)')\s*([,)])|([(),]))").match
+
+
 def tokenize(sql: str) -> List[Token]:
     out: List[Token] = []
     i, n = 0, len(sql)
     while i < n:
+        m = _BULK(sql, i)
+        if m is not None:
+            num, text, end, op = m.groups()
+            if op is not None:
+                out.append(Token("op", op, m.start(4)))
+            else:
+                if num is not None:
+                    out.append(Token("float" if "." in num else "int",
+                                     num, m.start(1)))
+                else:
+                    out.append(Token("str", text, m.start(2) - 1))
+                out.append(Token("op", end, m.start(3)))
+            i = m.end()
+            continue
         c = sql[i]
         if c.isspace():
             i += 1

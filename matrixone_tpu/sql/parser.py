@@ -982,17 +982,34 @@ class Parser:
             rows = []
             while True:
                 self.expect_op("(")
-                row = [self.expr()]
-                while self.accept_op(","):
-                    row.append(self.expr())
-                self.expect_op(")")
-                rows.append(row)
+                rows.append(self._values_row())
                 if not self.accept_op(","):
                     break
             return ast.Insert(table, columns, rows=rows)
         if self.at_kw("select"):
             return ast.Insert(table, columns, select=self.select())
         raise ParseError("INSERT requires VALUES or SELECT")
+
+    def _values_row(self) -> List[ast.Node]:
+        """The items of one VALUES row, up to and over its `)`.  A bulk
+        INSERT is bare literals: one that the `,` or `)` after it ends is
+        what `primary` would make of it, without the precedence ladder."""
+        toks, row = self.toks, []
+        while True:
+            t = toks[self.i]
+            end = toks[min(self.i + 1, len(toks) - 1)]
+            if t.kind in ("int", "float", "str") and end.kind == "op" \
+                    and end.value in (",", ")"):
+                row.append(ast.Literal(
+                    int(t.value) if t.kind == "int" else t.value, t.kind))
+                self.i += 2
+                if end.value == ")":
+                    return row
+                continue
+            row.append(self.expr())
+            if not self.accept_op(","):
+                self.expect_op(")")
+                return row
 
     def delete(self) -> ast.Node:
         self.expect_kw("delete")

@@ -329,11 +329,22 @@ class MVCCTable:
                 f"{self.meta.name!r}.{c}".replace("key  ", "key "))
         if self._pk_bloom is None:
             self._rebuild_pk_bloom()
-        suspects = new[self._pk_bloom.probe_int64(new)]
+        hit = self._pk_bloom.probe_int64(new)
+        suspects = new[hit]
         if len(suspects) == 0:
             return
+        # a key can only be met in a segment whose range of the key's first
+        # column holds it: keys past every loaded one (an append of new
+        # orders) fetch and hash no column of the segments below them
+        lead = self._pk_col or self._pk_cols[0]
+        lead_vals = None
+        if not dict(self.meta.schema)[lead].is_varlen:
+            lead_vals = np.asarray(arrays[lead], np.int64)[hit]
         dead = self._dead_gids(None, extra_deletes)
         for seg in self.segments:
+            if lead_vals is not None and \
+                    _key_range_excludes(seg, lead, lead_vals):
+                continue
             vals = self.pk_key_values(seg.arrays)
             # vectorized: one alive mask per segment, one membership pass
             gids = np.arange(seg.base_gid, seg.base_gid + seg.n_rows)
@@ -482,8 +493,12 @@ class MVCCTable:
                     extra_deletes: Optional[np.ndarray] = None,
                     only_part: Optional[int] = None
                     ) -> Iterator[tuple]:
-        """Yield (arrays, validity, dicts, n) merging committed segments
-        visible at snapshot_ts with txn-local segments/deletes."""
+        """Yield (arrays, validity, dicts, n, live) merging committed
+        segments visible at snapshot_ts with txn-local segments/deletes.
+        A chunk keeps its sliced length `n` whatever was deleted from it:
+        `live` is None, or a host bool [n] that is False for its dead
+        rows, and belongs in the consumer's row mask (`ScanOp`); a reader
+        that walks rows on the host takes each chunk through `live_rows`."""
         from matrixone_tpu.utils import metrics as M, motrace
         want_rowid = ROWID in columns
         data_cols = [c for c in columns if c != ROWID]
@@ -539,60 +554,52 @@ class MVCCTable:
 
     def _read_chunk(self, seg, start: int, end: int, data_cols,
                     want_rowid: bool, dead_filter, filters, qmap):
-        """One chunk of one segment: column lookups (which fetch, decode
-        and upload what the block cache misses), the slice (`_chunk_columns`:
-        one program for all the device-resident columns of the chunk, a
-        view of a numpy one), the tombstone mask and the chunk's own
-        zonemap check.  An object-backed segment's check reads the chunk
-        as it was sliced, before the tombstone mask: its summary is kept
-        with the object and must not depend on the snapshot (a range over
-        more rows than are visible can only prune less).  -> (arrays,
-        validity, dicts, n), or None when the chunk has nothing to scan."""
+        """One chunk of one segment: the tombstone test of its row ids,
+        column lookups (which fetch, decode and upload what the block
+        cache misses), the slice (`_chunk_columns`: one program for all
+        the device-resident columns of the chunk, a view of a numpy one)
+        and the chunk's own zonemap check.  The columns stay as they were
+        sliced: the dead rows go up as `live`, and the check reads min/max
+        over dead rows too (an object's summary is kept with the object
+        and must not depend on the snapshot; a range over more rows than
+        are visible can only prune less).  -> (arrays, validity, dicts, n,
+        live), or None when the chunk has nothing to scan."""
         from matrixone_tpu.utils import metrics as M, motrace
-        keep = None
+        live = None
         if dead_filter is not None:
-            keep = ~dead_filter.test_range(seg.base_gid + start,
+            live = ~dead_filter.test_range(seg.base_gid + start,
                                            seg.base_gid + end)
-            if not keep.any():
+            if not live.any():
                 M.scan_chunks.inc(outcome="all_dead")
                 return None
-            if keep.all():
-                keep = None
-        arrays, validity = sliced = _chunk_columns(seg, start, end,
-                                                   data_cols)
-        if keep is not None:
-            # a table thinned by tombstones: an eager gather a column
-            gathers = sum(isinstance(a, jax.Array)
-                          for d in sliced for a in d.values())
-            if gathers:
-                M.scan_slice_dispatch.inc(gathers, how="column")
-            arrays = {c: a[keep] for c, a in arrays.items()}
-            validity = {c: v[keep] for c, v in validity.items()}
-        if want_rowid:
-            g = np.arange(seg.base_gid + start, seg.base_gid + end,
-                          dtype=np.int64)
-            if keep is not None:
-                g = g[keep]
-            arrays[ROWID] = g
-            validity[ROWID] = np.ones(len(g), np.bool_)
-        n = len(next(iter(arrays.values()))) if arrays else 0
-        if n == 0:
-            return None
+            if live.all():
+                live = None
+        arrays, validity = _chunk_columns(seg, start, end, data_cols)
         if filters:
             with motrace.span("scan.zonemap"):
-                kept = seg.arrays.chunk_summaries if seg.is_lazy else None
                 pruned = _zonemap_excludes(
-                    filters, *(sliced if kept is not None
-                               else (arrays, validity)),
-                    qmap, dict(self.meta.schema), kept=kept,
+                    filters, arrays, validity, qmap,
+                    dict(self.meta.schema),
+                    kept=seg.arrays.chunk_summaries if seg.is_lazy else None,
                     rows=(start, end))
                 motrace.annotate(pruned=pruned)
             if pruned:
                 M.scan_chunks.inc(outcome="pruned_chunk")
                 return None
+        n = end - start
+        if want_rowid:
+            arrays[ROWID] = np.arange(seg.base_gid + start,
+                                      seg.base_gid + end, dtype=np.int64)
+            validity[ROWID] = np.ones(n, np.bool_)
+        n_live = n if live is None else int(live.sum())
         M.scan_chunks.inc(outcome="scanned")
-        motrace.annotate(rows=n)
-        return arrays, validity, self.dicts, n
+        M.scan_chunk_rows.inc(n_live, state="live")
+        if n_live < n:
+            M.scan_chunk_rows.inc(n - n_live, state="dead")
+        M.scan_chunks_backing.inc(
+            backing="object" if seg.is_lazy else "memory")
+        motrace.annotate(rows=n_live)
+        return arrays, validity, self.dicts, n, live
 
     def scan_is_cold(self, columns: List[str]) -> bool:
         """True when a scan of `columns` would miss the decoded-column
@@ -775,6 +782,21 @@ class MVCCTable:
         return self.engine.commit_write(self.meta.name, full, val)
 
 
+def live_rows(chunk):
+    """A chunk of `iter_chunks` for a reader that walks rows on the host
+    (the catalogs, RESTORE, the crash checker): (arrays, validity, dicts,
+    n) with the chunk's dead rows dropped by numpy, so that no program's
+    shape follows a count of live rows.  A device-resident column is
+    fetched whole first: a reader of a large table belongs on `ScanOp`,
+    which takes `live` in its row mask."""
+    arrays, validity, dicts, n, live = chunk
+    if live is not None:
+        arrays = {c: np.asarray(a)[live] for c, a in arrays.items()}
+        validity = {c: np.asarray(v)[live] for c, v in validity.items()}
+        n = int(live.sum())
+    return arrays, validity, dicts, n
+
+
 def _zm_predicates(filters, qmap):
     """Extract (raw_col, op, col_expr, lit) zonemap-usable predicates."""
     out = []
@@ -955,6 +977,25 @@ def _zonemap_excludes(filters, arrays, validity, qmap, schema,
         if _zm_range_excludes(op, lo, hi, lv):
             return True
     return False
+
+
+def _key_range_excludes(seg: Segment, col: str, keys: np.ndarray) -> bool:
+    """No key of `keys` lies inside the segment's [min, max] of integer
+    column `col`: the stored zonemap of an object-backed segment, the
+    array's own range for one in RAM; unknown means False."""
+    if not seg.n_rows:
+        return True
+    if seg.zonemaps is not None:
+        zm = seg.zonemaps.get(col)
+        if zm is None or zm[0] is None or zm[1] is None:
+            return False
+        lo, hi = zm[0], zm[1]
+    elif seg.is_lazy:
+        return False
+    else:
+        a = seg.arrays[col]
+        lo, hi = a.min(), a.max()
+    return not ((keys >= lo) & (keys <= hi)).any()
 
 
 def _seg_zonemap_excludes(filters, zonemaps, n_rows, qmap) -> bool:
@@ -1248,14 +1289,14 @@ class Engine:
         # materialize the historical view
         parts_a, parts_v = [], []
         cols = [c for c, _ in t.meta.schema]
-        for arrays, validity, _dicts, n in t.iter_chunks(
-                cols, 1 << 20, snapshot_ts=ts):
+        for arrays, validity, _dicts, n in map(live_rows, t.iter_chunks(
+                cols, 1 << 20, snapshot_ts=ts)):
             parts_a.append(arrays)
             parts_v.append(validity)
         # all currently-visible rows go away
         current = []
-        for arrays, validity, _d, n in t.iter_chunks(
-                [ROWID], 1 << 20):
+        for arrays, validity, _d, n in map(live_rows, t.iter_chunks(
+                [ROWID], 1 << 20)):
             current.append(arrays[ROWID])
         cur_gids = (np.concatenate(current) if current
                     else np.zeros(0, np.int64))

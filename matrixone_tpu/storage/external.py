@@ -175,7 +175,7 @@ class ExternalTable:
     @property
     def n_rows(self) -> int:
         if self._n_rows is None:
-            self._n_rows = sum(n for _a, _v, _d, n in
+            self._n_rows = sum(n for _a, _v, _d, n, _live in
                                self.iter_chunks(
                                    [self.meta.schema[0][0]], 1 << 20))
         return self._n_rows
@@ -368,7 +368,8 @@ class ExternalTable:
     def iter_chunks(self, columns: List[str], batch_rows: int,
                     filters=None, qualified_names=None, **_txn_kwargs):
         """MVCCTable.iter_chunks-compatible read (txn kwargs ignored: an
-        external file has no versions). Zonemap pruning applies per chunk
+        external file has no versions, and no tombstones: every chunk's
+        `live` is None). Zonemap pruning applies per chunk
         exactly as on internal segments; repeat queries of a local file
         serve from the decoded cache."""
         sd = dict(self.meta.schema)
@@ -393,10 +394,11 @@ class ExternalTable:
                     if filters and _zonemap_excludes(
                             filters, arrays, validity, qmap, sd):
                         continue
-                    yield arrays, validity, self.dicts, n
+                    yield arrays, validity, self.dicts, n, None
                 base += cn
             return
-        yield from self._iter_stream(columns, batch_rows, filters, qmap)
+        for chunk in self._iter_stream(columns, batch_rows, filters, qmap):
+            yield (*chunk, None)
 
     def _iter_stream(self, columns: List[str], batch_rows: int,
                      filters, qmap):

@@ -30,7 +30,7 @@ class WalWriter:
 
     def append(self, header: dict, arrow_blob: bytes = b"") -> None:
         from matrixone_tpu.utils.fault import INJECTOR
-        from matrixone_tpu.utils import san
+        from matrixone_tpu.utils import motrace, san
         if INJECTOR.trigger("wal.append") == "fail":
             raise IOError("fault injected: wal.append failed")
         hj = json.dumps(header).encode()
@@ -40,7 +40,8 @@ class WalWriter:
         # WAL-then-apply under one commit critical section IS the commit
         # protocol — exempt the durable append like the quorum client
         with san.allow_blocking("wal.append under the commit lock is "
-                                "the commit protocol"):
+                                "the commit protocol"), \
+                motrace.span("wal.sync", bytes=len(frame)):
             self.fs.append(self.path, frame)
 
     def truncate(self) -> None:

@@ -169,9 +169,11 @@ def _table_version(t) -> tuple:
 def _scan_rows(t) -> List[dict]:
     """Host-side read of all visible system_udf rows (the table is tiny:
     one row per function)."""
+    from matrixone_tpu.storage.engine import live_rows
     cols = [c for c, _ in _SCHEMA]
     rows: List[dict] = []
-    for arrays, validity, dicts, n in t.iter_chunks(cols, 1 << 16):
+    for arrays, validity, dicts, n in map(
+            live_rows, t.iter_chunks(cols, 1 << 16)):
         for i in range(n):
             row = {}
             for c, d in _SCHEMA:
@@ -239,11 +241,11 @@ def lookup(catalog, name: str) -> Optional[UdfMeta]:
 
 def gids_for_name(catalog, name: str) -> np.ndarray:
     """Global row ids of the function's row(s) (DROP / OR REPLACE)."""
-    from matrixone_tpu.storage.engine import ROWID
+    from matrixone_tpu.storage.engine import ROWID, live_rows
     t = catalog.get_table(UDF_TABLE)
     out = []
-    for arrays, validity, dicts, n in t.iter_chunks([ROWID, "name"],
-                                                    1 << 16):
+    for arrays, validity, dicts, n in map(
+            live_rows, t.iter_chunks([ROWID, "name"], 1 << 16)):
         d = dicts["name"]
         for i in range(n):
             if validity["name"][i] and \

@@ -329,10 +329,22 @@ scan_slice_dispatch = REGISTRY.counter(
     "mo_scan_slice_dispatch_total",
     "programs MVCCTable._read_chunk dispatched over a chunk's "
     "device-resident columns, by how: chunk (the one program that slices "
-    "every data and validity array of the chunk), column (an eager "
-    "program a column: the tombstone gather of a chunk with dead rows; "
-    "no slice is made a column any more).  A chunk that is its whole "
-    "segment, and a numpy column, dispatch nothing")
+    "every data and validity array of the chunk).  A chunk that is its "
+    "whole segment, and a numpy column, dispatch nothing; nothing is "
+    "dispatched a column any more (how=column was the tombstone gather: "
+    "a chunk's dead rows ride the row mask)")
+scan_chunk_rows = REGISTRY.counter(
+    "mo_scan_chunk_rows_total",
+    "rows of the chunks handed to a scan's consumer, once a scanned "
+    "chunk, by state: live (visible to the statement), dead (tombstoned: "
+    "read, sliced and uploaded at the chunk's own length, then masked "
+    "out)")
+scan_chunks_backing = REGISTRY.counter(
+    "mo_scan_chunks_backing_total",
+    "chunks handed to a scan's consumer, once a scanned chunk, by what "
+    "backs their segment: object (flushed, served through the block "
+    "cache and its device tier), memory (numpy in RAM since its commit, "
+    "or a transaction's workspace: uploaded again by every scan)")
 scan_zonemap_checks = REGISTRY.counter(
     "mo_scan_zonemap_checks_total",
     "zonemap predicates checked against a chunk's own min/max, once a "

@@ -34,7 +34,12 @@ RPC wire.  Here the span tree covers the whole statement lifecycle:
           tn.<op>                cluster/tn.py server-side handling
         worker.run               worker/client.py gRPC offload
           worker.<op>            worker/server.py server-side handling
+        dml.find                 frontend/session.py: the plan that finds
+                                 a DELETE's / UPDATE's row ids (its scan
+                                 and fused spans below it)
         txn.commit               txn/client.py commit pipeline
+          wal.sync               storage/wal.py: the append and flush a
+                                 commit waits for
         mview.apply              mview/maintain.py delta maintenance
 
 Rule for generators (kept by tests/test_motrace.py and the molint rule

@@ -16,6 +16,7 @@ from typing import Iterator
 import numpy as np
 
 from matrixone_tpu.sql import plan as P
+from matrixone_tpu.storage.engine import live_rows
 from matrixone_tpu.vm.exprs import ExecBatch
 from matrixone_tpu.vm.operators import Operator, chunk_to_execbatch
 
@@ -54,9 +55,9 @@ class FulltextTopKOp(Operator):
         if len(gids) < want:
             # fill with zero-score rows: ORDER BY must not drop rows
             all_gids = []
-            for arrays, _v, _d, _n in table.iter_chunks(
+            for arrays, _v, _d, _n in map(live_rows, table.iter_chunks(
                     ["__rowid"], 1 << 20,
-                    **self.ctx.table_read_args(self.node.table)):
+                    **self.ctx.table_read_args(self.node.table))):
                 all_gids.append(arrays["__rowid"])
             if all_gids:
                 rest = np.setdiff1d(np.concatenate(all_gids), gids)

@@ -44,11 +44,14 @@ class Operator:
 
 # ------------------------------------------------------------------- scan
 
-def chunk_to_execbatch(arrays, validity, table_dicts, n, columns, schema
-                       ) -> ExecBatch:
+def chunk_to_execbatch(arrays, validity, table_dicts, n, columns, schema,
+                       live=None) -> ExecBatch:
     """Host chunk -> padded device ExecBatch, renaming raw table columns to
     the plan's qualified names and tagging varlen columns (used by ScanOp
-    and the vector-index scan)."""
+    and the vector-index scan).  `live` (host bool [n], False for a
+    tombstoned row) becomes the batch's row mask: one bool upload at the
+    bucket's length, so every program downstream keeps the chunk's shape
+    whatever was deleted from it."""
     from matrixone_tpu.container import device as dev
     from matrixone_tpu.ops import encodings as ENC
     qnames = [nm for nm, _ in schema]
@@ -68,7 +71,13 @@ def chunk_to_execbatch(arrays, validity, table_dicts, n, columns, schema
         if dtype.is_varlen:
             c = db.columns[qn]
             db.columns[qn] = DeviceColumn(c.data, c.validity, dtype)
-    return ExecBatch(batch=db, dicts=dicts2, mask=db.row_mask())
+    if live is None:
+        mask = db.row_mask()
+    else:
+        padded = np.zeros(db.padded_len, np.bool_)
+        padded[:n] = live
+        mask = jnp.asarray(padded)
+    return ExecBatch(batch=db, dicts=dicts2, mask=mask)
 
 
 class _ChunkPrefetcher:
@@ -243,19 +252,21 @@ class ScanOp(Operator):
                     # (same snapshot, same filters -> same pruning on
                     # every replica)
                     continue
-                arrays, validity, dicts, n = chunk
+                arrays, validity, dicts, n, live = chunk
                 if hs is not None:
                     arrays, validity, n, moved = _hash_route(
-                        arrays, validity, n, hs, hs_aligned)
+                        arrays, validity, n, hs, hs_aligned, live)
+                    live = None
                     if n == 0:
                         continue
                     if moved:
                         M.exchange_shuffle_rows.inc(moved)
-                M.rows_scanned.inc(n, table=self.node.table)
+                M.rows_scanned.inc(n if live is None else int(live.sum()),
+                                   table=self.node.table)
                 with motrace.span("scan.batch", rows=n):
                     ex = chunk_to_execbatch(arrays, validity, dicts, n,
                                             self.node.columns,
-                                            self.node.schema)
+                                            self.node.schema, live)
                     # evaluate pushed filters as an early mask (zonemap
                     # pruning already dropped fully-excluded chunks
                     # host-side)
@@ -270,9 +281,10 @@ class ScanOp(Operator):
                 prefetcher.close()
 
 
-def _hash_route(arrays, validity, n: int, hs, aligned: bool):
-    """Keep only the rows this shard owns under the hash exchange
-    `hash_shard=(column, idx, n_shards)`.  Routing is splitmix64 % n with
+def _hash_route(arrays, validity, n: int, hs, aligned: bool, live=None):
+    """Keep only the live rows this shard owns under the hash exchange
+    `hash_shard=(column, idx, n_shards)` (`live`: the chunk's tombstone
+    mask, None where no row is dead).  Routing is splitmix64 % n with
     NULL -> shard 0 — bit-identical to the commit pipeline's
     storage.partition.assign_partitions, so a partitioned table and an
     implicit repartition agree on every row's home.  Returns
@@ -296,6 +308,8 @@ def _hash_route(arrays, validity, n: int, hs, aligned: bool):
                    (partmod._hash64(key.astype(np.int64))
                     % np.uint64(n_shards)).astype(np.int64), 0)
     keep = pid == idx
+    if live is not None:
+        keep &= live
     kept = int(keep.sum())
     moved = 0 if aligned else kept
     if kept == n:

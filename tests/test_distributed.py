@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from matrixone_tpu.logservice.replicated import ReplicatedLog
-from matrixone_tpu.storage.engine import Engine
+from matrixone_tpu.storage.engine import Engine, live_rows
 from matrixone_tpu.storage.fileservice import MemoryFS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -166,8 +166,8 @@ def test_remote_scope_q1_two_worker_processes(workers):
     coord = RemoteScopeCoordinator(workers)
     chunks = [({c: arrays[c] for c in cols},
                {c: validity[c] for c in cols})
-              for arrays, validity, _dicts, _n in t.iter_chunks(
-                  cols, batch_rows=16384)]
+              for arrays, validity, _dicts, _n in map(
+                  live_rows, t.iter_chunks(cols, batch_rows=16384))]
     assert len(chunks) >= 2, "need multiple chunks to exercise fan-out"
     keys, kvalids, vals, ng = coord.group_aggregate(
         chunks, schema,

@@ -504,7 +504,8 @@ def test_the_benchmark_reads_the_counter_as_a_share(session, monkeypatch):
         "source": "program_counter",
         "layer": next(m["layer"] for m in manifest["per_layer"]
                       if m["name"] == "join_build_ms.sql"),
-        "moves": "sql_rows_per_s", "workloads": ["ssb-sf1.star-join"]}]
+        "moves": "sql_rows_per_s",
+        "workloads": ["ssb-sf1.star-join", "tpch-sf1.join-q3"]}]
     with open(os.path.join(root, "benchmark", "metrics",
                            name + ".json")) as f:
         spec = json.load(f)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from matrixone_tpu.container.dtypes import DType, TypeOid
-from matrixone_tpu.storage.engine import Engine, TableMeta
+from matrixone_tpu.storage.engine import Engine, TableMeta, live_rows
 from matrixone_tpu.storage.fileservice import (LocalFS, MemoryFS,
                                                RecordingFileService)
 from matrixone_tpu.storage import wal as walmod
@@ -297,7 +297,8 @@ def test_checkpoint_truncate_window_drill():
             # must survive every point of the window
             ids = set()
             t = eng.get_table("t")
-            for arrays, _v, _d, n in t.iter_chunks(["id"], 1 << 20):
+            for arrays, _v, _d, n in map(
+                    live_rows, t.iter_chunks(["id"], 1 << 20)):
                 ids.update(int(x) for x in arrays["id"])
             assert set(range(5)) <= ids
 

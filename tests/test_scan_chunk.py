@@ -138,7 +138,8 @@ def test_a_sliced_chunk_is_the_columns_own_rows(reopened, col):
     table = eng.get_table("li")
     assert all(seg.is_lazy for seg in table.segments)
     counts = _Counts()
-    chunks = list(table.iter_chunks([col, "id"], BATCH_ROWS))
+    chunks = list(map(engmod.live_rows,
+                      table.iter_chunks([col, "id"], BATCH_ROWS)))
     cuts = [(seg, lo, min(lo + BATCH_ROWS, seg.n_rows))
             for seg in table.segments
             for lo in range(0, seg.n_rows, BATCH_ROWS)]
@@ -167,7 +168,8 @@ def test_a_chunk_that_is_its_whole_segment_dispatches_nothing(reopened):
     eng, _s = reopened
     table = eng.get_table("li")
     counts = _Counts()
-    chunks = list(table.iter_chunks(["id", "qty"], 1 << 20))
+    chunks = list(map(engmod.live_rows,
+                      table.iter_chunks(["id", "qty"], 1 << 20)))
     assert counts.slices == {}
     assert [n for *_, n in chunks] == [ROWS // COMMITS] * COMMITS
     for (arrays, validity, _d, _n), seg in zip(chunks, table.segments):
@@ -290,7 +292,7 @@ def test_two_scans_filling_one_summary_agree(tmp_path):
 
     def scan():
         try:
-            seen.append(sum(n for *_, n in table.iter_chunks(
+            seen.append(sum(n for *_, n, _live in table.iter_chunks(
                 ["id"], BATCH_ROWS, filters=filters)))
         except Exception as e:                         # noqa: BLE001
             errors.append(e)
@@ -324,8 +326,8 @@ def test_numpy_segments_dispatch_and_compile_nothing(tmp_path):
                _pred("lt", "qty", dt.decimal64(15, 2), 51, dt.INT64)]
     counts = _Counts()
     with _compiled() as names:
-        chunks = list(table.iter_chunks(["id", "qty", "v"], BATCH_ROWS,
-                                        filters=filters))
+        chunks = list(map(engmod.live_rows, table.iter_chunks(
+            ["id", "qty", "v"], BATCH_ROWS, filters=filters)))
     assert names == []
     assert counts.slices == {} and counts.waits == 0
     assert counts.checks == {"host": 2 * len(chunks)}
@@ -450,8 +452,8 @@ def test_dead_extremes_leave_the_chunk_scanned(tmp_path, monkeypatch):
     """The rows that hold chunk (0, 1000)'s maximum are deleted.  The
     kept summary is of the object, not of the snapshot: the chunk is
     scanned where an exact check of the visible rows would prune it, and
-    the answer is the visible rows'.  The gather of a thinned chunk is
-    the one eager program a column that is left."""
+    the answer is the visible rows'.  A thinned chunk keeps its length:
+    its dead rows ride the row mask, and no program runs a column."""
     monkeypatch.setenv("MO_FUSION_MIN_ROWS", "0")
     values = list(range(4000))
     eng, s = _ints(tmp_path, values, commits=2)
@@ -464,8 +466,8 @@ def test_dead_extremes_leave_the_chunk_scanned(tmp_path, monkeypatch):
              sum(1 for a in visible if a >= 950))]
     assert counts.chunks == {"scanned": 4}
     assert counts.checks == {"memo": 4} and counts.waits == 0
-    # chunk (0, 1000) alone has dead rows: data and validity of a and b
-    assert counts.slices == {"chunk": 4, "column": 4}
+    # chunk (0, 1000) alone has dead rows: masked, not gathered
+    assert counts.slices == {"chunk": 4}
     # a chunk with every row dead is still skipped before any read
     s.execute("delete from t where a < 1000")
     counts = _Counts()

@@ -56,7 +56,7 @@ import numpy as np
 
 from matrixone_tpu.cdc import CdcTask, FileWatermark
 from matrixone_tpu.logservice.replicated import ReplicaCore, merge_majority
-from matrixone_tpu.storage.engine import ROWID, Engine
+from matrixone_tpu.storage.engine import ROWID, Engine, live_rows
 from matrixone_tpu.storage.fileservice import MemoryFS
 
 from tools.mocrash import workload as W
@@ -85,8 +85,8 @@ def _read_main(eng: Engine, table: str = "t_main",
     """id -> (batch, v, s) of the visible rows (or the AS OF view)."""
     t = eng.get_table(table)
     out: Dict[int, tuple] = {}
-    for arrays, validity, dicts, n in t.iter_chunks(
-            ["id", "batch", "v", "s"], 1 << 20, snapshot_ts=snapshot_ts):
+    for arrays, validity, dicts, n in map(live_rows, t.iter_chunks(
+            ["id", "batch", "v", "s"], 1 << 20, snapshot_ts=snapshot_ts)):
         for i in range(n):
             s = (dicts["s"][int(arrays["s"][i])]
                  if validity["s"][i] else None)
@@ -99,7 +99,7 @@ def _read_main(eng: Engine, table: str = "t_main",
 def _read_pair(eng: Engine) -> set:
     t = eng.get_table("t_pair")
     out = set()
-    for arrays, _v, _d, n in t.iter_chunks(["id"], 1 << 20):
+    for arrays, _v, _d, n in map(live_rows, t.iter_chunks(["id"], 1 << 20)):
         for i in range(n):
             out.add(int(arrays["id"][i]))
     return out
@@ -109,7 +109,8 @@ def _read_mview(eng: Engine) -> Dict[Optional[str], tuple]:
     t = eng.get_table("mv1")
     cols = [c for c, _ in t.meta.schema]          # s, sv, c
     out: Dict[Optional[str], tuple] = {}
-    for arrays, validity, dicts, n in t.iter_chunks(cols, 1 << 20):
+    for arrays, validity, dicts, n in map(
+            live_rows, t.iter_chunks(cols, 1 << 20)):
         for i in range(n):
             key = (dicts[cols[0]][int(arrays[cols[0]][i])]
                    if validity[cols[0]][i] else None)

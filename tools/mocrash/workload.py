@@ -29,7 +29,8 @@ import numpy as np
 from matrixone_tpu.cdc import CdcTask, FileWatermark
 from matrixone_tpu.container.dtypes import DType, TypeOid
 from matrixone_tpu.logservice.replicated import ReplicaCore
-from matrixone_tpu.storage.engine import ROWID, Engine, TableMeta
+from matrixone_tpu.storage.engine import (ROWID, Engine, TableMeta,
+                                          live_rows)
 from matrixone_tpu.storage.fileservice import (MemoryFS,
                                                RecordingFileService)
 from matrixone_tpu.utils.crash import CrashJournal
@@ -121,7 +122,8 @@ class EngineSink:
         t = self.eng.get_table(self.table)
         want = set(int(i) for i in ids)
         gids = []
-        for arrays, _v, _d, n in t.iter_chunks(["id", ROWID], 1 << 20):
+        for arrays, _v, _d, n in map(
+                live_rows, t.iter_chunks(["id", ROWID], 1 << 20)):
             for i in range(n):
                 if int(arrays["id"][i]) in want:
                     gids.append(int(arrays[ROWID][i]))
@@ -162,7 +164,8 @@ def _clear_table(eng: Engine, name: str) -> None:
     """Tombstone every visible row (one commit) — the mirror re-seed."""
     t = eng.get_table(name)
     gids: List[int] = []
-    for arrays, _v, _d, n in t.iter_chunks([ROWID], 1 << 20):
+    for arrays, _v, _d, n in map(live_rows,
+                                 t.iter_chunks([ROWID], 1 << 20)):
         gids.extend(int(g) for g in arrays[ROWID])
     if gids:
         eng.commit_txn(None, {}, {name: np.asarray(gids, np.int64)})
