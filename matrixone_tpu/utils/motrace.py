@@ -9,6 +9,9 @@ RPC wire.  Here the span tree covers the whole statement lifecycle:
       run                        per-statement execution envelope
         admission.queue          serving/admission.py slot wait
         plan                     bind + optimize on a plan-cache miss
+        op.<kind>                vm/: one step of an operator's
+                                 `execute()` (`steps`), its children's
+                                 steps and every span below nested in it
         scan.wait                vm/operators.py: the statement's thread
                                  blocked on the scan prefetcher
         scan.chunk               storage/engine.py iter_chunks, one per
@@ -60,6 +63,11 @@ caller on the RESPONSE header (`trace_spans`) so one process ends up
 owning the complete tree — the Chrome exporter then renders each
 logical process (cn/tn/worker/proxy) as its own lane.
 
+Every span records its thread's CPU time (`cpu_us`, from
+`time.thread_time_ns`) beside its wall time (`dur_us`): the difference
+is the time the thread was off the CPU, waiting for the GIL, a lock,
+the device or I/O.
+
 Cost discipline (same contract as utils/fault.py and utils/san.py):
 disarmed, every instrumentation site costs ONE attribute read
 (`TRACER.armed`) — `span()` returns a shared no-op context manager
@@ -100,11 +108,11 @@ def _env_float(name: str, default: float) -> float:
 
 
 class _Ctx:
-    """Ambient trace context for one open span (immutable; the
-    contextvar stack IS the span stack)."""
+    """Ambient trace context for one open span (immutable but for
+    `parent_of_recorded`; the contextvar stack IS the span stack)."""
 
     __slots__ = ("trace_id", "span_id", "proc", "sink", "attrs",
-                 "events")
+                 "events", "parent_of_recorded")
 
     def __init__(self, trace_id: str, span_id: str, proc: str,
                  sink: Optional[list], attrs: dict, events: list):
@@ -117,6 +125,8 @@ class _Ctx:
         #: live references so event()/annotate() reach the OPEN span
         self.attrs = attrs
         self.events = events
+        #: a span opened under this one (on any thread) was recorded
+        self.parent_of_recorded = False
 
 
 _CTX: contextvars.ContextVar = contextvars.ContextVar(
@@ -248,8 +258,17 @@ class _NoopSpan:
     def __exit__(self, *a):
         return False
 
+    def last_step(self) -> None:
+        return None
+
 
 _NOOP = _NoopSpan()
+
+#: An operator's last step, the `next()` that finds its input done, is a
+#: span only where a span below it was recorded or it took this much of
+#: its thread's CPU: a step that only learns the end adds nothing to the
+#: ring (a one-batch operator is one span, not two).
+LAST_STEP_CPU_NS = 100_000
 
 _ANNOTATION = None
 
@@ -273,7 +292,8 @@ class _Span:
     clock beside the device's operations."""
 
     __slots__ = ("name", "attrs", "_tid", "_psid", "_sid", "_proc",
-                 "_sink", "_events", "_t0", "_token", "_twin")
+                 "_sink", "_events", "_t0", "_c0", "_token", "_twin",
+                 "_ctx", "_parent", "_last_step")
 
     def __init__(self, name: str, trace_id: str, parent_sid: str,
                  proc: str, sink: Optional[list], attrs: dict):
@@ -285,20 +305,29 @@ class _Span:
         self._proc = proc
         self._sink = sink
         self._events: list = []
-        self._t0 = 0
+        self._t0 = self._c0 = 0
         self._token = None
         self._twin = None
+        self._ctx = self._parent = None
+        self._last_step = False
 
     def __enter__(self):
         self._t0 = time.time_ns()
+        self._c0 = time.thread_time_ns()
         annotation = _annotation()
         if annotation is not None:
             self._twin = annotation(self.name)
             self._twin.__enter__()
-        self._token = _CTX.set(_Ctx(self._tid, self._sid, self._proc,
-                                    self._sink, self.attrs,
-                                    self._events))
+        self._parent = _CTX.get()
+        self._ctx = _Ctx(self._tid, self._sid, self._proc, self._sink,
+                         self.attrs, self._events)
+        self._token = _CTX.set(self._ctx)
         return self
+
+    def last_step(self) -> None:
+        """This span is an operator's step that found its input done
+        (`steps`): it is recorded only if it did work."""
+        self._last_step = True
 
     def __exit__(self, exc_type, exc, tb):
         _CTX.reset(self._token)
@@ -306,13 +335,19 @@ class _Span:
             self._twin.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
+        cpu = time.thread_time_ns() - self._c0
         dur = time.time_ns() - self._t0
+        if (self._last_step and cpu < LAST_STEP_CPU_NS
+                and not self._ctx.parent_of_recorded):
+            return False
+        if self._parent is not None:
+            self._parent.parent_of_recorded = True
         TRACER.record({"tid": self._tid, "sid": self._sid,
                        "psid": self._psid, "name": self.name,
                        "proc": self._proc,
                        "thread": threading.current_thread().name,
                        "ts_us": self._t0 // 1000,
-                       "dur_us": dur // 1000,
+                       "dur_us": dur // 1000, "cpu_us": cpu // 1000,
                        "attrs": self.attrs, "events": self._events},
                       sink=self._sink)
         return False
@@ -328,6 +363,32 @@ def span(name: str, **attrs):
         return _NOOP
     return _Span(name, ctx.trace_id, ctx.span_id, ctx.proc, ctx.sink,
                  attrs)
+
+
+def steps(name: Optional[str], it):
+    """`it`, an operator's batch iterator, with each `next()` under a
+    span `name`: the work between two yields, the pulls from its
+    children included, and never a yield.  Closing the result closes
+    `it`.  Disarmed, with no trace active or no name, it is `it`."""
+    if not TRACER.armed or name is None or _CTX.get() is None:
+        return it
+    return _steps(name, it)
+
+
+def _steps(name: str, it):
+    try:
+        while True:
+            with span(name) as step:
+                try:
+                    item = next(it)
+                except StopIteration:
+                    step.last_step()
+                    return
+            yield item
+    finally:
+        close = getattr(it, "close", None)
+        if close is not None:
+            close()
 
 
 def root_span(name: str, proc: Optional[str] = None, **attrs):
@@ -597,7 +658,7 @@ def chrome_trace(trace_id: str) -> dict:
             "pid": pid, "tid": tid, "ts": s["ts_us"],
             "dur": max(1, s["dur_us"]),
             "args": dict(s["attrs"], span_id=s["sid"],
-                         parent_id=s["psid"])})
+                         parent_id=s["psid"], cpu_us=s.get("cpu_us"))})
         for ev in s["events"]:
             events.append({
                 "ph": "i", "s": "t", "name": ev["name"],

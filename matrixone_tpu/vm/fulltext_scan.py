@@ -22,6 +22,8 @@ from matrixone_tpu.vm.operators import Operator, chunk_to_execbatch
 
 
 class FulltextTopKOp(Operator):
+    step_span = "op.fulltext_topk"
+
     def __init__(self, node: P.FulltextTopK, ctx):
         self.node = node
         self.ctx = ctx

@@ -789,6 +789,8 @@ class FusedFragmentOp(O.Operator):
     ANALYZE, runtime-filter resolution, ctx retargeting) traverse
     through fragments unchanged."""
 
+    step_span = "op.fused"
+
     #: prelude subclasses (join probe, window) build the chain's input
     #: batch in-trace — the child scan stays its own operator there
     _allow_scan_defer = True

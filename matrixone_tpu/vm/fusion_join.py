@@ -186,6 +186,8 @@ class FusedJoinProbeOp(FF.FusedFragmentOp):
     walkers (EXPLAIN ANALYZE, retarget_tree, runtime-filter resolution)
     traverse both unchanged."""
 
+    step_span = "op.fused_join"
+
     _allow_scan_defer = False
     _step_prefix = "frag_join"
 

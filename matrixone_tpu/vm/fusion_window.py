@@ -77,6 +77,8 @@ class FusedWindowOp(FF.FusedFragmentOp):
     — exactly what the per-operator WindowOp does — and then runs a
     single compiled program: window prelude + stages + terminal."""
 
+    step_span = "op.fused_window"
+
     _allow_scan_defer = False
     _step_prefix = "frag_window"
 

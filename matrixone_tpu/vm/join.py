@@ -50,6 +50,7 @@ from matrixone_tpu.container import dtypes as dt
 from matrixone_tpu.container.device import DeviceBatch, DeviceColumn
 from matrixone_tpu.ops import filter as F, hash as H
 from matrixone_tpu.sql import plan as P
+from matrixone_tpu.utils import metrics as M, motrace
 from matrixone_tpu.vm.exprs import ExecBatch, eval_expr
 from matrixone_tpu.vm.operators import Operator, _broadcast_full, _concat_batches
 
@@ -96,6 +97,7 @@ def _maybe_compact(out: ExecBatch) -> ExecBatch:
     if lanes <= 2048:
         return out
     live = int(jax.device_get(jnp.sum(out.mask.astype(jnp.int32))))
+    M.device_wait.inc(site="join_compact")
     cap = bucket_length(max(live, 1))
     if cap * 4 > lanes:
         return out
@@ -354,7 +356,6 @@ def output_schema(node) -> list:
 def count_build_columns(node) -> None:
     """`mo_join_build_columns_total`, once a join executed: the build
     side's columns that a probe gathers and those it leaves alone."""
-    from matrixone_tpu.utils import metrics as M
     gathered = len(_probe_columns(node)[1])
     M.join_build_columns.inc(gathered, outcome="gathered")
     M.join_build_columns.inc(len(node.right.schema) - gathered,
@@ -434,7 +435,6 @@ def stream_build_side(build_iter, budget: int):
     old per-batch `device_get` serialized every dispatch past the
     bound).  Each drain is a `join.build.livesync` motrace span, which
     is how the regression test counts them."""
-    from matrixone_tpu.utils import motrace
     batches: List[ExecBatch] = []
     pending = []
     padded_pending = 0
@@ -448,6 +448,7 @@ def stream_build_side(build_iter, budget: int):
             continue
         with motrace.span("join.build.livesync", pending=len(pending)):
             live += int(jax.device_get(jnp.sum(jnp.stack(pending))))
+            M.device_wait.inc(site="join_livesync")
         pending = []
         padded_pending = 0
         if live > budget:
@@ -524,6 +525,8 @@ def _null_column(dtype, lanes: int) -> DeviceColumn:
 
 
 class JoinOp(Operator):
+    step_span = "op.join"
+
     #: build rows beyond which the join Grace-spills both sides
     DEFAULT_BUILD_BUDGET = 1 << 22
 
@@ -632,7 +635,6 @@ class JoinOp(Operator):
         """Build side over budget: hash-partition BOTH sides to host disk
         by the join key, then run each partition through the normal
         in-memory join (reference: spillutil/join_spill.go)."""
-        from matrixone_tpu.utils import metrics as M
         M.join_spills.inc()
         spill = _JoinSpill(self.spill_partitions)
         try:
@@ -719,6 +721,7 @@ class JoinOp(Operator):
             return
         lo, hi, any_valid = runtime_filter_ranges(specs, bkeys, bvalid)
         got = jax.device_get((lo, hi, any_valid))
+        M.device_wait.inc(site="join_eager")
         self.apply_runtime_filters(specs, np.asarray(got[0]),
                                    np.asarray(got[1]), bool(got[2]))
 
@@ -754,7 +757,9 @@ class JoinOp(Operator):
             out, overflow, bm = expand_probe(
                 self.node, ex, build, sorted_hash, border, phash,
                 pvalid, pkeys, bkeys, mm, bm)
-            if not bool(jax.device_get(overflow)):
+            over = bool(jax.device_get(overflow))
+            M.device_wait.inc(site="join_eager")
+            if not over:
                 if self.node.kind == "full":
                     self._build_matched = bm
                 break

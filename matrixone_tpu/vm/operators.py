@@ -32,14 +32,38 @@ from matrixone_tpu.container.dtypes import DType, TypeOid
 from matrixone_tpu.ops import agg as A, filter as F, sort as msort
 from matrixone_tpu.sql import plan as P
 from matrixone_tpu.sql.expr import AggCall, BoundCol, BoundExpr
+from matrixone_tpu.utils import metrics as M, motrace
 from matrixone_tpu.vm.exprs import EvalError, ExecBatch, eval_expr
 
 
 class Operator:
+    #: the span each step of `execute()` runs under (`motrace.steps`):
+    #: `op.<kind>`, or None for an operator that only hands back batches
+    #: it was given
+    step_span: Optional[str] = None
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        own = cls.__dict__.get("execute")
+        if own is not None:
+            cls.execute = _stepped(own)
+
     def execute(self) -> Iterator[ExecBatch]:
         raise NotImplementedError
 
     schema: List
+
+
+def _stepped(execute):
+    """A class's own `execute`, its steps under the operator's span.  A
+    subclass's `execute` that calls this one through `super()` opens the
+    spans alone: only the operator's own class's wrapper does."""
+    @functools.wraps(execute)
+    def stepped(self):
+        if not motrace.TRACER.armed or type(self).execute is not stepped:
+            return execute(self)
+        return motrace.steps(self.step_span, execute(self))
+    return stepped
 
 
 # ------------------------------------------------------------------- scan
@@ -96,7 +120,6 @@ class _ChunkPrefetcher:
 
     def __init__(self, gen, depth: int):
         import queue
-        from matrixone_tpu.utils import motrace
         self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
         self._stop = threading.Event()
         self._thread = threading.Thread(
@@ -131,7 +154,6 @@ class _ChunkPrefetcher:
                     continue
 
     def __iter__(self):
-        from matrixone_tpu.utils import metrics as M, motrace
         while True:
             ready = not self._q.empty()
             if ready:
@@ -168,6 +190,8 @@ class ScanOp(Operator):
     read-ahead stage decoding chunk N+1 while chunk N computes
     (MO_SCAN_PREFETCH chunks deep; 0 disables)."""
 
+    step_span = "op.scan"
+
     def __init__(self, node: P.Scan, relation, batch_rows: int = 1 << 20,
                  ctx=None):
         self.node = node
@@ -188,7 +212,6 @@ class ScanOp(Operator):
         still handed to iter_chunks (zonemap pruning) but NOT evaluated
         as an early row mask — a fused fragment (vm/fusion.py) folds
         them into its single traced program instead."""
-        from matrixone_tpu.utils import metrics as M, motrace
         from matrixone_tpu.utils.fault import INJECTOR
         INJECTOR.trigger("scan.before")
         qnames = [n for n, _ in self.node.schema]
@@ -324,6 +347,8 @@ class MaterializedOp(Operator):
     """Host arrays as a plan input (P.Materialized): the coordinator's
     merged fragment results re-enter the local operator tree here."""
 
+    step_span = "op.materialized"
+
     def __init__(self, node):
         self.node = node
         self.schema = node.schema
@@ -365,6 +390,8 @@ class MaterializedOp(Operator):
 
 
 class ValuesOp(Operator):
+    step_span = "op.values"
+
     def __init__(self, node: P.Values):
         self.node = node
         self.schema = node.schema
@@ -383,6 +410,8 @@ class ValuesOp(Operator):
 # ----------------------------------------------------------------- filter
 
 class FilterOp(Operator):
+    step_span = "op.filter"
+
     def __init__(self, node: P.Filter, child: Operator):
         self.node = node
         self.child = child
@@ -396,6 +425,8 @@ class FilterOp(Operator):
 
 
 class ProjectOp(Operator):
+    step_span = "op.project"
+
     def __init__(self, node: P.Project, child: Operator):
         self.node = node
         self.child = child
@@ -429,6 +460,8 @@ class UdfAggregateOp(Operator):
     NULL-in-any-argument rows are skipped, matching builtin aggregate
     NULL semantics) and run each body ONCE over the concatenated
     arrays. One output row."""
+
+    step_span = "op.udf_aggregate"
 
     def __init__(self, node: "P.UdfAggregate", child: Operator):
         self.node = node
@@ -620,6 +653,8 @@ class AggOp(Operator):
     The group table grows adaptively (quantized ×4 so the jit cache stays
     small — the reference grows its hash table the same way); past
     `max_device_groups` it Grace-spills to host (see _AggSpill)."""
+
+    step_span = "op.aggregate"
 
     def __init__(self, node: P.Aggregate, child: Operator,
                  max_groups: int = 4096,
@@ -1023,7 +1058,6 @@ class AggOp(Operator):
         slots' row counts come to the host (the path's one wait), the few
         filled ones are gathered."""
         from matrixone_tpu.container.device import bucket_length
-        from matrixone_tpu.utils import metrics as M
         rows, outside = jax.device_get((_wide_rows(wide["acc"]),
                                         wide["outside"]))
         M.device_wait.inc(site="agg_slots")
@@ -1068,6 +1102,7 @@ class AggOp(Operator):
             mg = self.max_groups
             gi = A.group_ids(kdata, kvalid, mask, mg)
             ng = int(jax.device_get(gi.num_groups))
+            M.device_wait.inc(site="agg_groups")
             if ng <= mg:
                 break
             self._grow(ng, allow_spill)
@@ -1089,6 +1124,7 @@ class AggOp(Operator):
             mg = self.max_groups
             gi = A.group_ids(keys, kvalid, mask, mg)
             ng = int(jax.device_get(gi.num_groups))
+            M.device_wait.inc(site="agg_groups")
             if ng <= mg:
                 break
             self._grow(ng, allow_spill)
@@ -1606,6 +1642,8 @@ class UnionOp(Operator):
     re-encoding string columns into a union-wide dictionary (children's
     dictionaries are per-table and must not collide)."""
 
+    step_span = "op.union"
+
     def __init__(self, node, children: List[Operator]):
         self.node = node
         self.children = children
@@ -1724,6 +1762,8 @@ class SortOp(Operator):
     order in numpy, which vm/result.py takes for a statement's last Sort
     over a small input."""
 
+    step_span = "op.sort"
+
     def __init__(self, node: P.Sort, child: Operator):
         self.node = node
         self.child = child
@@ -1752,6 +1792,8 @@ class SortOp(Operator):
 class TopKOp(Operator):
     """ORDER BY ... LIMIT: `order` on the device, `host_rows` the same in
     numpy (see SortOp)."""
+
+    step_span = "op.topk"
 
     def __init__(self, node: P.TopK, child: Operator):
         self.node = node
@@ -1817,6 +1859,8 @@ class SampleOp(Operator):
     TPU-style as top-N over per-row random keys — the same top_k kernel
     TopK uses, so no per-row host loop and a bounded device footprint."""
 
+    step_span = "op.sample"
+
     def __init__(self, node: P.Sample, child: Operator):
         self.node = node
         self.child = child
@@ -1862,6 +1906,8 @@ class FillOp(Operator):
     non-null value forward, LINEAR interpolates between the surrounding
     non-null values on the order axis, VALUE writes a constant."""
 
+    step_span = "op.fill"
+
     def __init__(self, node: P.Fill, child: Operator):
         self.node = node
         self.child = child
@@ -1873,12 +1919,16 @@ class FillOp(Operator):
         if not batches:
             return
         ex = _concat_batches(batches, self.schema)
-        mask = np.asarray(jax.device_get(ex.mask))
+        cols = [_broadcast_full(ex.batch.columns[name], ex.padded_len)
+                for name, _ in self.schema]
+        mask, got = jax.device_get((ex.mask,
+                                    [(c.data, c.validity) for c in cols]))
+        M.device_wait.inc(site="fill")
+        mask = np.asarray(mask)
         host, val = {}, {}
-        for name, dtype in self.schema:
-            c = _broadcast_full(ex.batch.columns[name], ex.padded_len)
-            host[name] = np.asarray(jax.device_get(c.data))[mask]
-            val[name] = np.asarray(jax.device_get(c.validity))[mask]
+        for (name, _), (data, valid) in zip(self.schema, got):
+            host[name] = np.asarray(data)[mask]
+            val[name] = np.asarray(valid)[mask]
         ocol = self.node.order_col
         odtype = dict(self.schema)[ocol]
         if odtype.is_varlen:
@@ -1940,6 +1990,8 @@ class FillOp(Operator):
 
 
 class LimitOp(Operator):
+    step_span = "op.limit"
+
     def __init__(self, node: P.Limit, child: Operator):
         self.node = node
         self.child = child
@@ -1957,6 +2009,7 @@ class LimitOp(Operator):
             if n is not None:
                 keep = keep & (rank <= off + n)
             batch_rows = int(jax.device_get(jnp.sum(ex.mask.astype(jnp.int64))))
+            M.device_wait.inc(site="limit")
             seen += batch_rows
             ex.mask = keep
             yield ex
@@ -1965,6 +2018,8 @@ class LimitOp(Operator):
 
 
 class DistinctOp(Operator):
+    step_span = "op.distinct"
+
     def __init__(self, node: P.Distinct, child: Operator,
                  max_groups: int = 65536):
         self.node = node
@@ -1982,6 +2037,7 @@ class DistinctOp(Operator):
         gi = A.group_ids([c.data for c in cols], [c.validity for c in cols],
                          ex.mask, self.max_groups)
         ng = int(jax.device_get(gi.num_groups))
+        M.device_wait.inc(site="distinct")
         if ng > self.max_groups:
             raise EvalError("DISTINCT cardinality exceeds max_groups")
         out = F.gather(ex.batch, gi.rep_rows, gi.num_groups)

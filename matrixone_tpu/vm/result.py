@@ -51,6 +51,7 @@ def take_rows(hb: DeviceBatch, rows: np.ndarray) -> DeviceBatch:
 
 def to_host(ex: ExecBatch, schema) -> Batch:
     """An ExecBatch's live rows as a host Batch, with one wait."""
+    M.device_wait.inc(site="result")
     if ex.padded_len <= HOST_LANES:
         hb, mask = jax.device_get((ex.batch, ex.mask))
         return host_batch(take_rows(hb, np.flatnonzero(mask)), ex.dicts,
@@ -155,6 +156,7 @@ class Tail:
     def _host(self, batches: List[ExecBatch], schema) -> Batch:
         with motrace.span("result.fetch"):
             got = jax.device_get([(ex.batch, ex.mask) for ex in batches])
+            M.device_wait.inc(site="result")
             M.result_finish.inc(op="fetch", where="host")
             hex_ = _host_concat(got, batches, self.order_op.schema)
             with motrace.span("result.sort", where="host"):

@@ -42,6 +42,8 @@ from matrixone_tpu.vm.operators import Operator, chunk_to_execbatch
 
 
 class VectorTopKOp(Operator):
+    step_span = "op.vector_topk"
+
     def __init__(self, node: P.VectorTopK, ctx):
         self.node = node
         self.ctx = ctx

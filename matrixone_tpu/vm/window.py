@@ -54,6 +54,8 @@ def _suffix_min(vals: jnp.ndarray) -> jnp.ndarray:
 
 
 class WindowOp(Operator):
+    step_span = "op.window"
+
     def __init__(self, node: P.Window, child: Operator,
                  max_partitions: int = 65536):
         self.node = node

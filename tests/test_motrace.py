@@ -508,11 +508,13 @@ def test_cold_scan_spans_ride_the_prefetch_thread(tracer, cold):
     names = {sp["name"] for sp in spans}
     assert {"scan.wait", "scan.batch", "fusion.dispatch",
             "fusion.finalize"} <= names
-    # the tree: run > scan.chunk > blockcache.load > read/decode/upload,
-    # scan.wait beside scan.chunk on the statement's own thread
+    # the tree: the fused fragment's step > scan.chunk > blockcache.load >
+    # read/decode/upload, scan.wait beside scan.chunk on the statement's
+    # own thread
     parent = {sp["name"]: by_sid[sp["psid"]]["name"]
               for sp in spans if sp["psid"] in by_sid}
-    assert parent["scan.chunk"] == parent["scan.wait"] == "run"
+    assert parent["scan.chunk"] == parent["scan.wait"] == "op.fused"
+    assert parent["op.fused"] == "op.project" and parent["op.project"] == "run"
     assert parent["blockcache.load"] == parent["scan.zonemap"] \
         == "scan.chunk"
     assert parent["object.read"] == parent["object.decode"] \
@@ -544,7 +546,7 @@ def test_generator_rule_no_span_is_held_across_a_yield(tracer, cold):
         fused = [sp for sp in spans if sp["name"].startswith("fusion.")]
         assert any(sp["name"] == "fusion.dispatch" for sp in fused)
         for sp in fused + [x for x in spans if x["name"] == "scan.batch"]:
-            assert by_sid[sp["psid"]]["name"] == "run", sp
+            assert by_sid[sp["psid"]]["name"] == "op.fused", sp
         # on one thread a child lies inside its parent: a span held
         # across a yield would end after spans that are not its children
         for sp in spans:
